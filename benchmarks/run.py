@@ -34,6 +34,8 @@ import subprocess
 import sys
 import time
 
+from repro.launch import compile_cache
+
 from . import (
     bench_attention,
     bench_dequant,
@@ -167,6 +169,7 @@ def main() -> None:
                          "gate derived metrics against; >20% regression "
                          "fails the run")
     args = ap.parse_args()
+    compile_cache.enable()
     names = args.only.split(",") if args.only else list(TABLES)
     baselines = load_baselines(args.compare, names) if args.compare else {}
     t0 = time.time()

@@ -4,6 +4,7 @@ autotuner — the paper's §4 machinery end to end.
 
     PYTHONPATH=src python examples/custom_kernel.py
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,7 +52,7 @@ def fused_dequant_gelu_matmul(block_M, block_N, block_K, num_stages=2):
 kernel, winner = autotune(
     fused_dequant_gelu_matmul,
     grid_configs(block_M=[64, 128], block_N=[64, 128], block_K=[128, 256]),
-    schedule=Schedule(interpret=True),
+    schedule=Schedule(interpret=jax.default_backend() != "tpu"),  # Mosaic on a TPU
 )
 print(f"autotuner picked {winner.config}  (predicted {winner.score*1e6:.1f} us, "
       f"mxu={winner.mxu_util:.0%})")

@@ -3,6 +3,7 @@ schedule the compiler derived.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import numpy as np
 
 from repro.core import Schedule, compile as tl_compile
@@ -35,10 +36,10 @@ def Matmul(
 
 
 # ---------------------------------------------------------------------------
-# 2. Compile.  interpret=True runs the Pallas kernel body on CPU; on a TPU
-#    host the same program compiles to a Mosaic kernel.
+# 2. Compile.  On a TPU host the program compiles to a Mosaic kernel;
+#    anywhere else interpret=True runs the Pallas kernel body on the CPU.
 # ---------------------------------------------------------------------------
-kernel = tl_compile(Matmul, Schedule(interpret=True))
+kernel = tl_compile(Matmul, Schedule(interpret=jax.default_backend() != "tpu"))
 
 print("grid:", kernel.info.grid)
 print("dimension semantics:", kernel.info.dimension_semantics)

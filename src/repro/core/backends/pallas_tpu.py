@@ -18,7 +18,7 @@ import numpy as np
 
 from ..buffer import GLOBAL, SCALAR, TileBuffer
 from ..errors import LoweringError, ScheduleError, VerifyError
-from ..expr import BinExpr, ConstExpr, Expr, VarExpr, evaluate
+from ..expr import BinExpr, ConstExpr, Expr, VarExpr, evaluate, static_eval
 from ..lowering.indexing import make_index_map, no_loads
 from ..lowering.verify import alias_wiring
 from ..lowering.module import CompiledKernel, LoweredModule
@@ -39,20 +39,6 @@ from ..tile_ops import (
     TileOp,
 )
 from . import register_backend
-
-
-def _compiler_params_cls(pltpu):
-    """JAX moved ``TPUCompilerParams`` -> ``CompilerParams`` across releases;
-    accept whichever name the installed version exposes."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        raise LoweringError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported JAX version"
-        )
-    return cls
 
 
 @register_backend("pallas")
@@ -173,9 +159,11 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             if buf.name in values:
                 return values[buf.name]
             if buf.scope == SCALAR:
-                val = scalar_refs[scalar_pos[buf.name]][...]
-                values[buf.name] = val
-                return val
+                # Mosaic loads only scalars from SMEM: see load_scalar
+                raise LoweringError(
+                    f"{program.name}: scalar-prefetch operand {buf.name} "
+                    "must be read element by element"
+                )
             if buf.name in window_of:
                 w = in_windows[window_of[buf.name]]
                 val = squeeze(in_refs[window_of[buf.name]][...], w.region)
@@ -215,6 +203,17 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             )
             put(buf, jnp.where(g, new, get(buf).astype(new.dtype)))
 
+        def load_scalar(buf: TileBuffer, idx_values):
+            """One SMEM scalar load (``Lens[bz]``): every index must be a
+            scalar (grid ids, constants), never a vector of lanes."""
+            idx = tuple(jnp.asarray(v, jnp.int32) for v in idx_values)
+            if any(i.ndim for i in idx):
+                raise LoweringError(
+                    f"{program.name}: {buf.name} is indexed per lane; "
+                    "scalar-prefetch operands take scalar indices only"
+                )
+            return scalar_refs[scalar_pos[buf.name]][idx]
+
         def scalar_env():
             return dict(env_scalars)
 
@@ -239,18 +238,41 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             gput(op.buffer, tile, phase)
 
         def region_value(region: ResolvedRegion, extra):
-            """Read a region of an on-chip buffer as a tile value."""
-            base = get(region.buffer)
+            """Read a region of an on-chip buffer as a tile value.  Static
+            starts slice the value; a start that depends on a grid id reads
+            the buffer's window or scratch ref through ``pl.ds`` (Mosaic
+            lowers no dynamic_slice of a value)."""
+            buf = region.buffer
             starts = [eval_expr(s, extra, no_loads) for s in region.starts]
-            if all(isinstance(s, (int, np.integer)) and s == 0 for s in starts) and tuple(
-                region.sizes
-            ) == tuple(region.buffer.shape):
-                val = base
+            sizes = tuple(region.sizes)
+            if all(isinstance(s, (int, np.integer)) for s in starts):
+                val = get(buf)
+                if sizes != tuple(buf.shape):
+                    val = jax.lax.slice(
+                        val, starts, [s + n for s, n in zip(starts, sizes)]
+                    )
             else:
-                import jax.lax as lax
-
-                val = lax.dynamic_slice(base, [jnp.asarray(s, jnp.int32) for s in starts], region.sizes)
+                val = ref_slice(buf, [pl.ds(s, n) for s, n in zip(starts, sizes)])
             return squeeze(val, region)
+
+        def ref_slice(buf: TileBuffer, slices):
+            if buf.name in window_of:
+                w = in_windows[window_of[buf.name]]
+                it = iter(slices)
+                idx = tuple(0 if c else next(it) for c in w.region.collapsed)
+                return in_refs[window_of[buf.name]][idx].astype(
+                    jnp.dtype(buf.dtype)
+                )
+            if buf.name not in scratch_pos:
+                raise LoweringError(
+                    f"{program.name}: dynamic slice of {buf.name}, which "
+                    "has no window or scratch ref"
+                )
+            ref = scr_refs[scratch_pos[buf.name]]
+            if buf.name in dirty:  # the ref must hold the current value
+                ref[...] = values[buf.name].astype(ref.dtype)
+                dirty.discard(buf.name)
+            return ref[tuple(slices)]
 
         def run_copy(op: CopyOp, phase: str, extra):
             s, d = op.src.buffer, op.dst.buffer
@@ -275,19 +297,25 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             val = region_value(op.src, extra)
             if tuple(op.dst.tile_shape) == tuple(d.shape) and not any(op.dst.collapsed):
                 gput(d, val, phase)
+                return
+            # a sub-region: store into the scratch ref (Mosaic lowers no
+            # dynamic_update_slice of a value), then re-read it on next use
+            sizes = tuple(op.dst.sizes)
+            starts = [eval_expr(x, extra, no_loads) for x in op.dst.starts]
+            ref = scr_refs[scratch_pos[d.name]]
+            if d.name in dirty:
+                ref[...] = values[d.name].astype(ref.dtype)
+                dirty.discard(d.name)
+            values.pop(d.name, None)
+            idx = tuple(pl.ds(x, n) for x, n in zip(starts, sizes))
+            upd = val.reshape(sizes).astype(ref.dtype)
+            g = guard(phase)
+            if g is None:
+                ref[idx] = upd
             else:
-                import jax.lax as lax
-
-                starts = [eval_expr(x, extra, no_loads) for x in op.dst.starts]
-                cur = get(d)
-                upd = val.reshape(tuple(op.dst.sizes)).astype(cur.dtype)
-                gput(
-                    d,
-                    lax.dynamic_update_slice(
-                        cur, upd, [jnp.asarray(x, jnp.int32) for x in starts]
-                    ),
-                    phase,
-                )
+                @pl.when(g)
+                def _():
+                    ref[idx] = upd
 
         def run_gemm(op: GemmOp, phase: str, extra):
             a, b = get(op.a), get(op.b)
@@ -349,42 +377,66 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
                 iotas[v.name] = jax.lax.broadcasted_iota(jnp.int32, tuple(shape), i)
 
             def structured_load(buffer, idx_exprs):
-                """TPU-friendly load patterns over the parallel box.
+                """TPU-friendly load patterns over the parallel box — whole
+                tiles and broadcasts, never a gather (Mosaic has none).
 
-                * all-direct indices -> the whole tile (pure vector op)
-                * ``ax // c`` on an axis -> jnp.repeat along that axis (the
-                  vectorized sub-byte unpack idiom; the TPU analogue of PTX
-                  lop3 byte-extraction in the paper's dequant kernels)
-                Returns None when the pattern doesn't apply.
+                Each buffer dim is indexed by
+                * a box axis -> the dim as is (its first ``extent`` entries
+                  when the buffer is lane-padded past the box);
+                * ``ax // c`` -> jnp.repeat along the dim (the vectorized
+                  sub-byte unpack idiom; the TPU analogue of PTX lop3
+                  byte-extraction in the paper's dequant kernels);
+                * the constant 0 on a unit dim -> a dim that broadcasts.
+                The box axes must appear in increasing order; box axes the
+                buffer lacks become unit dims (a row statistic ``m[i]`` in
+                an ``(i, j)`` box is ``m[:, None]``).  Returns None when
+                the pattern doesn't apply.
                 """
-                if len(idx_exprs) != buffer.ndim or len(idx_exprs) != nax:
+                if len(idx_exprs) != buffer.ndim:
                     return None
-                plan = []
-                for i, e in enumerate(idx_exprs):
-                    if (
-                        isinstance(e, VarExpr)
-                        and e.name == axis_names[i]
-                        and buffer.shape[i] == op.extents[i]
-                    ):
-                        plan.append(("id", 1))
+                val = get(buffer)
+                placed = []  # box axis of each buffer dim (None: unit dim)
+                for d, e in enumerate(idx_exprs):
+                    if isinstance(e, VarExpr) and e.name in axis_names:
+                        a = axis_names.index(e.name)
+                        if buffer.shape[d] < op.extents[a]:
+                            return None
+                        if buffer.shape[d] > op.extents[a]:  # lane padding
+                            val = jax.lax.slice_in_dim(val, 0, op.extents[a], axis=d)
                     elif (
                         isinstance(e, BinExpr)
                         and e.op == "floordiv"
                         and isinstance(e.lhs, VarExpr)
-                        and e.lhs.name == axis_names[i]
+                        and e.lhs.name in axis_names
                         and isinstance(e.rhs, ConstExpr)
-                        and buffer.shape[i] * int(e.rhs.value) == op.extents[i]
                     ):
-                        plan.append(("repeat", int(e.rhs.value)))
+                        a = axis_names.index(e.lhs.name)
+                        c = int(e.rhs.value)
+                        n = op.extents[a] // c  # live columns (rest: lane pad)
+                        if n * c != op.extents[a] or buffer.shape[d] < n:
+                            return None
+                        if buffer.shape[d] > n:
+                            val = jax.lax.slice_in_dim(val, 0, n, axis=d)
+                        val = jnp.repeat(val, c, axis=d)
+                    elif static_eval(e) == 0 and buffer.shape[d] == 1:
+                        a = None
                     else:
                         return None
-                val = get(buffer)
-                for ax, (kind, c) in enumerate(plan):
-                    if kind == "repeat":
-                        val = jnp.repeat(val, c, axis=ax)
-                return val
+                    placed.append(a)
+                axes = [a for a in placed if a is not None]
+                if axes != sorted(set(axes)):
+                    return None
+                if len(placed) == nax and all(
+                    a is None or a == d for d, a in enumerate(placed)
+                ):
+                    return val
+                return val.reshape(tuple(
+                    op.extents[a] if a in axes else 1 for a in range(nax)
+                ))
 
             def load_fn(buffer, idx_values, idx_exprs):
+                if buffer.scope == SCALAR:
+                    return load_scalar(buffer, idx_values)
                 fast = structured_load(buffer, idx_exprs)
                 if fast is not None:
                     return fast
@@ -471,13 +523,18 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             run_ops(pipe.body, LOOP, {})
         run_ops(phases.post, POST, {})
 
-        # write back dirty scratch accumulators
-        for name in dirty:
+        # write back dirty scratch accumulators, in a fixed order: the
+        # kernel's text is part of the persistent compile cache's key
+        for name in sorted(dirty):
             scr_refs[scratch_pos[name]][...] = values[name].astype(
                 scr_refs[scratch_pos[name]].dtype
             )
 
-    compiler_params = _compiler_params_cls(pltpu)(dimension_semantics=dim_sem)
+    # the limit plan_vmem checked against, so Mosaic's smaller scoped
+    # default cannot refuse a kernel the planner accepted
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=dim_sem, vmem_limit_bytes=schedule.vmem_limit
+    )
     if n_scalars:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_scalars,

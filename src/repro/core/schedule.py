@@ -25,10 +25,28 @@ from .buffer import FRAGMENT, GLOBAL, SHARED, TileBuffer, dtype_bits
 from .errors import ScheduleError
 from .layout import LANE, round_up, sublane
 
-# TPU v5e on-chip budget (bytes).  ~128 MiB VMEM; keep headroom for Mosaic's
-# own spills, semaphores and the grid pipeline's internal buffers.
-VMEM_BYTES = 128 * 1024 * 1024
+# VMEM per chip (bytes), keyed by ``jax.Device.device_kind``.  A kernel may
+# plan VMEM_HEADROOM of it; the rest is headroom for Mosaic's own spills,
+# semaphores and the grid pipeline's internal buffers.  The same number is
+# handed to Mosaic as ``vmem_limit_bytes`` (backends/pallas_tpu.py), so a
+# kernel the planner accepts is not refused by a smaller scoped default.
+VMEM_BYTES = {
+    "TPU v5 lite": 128 * 1024 * 1024,  # v5e
+}
 VMEM_HEADROOM = 0.85
+# The chip kernels are planned for when none is attached (CPU interpret runs
+# and ahead-of-time compiles against a described topology).
+DEFAULT_DEVICE_KIND = "TPU v5 lite"
+
+
+def vmem_limit_for(device_kind: str) -> int:
+    """The scoped-VMEM limit (bytes) for one chip kind; unknown is an error."""
+    if device_kind not in VMEM_BYTES:
+        raise ScheduleError(
+            f"no VMEM size known for device kind {device_kind!r}; add it to "
+            f"repro.core.schedule.VMEM_BYTES (known: {sorted(VMEM_BYTES)})"
+        )
+    return int(VMEM_BYTES[device_kind] * VMEM_HEADROOM)
 
 
 @dataclasses.dataclass
@@ -39,9 +57,16 @@ class Schedule:
     num_stages: Optional[int] = None  # override T.Pipelined's stage count
     grid_swizzle: Optional[int] = None  # override T.use_swizzle
     dimension_semantics: Optional[Tuple[str, ...]] = None  # rarely needed
-    vmem_limit: int = int(VMEM_BYTES * VMEM_HEADROOM)
+    device_kind: str = DEFAULT_DEVICE_KIND  # chip the VMEM plan targets
     # Advisory: collected by lower.py for the cost model / roofline.
     notes: Dict[str, object] = dataclasses.field(default_factory=dict)
+
+    def __post_init__(self):
+        vmem_limit_for(self.device_kind)  # unknown chip: fail here, loudly
+
+    @property
+    def vmem_limit(self) -> int:
+        return vmem_limit_for(self.device_kind)
 
 
 @dataclasses.dataclass

@@ -11,6 +11,12 @@ Backend selection (``kernel_backend``):
   partitioning).
 * ``"auto"``   — pallas on TPU, xla elsewhere.
 
+A call that asks for the tile kernel but cannot get it — a feature the kernel
+lacks (soft-capped logits, a chunk off the page grid), or no TPU attached so
+the kernel is interpreted — still runs, on the oracle or the interpreter,
+and is counted in :data:`FALLBACKS`.  On the chip the serving path must
+leave it empty (``chip_smoke.py`` asserts so).
+
 Compiled tile kernels are cached per (kernel, static config) — the TPU
 realization of the paper's "dynamic parameter simplification" for kernel
 libraries: a library entry recompiles per shape bucket and reuses the cached
@@ -20,8 +26,8 @@ the compile itself is additionally memoized inside repro.core.compiler on
 """
 from __future__ import annotations
 
+import collections
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -47,8 +53,10 @@ from .prefill_attention import (
     prefill_attention_quant_program,
 )
 
-_DEFAULT = os.environ.get("REPRO_KERNEL_BACKEND", "auto")
 _CACHE: dict = {}
+# (op, reason) -> times a tile-kernel request ran something else (counted
+# at trace time, so once per compiled shape, not per call)
+FALLBACKS: "collections.Counter[Tuple[str, str]]" = collections.Counter()
 
 # Obligation kinds (core.lowering.verify.Obligation) that guard_dispatch
 # discharges.  A future kernel emitting a new kind must either extend the
@@ -150,19 +158,39 @@ def guard_dispatch(tables, num_pages, page_size, work):
 
 
 def default_backend() -> str:
-    if _DEFAULT != "auto":
-        return _DEFAULT
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _fallback(op: str, reason: str) -> None:
+    FALLBACKS[(op, reason)] += 1
+
+
+def _schedule(op: str) -> Schedule:
+    """Mosaic-compiled for the attached chip; the interpreter off the TPU."""
+    if jax.default_backend() == "tpu":
+        return Schedule(device_kind=jax.devices()[0].device_kind)
+    _fallback(op, "interpreted: no TPU attached")
+    return Schedule(interpret=True)
 
 
 def _cached(key, builder):
     if key not in _CACHE:
-        _CACHE[key] = tl_compile(builder(), Schedule(interpret=_interpret()))
+        _CACHE[key] = tl_compile(builder(), _schedule(key[0]))
     return _CACHE[key]
+
+
+def _oracle_reason(logit_soft_cap=None, chunk=None, page_size=None,
+                   max_pages=None, **xla_kw) -> Optional[str]:
+    """Why a tile-kernel request must run the oracle instead (None: it
+    need not).  ``xla_kw`` holds the attention options no kernel takes."""
+    if logit_soft_cap is not None:
+        return "logit soft-cap"
+    for name in ("window", "kv_len"):
+        if xla_kw.get(name) is not None:
+            return name
+    if chunk is not None and (chunk % page_size or chunk // page_size > max_pages):
+        return "chunk off the page grid"
+    return None
 
 
 def _resolve(backend: Optional[str]) -> str:
@@ -216,12 +244,10 @@ def attention(q, k, v, *, causal: bool = False, sm_scale=None,
     hkv, sk = k.shape[1], k.shape[2]
     bm = block_m or _pick_block(sq)
     bn = block_n or _pick_block(sk)
-    if (
-        be == "xla"
-        or xla_kw.get("window") is not None
-        or xla_kw.get("kv_len") is not None
-        or xla_kw.get("logit_soft_cap") is not None
-    ):
+    why = _oracle_reason(**xla_kw)
+    if be == "xla" or why:
+        if be != "xla":
+            _fallback("fa", why)
         return ref.attention(q, k, v, causal=causal, sm_scale=sm_scale, **xla_kw)
     key = ("fa", b, hq, hkv, sq, sk, d, causal, str(q.dtype), bm, bn,
            num_stages, sm_scale)
@@ -244,7 +270,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     through the block table via scalar prefetch; the XLA path is
     ref.paged_attention (used by the serving engine on CPU hosts)."""
     be = _resolve(backend)
-    if be == "xla" or logit_soft_cap is not None:
+    why = _oracle_reason(logit_soft_cap)
+    if be == "xla" or why:
+        if be != "xla":
+            _fallback("paged", why)
         return ref.paged_attention(
             q, k_pages, v_pages, block_tables, seq_lens, sm_scale=sm_scale,
             window=window, logit_soft_cap=logit_soft_cap,
@@ -261,7 +290,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
             str(q.dtype), "float32", num_stages, sm_scale,
         ),
     )
-    return kern(block_tables, seq_lens, q, k_pages, v_pages)
+    # pack queries with their GQA group: head h*group + g -> [h, g]
+    qp = q.reshape(b, hkv, hq // hkv, d)
+    return kern(block_tables, seq_lens, qp, k_pages, v_pages).reshape(b, hq, d)
 
 
 def prefill_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
@@ -287,8 +318,10 @@ def prefill_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
     b, hq, chunk, d = q.shape
     hkv, num_pages, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
-    if be != "xla" and logit_soft_cap is None and chunk % page_size == 0 \
-            and chunk // page_size <= max_pages:
+    why = _oracle_reason(logit_soft_cap, chunk, page_size, max_pages)
+    if be != "xla" and why:
+        _fallback("prefill", why)
+    if be != "xla" and not why:
         group = hq // hkv
         key = ("prefill", b, hq, hkv, num_pages, page_size, max_pages, chunk,
                d, window, str(q.dtype), num_stages, sm_scale)
@@ -350,7 +383,10 @@ def paged_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
     page-at-a-time inside the kernel (DequantStage); the XLA path is
     ref.paged_attention_quant (dequantize pools, then the fp oracle)."""
     be = _resolve(backend)
-    if be == "xla" or logit_soft_cap is not None:
+    why = _oracle_reason(logit_soft_cap)
+    if be == "xla" or why:
+        if be != "xla":
+            _fallback("paged_q", why)
         return ref.paged_attention_quant(
             q, k_pages, v_pages, k_scales, v_scales, block_tables, seq_lens,
             fmt=fmt, sm_scale=sm_scale, window=window,
@@ -368,7 +404,9 @@ def paged_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
             str(q.dtype), "float32", num_stages, sm_scale,
         ),
     )
-    return kern(block_tables, seq_lens, q, k_pages, v_pages, k_scales, v_scales)
+    qp = q.reshape(b, hkv, hq // hkv, d)
+    out = kern(block_tables, seq_lens, qp, k_pages, v_pages, k_scales, v_scales)
+    return out.reshape(b, hq, d)
 
 
 def prefill_attention_quant(q, k_new, v_new, k_pages, v_pages, k_scales,
@@ -391,8 +429,10 @@ def prefill_attention_quant(q, k_new, v_new, k_pages, v_pages, k_scales,
     max_pages = block_tables.shape[1]
     kq, ks_new = ref.quantize_rows(k_new, fmt)
     vq, vs_new = ref.quantize_rows(v_new, fmt)
-    if be != "xla" and logit_soft_cap is None and chunk % page_size == 0 \
-            and chunk // page_size <= max_pages:
+    why = _oracle_reason(logit_soft_cap, chunk, page_size, max_pages)
+    if be != "xla" and why:
+        _fallback("prefill_q", why)
+    if be != "xla" and not why:
         group = hq // hkv
         key = ("prefill_q", fmt, b, hq, hkv, num_pages, page_size, max_pages,
                chunk, d, window, str(q.dtype), num_stages, sm_scale)
@@ -480,7 +520,10 @@ def mla_paged(q_lat, q_pe, ckv_pages, kpe_pages, block_tables, seq_lens, *,
     (what the serving engine runs on CPU hosts).  Soft-capped models route
     to the oracle — same policy as paged_attention."""
     be = _resolve(backend)
-    if be == "xla" or logit_soft_cap is not None:
+    why = _oracle_reason(logit_soft_cap)
+    if be == "xla" or why:
+        if be != "xla":
+            _fallback("mla_paged", why)
         return ref.mla_paged(q_lat, q_pe, ckv_pages, kpe_pages, block_tables,
                              seq_lens, sm_scale=sm_scale, window=window,
                              logit_soft_cap=logit_soft_cap)
@@ -526,8 +569,10 @@ def mla_prefill(q_lat, q_pe, ckv_new, kpe_new, ckv_pages, kpe_pages,
     pe = q_pe.shape[-1]
     num_pages, page_size, _ = ckv_pages.shape
     max_pages = block_tables.shape[1]
-    if be != "xla" and logit_soft_cap is None and chunk % page_size == 0 \
-            and chunk // page_size <= max_pages:
+    why = _oracle_reason(logit_soft_cap, chunk, page_size, max_pages)
+    if be != "xla" and why:
+        _fallback("mla_prefill", why)
+    if be != "xla" and not why:
         key = ("mla_prefill", b, h, r, pe, num_pages, page_size, max_pages,
                chunk, str(q_lat.dtype), num_stages, sm_scale, window)
         kern = _cached(
@@ -582,7 +627,10 @@ def mla_paged_quant(q_lat, q_pe, ckv_pages, kpe_pages, ckv_scales, kpe_scales,
     per-token scale columns.  Pallas path dequantizes inline
     (DequantStage); XLA path is ref.mla_paged_quant."""
     be = _resolve(backend)
-    if be == "xla" or logit_soft_cap is not None:
+    why = _oracle_reason(logit_soft_cap)
+    if be == "xla" or why:
+        if be != "xla":
+            _fallback("mla_paged_q", why)
         return ref.mla_paged_quant(
             q_lat, q_pe, ckv_pages, kpe_pages, ckv_scales, kpe_scales,
             block_tables, seq_lens, fmt=fmt, sm_scale=sm_scale, window=window,
@@ -625,8 +673,10 @@ def mla_prefill_quant(q_lat, q_pe, ckv_new, kpe_new, ckv_pages, kpe_pages,
     max_pages = block_tables.shape[1]
     cq, cs_new = ref.quantize_rows(ckv_new, fmt)
     pq, ps_new = ref.quantize_rows(kpe_new, fmt)
-    if be != "xla" and logit_soft_cap is None and chunk % page_size == 0 \
-            and chunk // page_size <= max_pages:
+    why = _oracle_reason(logit_soft_cap, chunk, page_size, max_pages)
+    if be != "xla" and why:
+        _fallback("mla_prefill_q", why)
+    if be != "xla" and not why:
         key = ("mla_prefill_q", fmt, b, h, r, pe, num_pages, page_size,
                max_pages, chunk, str(q_lat.dtype), num_stages, sm_scale, window)
         kern = _cached(
@@ -708,6 +758,7 @@ def dequant_matmul(a, b_packed, *, fmt: str = "int4", scales=None,
     with_scales = scales is not None
     if with_scales and scales.shape[1] != K // bk:
         # kernel constraint: one scale group per K block
+        _fallback("dq", "scale group is not one K block")
         return ref.dequant_matmul(
             a, b_packed, fmt, scales, K // scales.shape[1], out_dtype
         )

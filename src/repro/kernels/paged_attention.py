@@ -12,7 +12,10 @@ non-contiguous pages exactly like contiguous ones (TileLoom's "plan
 dataflow over non-contiguous tiles" as a one-line index change).
 
 Softmax is the shared online-rescaling template (attention_core.py) with a
-page-gather KV source and GQA group-major Q packing; ragged sequence
+page-gather KV source and GQA group-major Q packing: ``Q`` and ``Output``
+are ``(slots, kv_heads, group, head_dim)``, so each grid cell's block spans
+the array's last two dimensions whole (Mosaic's block rule holds for any
+group size, e.g. qwen2's 6); ragged sequence
 lengths (every slot at its own position) and sliding windows compose the
 ragged mask against the ``Lens`` scalar tensor.  Entries of the block
 table beyond a slot's live length must still hold *valid* page ids (the
@@ -52,10 +55,10 @@ def paged_attention_program(
     def PagedAttn(
         Tables: T.ScalarTensor((slots, max_pages), "int32"),
         Lens: T.ScalarTensor((slots,), "int32"),
-        Q: T.Tensor((slots, heads, head_dim), dtype),
+        Q: T.Tensor((slots, kv_heads, group, head_dim), dtype),
         KPages: T.Tensor((kv_heads, num_pages, page_size, head_dim), dtype),
         VPages: T.Tensor((kv_heads, num_pages, page_size, head_dim), dtype),
-        Output: T.Tensor((slots, heads, head_dim), dtype),
+        Output: T.Tensor((slots, kv_heads, group, head_dim), dtype),
     ):
         with T.Kernel(kv_heads, slots) as (bh, bz):
             Q_shared = T.alloc_shared((group, head_dim), dtype)
@@ -66,7 +69,7 @@ def paged_attention_program(
             ons = AC.OnlineSoftmax(group, head_dim, scale, accum_dtype,
                                    safe_div=True)
 
-            T.copy(Q[bz, bh * group, 0], Q_shared)
+            T.copy(Q[bz, bh, 0, 0], Q_shared)
 
             def load_kv(k):
                 # the paged gather: page index loaded from the block table
@@ -85,7 +88,7 @@ def paged_attention_program(
                 lambda s, ks, k: AC.scores(s, Q_shared, ks), mask,
                 num_stages=num_stages,
             )
-            ons.finalize(Output[bz, bh * group, 0])
+            ons.finalize(Output[bz, bh, 0, 0])
 
     return PagedAttn
 
@@ -121,12 +124,12 @@ def paged_attention_quant_program(
     def PagedAttnQuant(
         Tables: T.ScalarTensor((slots, max_pages), "int32"),
         Lens: T.ScalarTensor((slots,), "int32"),
-        Q: T.Tensor((slots, heads, head_dim), dtype),
+        Q: T.Tensor((slots, kv_heads, group, head_dim), dtype),
         KPages: T.Tensor((kv_heads, num_pages, page_size, head_dim // pack), "int8"),
         VPages: T.Tensor((kv_heads, num_pages, page_size, head_dim // pack), "int8"),
         KScales: T.Tensor((kv_heads, num_pages, page_size, 1), dtype),
         VScales: T.Tensor((kv_heads, num_pages, page_size, 1), dtype),
-        Output: T.Tensor((slots, heads, head_dim), dtype),
+        Output: T.Tensor((slots, kv_heads, group, head_dim), dtype),
     ):
         with T.Kernel(kv_heads, slots) as (bh, bz):
             Q_shared = T.alloc_shared((group, head_dim), dtype)
@@ -136,7 +139,7 @@ def paged_attention_quant_program(
             ons = AC.OnlineSoftmax(group, head_dim, scale, accum_dtype,
                                    safe_div=True)
 
-            T.copy(Q[bz, bh * group, 0], Q_shared)
+            T.copy(Q[bz, bh, 0, 0], Q_shared)
 
             def load_kv(k):
                 # paged gather + inline dequant (page index from the table)
@@ -154,7 +157,7 @@ def paged_attention_quant_program(
                 lambda s, ks, k: AC.scores(s, Q_shared, ks), mask,
                 num_stages=num_stages,
             )
-            ons.finalize(Output[bz, bh * group, 0])
+            ons.finalize(Output[bz, bh, 0, 0])
 
     return PagedAttnQuant
 

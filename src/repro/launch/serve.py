@@ -1,18 +1,23 @@
 """Serving driver: continuous-batching engine over a reduced (CPU) or full
-(TPU) model.
+(TPU) model, with random weights drawn from ``--seed``.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2_1_5b --reduced \
         --requests 16 --max-new 32
+
+Exits non-zero unless every request completes.  Compiled programs are kept
+in the persistent compilation cache (``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serving import ServeConfig, ServingEngine
 
@@ -25,7 +30,10 @@ def main(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--prompt-len", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, nargs="+", default=[8],
+                    metavar=("LEN", "MAX"),
+                    help="prompt length, or a range [LEN, MAX] drawn "
+                         "uniformly per request")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--cache", choices=["paged", "contiguous"], default="paged",
@@ -73,14 +81,19 @@ def main(argv=None):
                     help="per-request deadline in engine ticks; expired "
                          "requests exit TIMED_OUT with partial output")
     args = ap.parse_args(argv)
+    if len(args.prompt_len) > 2:
+        ap.error("--prompt-len takes LEN or LEN MAX")
+    lo, hi = args.prompt_len[0], args.prompt_len[-1]
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if cfg.is_encoder_decoder:
         raise SystemExit("enc-dec serving demo lives in examples/; use an LM arch")
 
-    params = lm.init(cfg, jax.random.PRNGKey(args.seed))
+    # one compiled program, not one dispatch per weight
+    params = jax.jit(functools.partial(lm.init, cfg))(jax.random.PRNGKey(args.seed))
     engine = ServingEngine(
         cfg, params,
         ServeConfig(slots=args.slots, max_len=args.max_len,
@@ -97,7 +110,8 @@ def main(argv=None):
     )
     rng = np.random.default_rng(args.seed)
     for _ in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab_size, size=args.prompt_len).tolist()
+        n = lo if lo == hi else int(rng.integers(lo, hi + 1))
+        prompt = rng.integers(0, cfg.vocab_size, size=n).tolist()
         engine.submit(prompt, deadline_ticks=args.deadline_ticks)
 
     t0 = time.time()
@@ -138,7 +152,12 @@ def main(argv=None):
     )
     for r in done[:3]:
         print(f"  req {r.uid}: prompt {r.prompt[:4]}... -> {r.output[:8]}...")
-    return done
+    if not_completed or len(done) != args.requests:
+        raise SystemExit(
+            f"{args.requests - len(done) + len(not_completed)} of "
+            f"{args.requests} requests did not complete"
+        )
+    return engine
 
 
 if __name__ == "__main__":

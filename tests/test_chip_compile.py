@@ -1,0 +1,154 @@
+"""Ahead-of-time compiles for a described TPU v5e: the serving kernels and
+steps at qwen2_1_5b's published widths, in bfloat16, through Mosaic
+(``interpret=False``).  Nothing runs; each test asserts that the chip's
+compiler accepts the program and that a Mosaic kernel (``tpu_custom_call``)
+is in it — what the CPU interpret-mode suite cannot show (tile alignment,
+SMEM scalar loads, VMEM limits).
+
+The topology is described inside a module fixture, never at import: only one
+process may hold the TPU library at a time, and every test worker imports
+this file.  Keep these tests in this one file so one worker holds it.
+"""
+import collections
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs import get_config
+from repro.core import Schedule
+from repro.kernels import ops
+
+QWEN = get_config("qwen2_1_5b")
+SLOTS, MAX_LEN, PAGE, CHUNK = 8, 1024, 16, 128
+MAX_PAGES = MAX_LEN // PAGE
+NUM_PAGES = SLOTS * MAX_PAGES + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # entries compiled for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile tile kernels for the chip, not the interpreter: off the TPU
+    ``ops`` would interpret them, and must not reuse interpreted entries."""
+    monkeypatch.setattr(ops, "_schedule", lambda op: Schedule())
+    monkeypatch.setattr(ops, "_CACHE", {})
+    monkeypatch.setattr(ops, "FALLBACKS", collections.Counter())
+
+
+def compiled_text(fn, *shapes, sharding):
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), shapes
+    )
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def sds(*shape, dtype=QWEN.dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+def pools(fmt=None):
+    """(k, v) page pools, plus scale pools for a quantized format."""
+    hkv, d = QWEN.num_kv_heads, QWEN.head_dim
+    if fmt is None:
+        return (sds(hkv, NUM_PAGES, PAGE, d),) * 2
+    return (sds(hkv, NUM_PAGES, PAGE, d, dtype="int8"),) * 2 + (
+        sds(hkv, NUM_PAGES, PAGE, 1),) * 2
+
+
+def decode_shapes(fmt=None):
+    table, lens = sds(SLOTS, MAX_PAGES, dtype="int32"), sds(SLOTS, dtype="int32")
+    q = sds(SLOTS, QWEN.num_heads, QWEN.head_dim)
+    return q, *pools(fmt), table, lens
+
+
+def prefill_shapes(fmt=None):
+    hq, hkv, d = QWEN.num_heads, QWEN.num_kv_heads, QWEN.head_dim
+    table, vec = sds(SLOTS, MAX_PAGES, dtype="int32"), sds(SLOTS, dtype="int32")
+    q, kv = sds(SLOTS, hq, CHUNK, d), sds(SLOTS, hkv, CHUNK, d)
+    return q, kv, kv, *pools(fmt), table, vec, vec
+
+
+KERNELS = {
+    "paged_attention": (ops.paged_attention, decode_shapes()),
+    "paged_attention_int8": (
+        functools.partial(ops.paged_attention_quant, fmt="int8"), decode_shapes("int8")),
+    "prefill_attention": (ops.prefill_attention, prefill_shapes()),
+    "prefill_attention_int8": (
+        functools.partial(ops.prefill_attention_quant, fmt="int8"),
+        prefill_shapes("int8")),
+    "gemm": (ops.matmul, (sds(SLOTS * CHUNK, QWEN.d_model), sds(QWEN.d_model, QWEN.d_ff))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, one_chip, mosaic):
+    fn, shapes = KERNELS[name]
+    text = compiled_text(lambda *a: fn(*a, backend="pallas"), *shapes, sharding=one_chip)
+    assert "tpu_custom_call" in text
+    assert not ops.FALLBACKS, dict(ops.FALLBACKS)
+
+
+def test_mla_paged_compiles_at_sixteen_heads(one_chip, mosaic):
+    """deepseek_v2_lite_16b's latent decode: 16 heads in one ``block_h``
+    window spans the head axis whole, so Mosaic's block rule holds."""
+    m = get_config("deepseek_v2_lite_16b").mla
+    h, r, pe = 16, m.kv_lora_rank, m.qk_rope_head_dim
+    shapes = (sds(SLOTS, h, r), sds(SLOTS, h, pe), sds(NUM_PAGES, PAGE, r),
+              sds(NUM_PAGES, PAGE, pe), sds(SLOTS, MAX_PAGES, dtype="int32"),
+              sds(SLOTS, dtype="int32"))
+    text = compiled_text(lambda *a: ops.mla_paged(*a, backend="pallas"), *shapes,
+                         sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "window"])
+def test_serving_step_compiles_for_v5e(step, one_chip, mosaic):
+    """The engine's jitted steps at published widths, cut to 2 layers (the
+    layers are scanned, so depth does not change the program's body), with
+    the Pallas kernels inside."""
+    from repro.models import lm
+    from repro.serving import engine as E
+
+    cfg = dataclasses.replace(QWEN, num_layers=2, kernel_backend="pallas")
+    params = jax.eval_shape(lambda k: lm.init(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_cache(
+        cfg, SLOTS, MAX_LEN, layout="paged", page_size=PAGE, num_blocks=NUM_PAGES))
+    vec, flag = sds(SLOTS, dtype="int32"), sds(SLOTS, dtype="bool")
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    fn, args = {
+        "decode": (E._decode_step_fn(cfg, 0.0),
+                   (params, cache, vec, vec, key, flag, flag)),
+        "prefill": (E._prefill_step_fn(cfg, 0.0),
+                    (params, cache, sds(SLOTS, CHUNK, dtype="int32"), vec, vec, key, flag)),
+        "window": (E._decode_loop_fn(cfg, 0.0, 8, -1, MAX_LEN),
+                   (params, cache, vec, vec, key, flag, vec)),
+    }[step]
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), args
+    )
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text

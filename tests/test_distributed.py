@@ -19,11 +19,7 @@ def abstract_mesh(multi_pod=False):
         sizes, names = (2, 16, 16), ("pod", "data", "model")
     else:
         sizes, names = (16, 16), ("data", "model")
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        # older JAX (<0.5): AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
+    return AbstractMesh(sizes, names)
 
 
 def _axis_size(mesh, ax):

@@ -1,5 +1,10 @@
 """Per-kernel validation: Pallas lowering (interpret mode) vs ref.py oracle,
 swept over shapes and dtypes; plus reference-backend cross-checks."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -422,3 +427,34 @@ class TestLinearAttention:
             np.testing.assert_allclose(
                 y[:, t], np.einsum("bn,bnp->bp", c[:, t], h), atol=2e-2
             )
+
+
+# ---------------------------------------------------------------------------
+# Lowering is deterministic across processes
+# ---------------------------------------------------------------------------
+
+_LOWER_PAGED = """
+import jax, numpy as np
+from repro.core import Schedule, compile as tl_compile
+from repro.kernels.paged_attention import PARITY_CASES, paged_attention_program, parity_inputs
+prog = paged_attention_program(**dict(PARITY_CASES)["paged_attention_mqa"])
+args = parity_inputs("paged_attention_mqa", prog, np.random.default_rng(0))
+print(jax.make_jaxpr(tl_compile(prog, Schedule()))(*args))  # the Mosaic kernel body
+"""
+
+
+def test_kernel_text_is_independent_of_hash_seed():
+    """JAX's persistent compilation cache keys on a program's text, so a
+    kernel's body must come out the same whatever Python's per-process
+    string hash seed (set iteration order) is.  Traced, not lowered: the
+    chip's kernel cannot be lowered off the TPU, and interpret mode's
+    lowering hides the order of the final scratch stores."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    texts = set()
+    for seed in ("1", "2", "3"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=str(root / "src"))
+        out = subprocess.run([sys.executable, "-c", _LOWER_PAGED], env=env, cwd=root,
+                             capture_output=True, text=True, check=True)
+        texts.add(out.stdout)
+    assert len(texts) == 1

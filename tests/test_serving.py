@@ -1043,9 +1043,12 @@ def test_paged_attention_kernel_matches_oracle(rng):
         prog = paged_attention_program(**cfg)
         kern = tl_compile(prog, Schedule(interpret=True), target="pallas")
         tbl, lens, q, kp, vp = parity_inputs(name, prog, rng)
-        out = np.asarray(kern(tbl, lens, q, kp, vp))
+        # the kernel packs Q/Output as (slots, kv_heads, group, d); the
+        # oracle takes (slots, heads, d)
+        out = np.asarray(kern(tbl, lens, q, kp, vp)).reshape(q.shape[0], -1, q.shape[-1])
         oracle = np.asarray(
-            ref.paged_attention(q, kp, vp, tbl, lens, window=cfg.get("window"))
+            ref.paged_attention(q.reshape(q.shape[0], -1, q.shape[-1]), kp, vp,
+                                tbl, lens, window=cfg.get("window"))
         )
         np.testing.assert_allclose(out, oracle, rtol=1e-4, atol=2e-3)
 
