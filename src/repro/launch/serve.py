@@ -19,7 +19,22 @@ import numpy as np
 from repro.configs import get_config
 from repro.launch import compile_cache
 from repro.models import lm
-from repro.serving import ServeConfig, ServingEngine
+from repro.serving import ServeConfig, ServingEngine, telemetry
+
+
+def dispatch_line(report) -> str:
+    """One line from the dispatch log (``telemetry.report``): host seconds
+    per phase, decode milliseconds per tick, prefill live-row share."""
+    if report is None:
+        return "dispatch log: cut short (the run outlasted the log)"
+    phases = ", ".join(f"{k} {v:.3f}" for k, v in report["phase_s"].items())
+    line = f"dispatch log: {report['dispatches']} programs; host s: {phases}"
+    if report["decode_ms_per_tick"] is not None:
+        line += f"; decode {report['decode_ms_per_tick']:.2f} ms/tick"
+    if report["prefill_rows"]:
+        live, rows = report["prefill_live_rows"], report["prefill_rows"]
+        line += f"; prefill live rows {live}/{rows} ({100.0 * live / rows:.1f}%)"
+    return line
 
 
 def main(argv=None):
@@ -115,8 +130,10 @@ def main(argv=None):
         engine.submit(prompt, deadline_ticks=args.deadline_ticks)
 
     t0 = time.time()
+    m0 = telemetry.clock()
     done = engine.run()
     dt = time.time() - t0
+    report = telemetry.report(m0, telemetry.clock())
     total_tokens = sum(len(r.output) for r in done)
     extra = ""
     if engine.pool is not None:
@@ -150,6 +167,7 @@ def main(argv=None):
         f"({total_tokens/max(dt,1e-9):.1f} tok/s, {engine.steps_run} engine steps"
         f" [{engine.prefill_mode} prefill]{extra})"
     )
+    print(dispatch_line(report))
     for r in done[:3]:
         print(f"  req {r.uid}: prompt {r.prompt[:4]}... -> {r.output[:8]}...")
     if not_completed or len(done) != args.requests:

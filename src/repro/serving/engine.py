@@ -107,6 +107,7 @@ from repro.kernels.ops import guard_dispatch
 from repro.models import lm
 from repro.models.config import ModelConfig
 
+from . import telemetry
 from .faults import FaultInjector, audit_engine
 from .paged_cache import (
     BlockPool,
@@ -161,14 +162,14 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
     def build():
         snap = copy.deepcopy(cfg)
 
-        def step(p, c, tok, pos, key, live, poison):
+        def decode_step(p, c, tok, pos, key, live, poison):
             logits, c = lm.decode_step(p, snap, c, tok, pos, live=live)
             logits = jnp.where(poison[:, None], jnp.nan, logits)
             bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
             tok, key = sample_step(logits, key, temperature=temperature)
             return tok, bad, c, key
 
-        return jax.jit(step, donate_argnums=(1,))
+        return jax.jit(decode_step, donate_argnums=(1,))
 
     return _cached_fn(("decode", repr(cfg), temperature), build)
 
@@ -183,14 +184,14 @@ def _prefill_step_fn(cfg: ModelConfig, temperature: float):
     def build():
         snap = copy.deepcopy(cfg)
 
-        def step(p, c, toks, pos, lens, key, poison):
+        def prefill_step(p, c, toks, pos, lens, key, poison):
             logits, c = lm.prefill_step(p, snap, c, toks, pos, lens)
             logits = jnp.where(poison[:, None], jnp.nan, logits)
             bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
             tok, key = sample_step(logits, key, temperature=temperature)
             return tok, bad, c, key
 
-        return jax.jit(step, donate_argnums=(1,))
+        return jax.jit(prefill_step, donate_argnums=(1,))
 
     return _cached_fn(("prefill", repr(cfg), temperature), build)
 
@@ -215,6 +216,7 @@ def _decode_loop_fn(cfg: ModelConfig, temperature: float, n_steps: int,
                 max_len=max_len,
             )
 
+        loop.__name__ = loop.__qualname__ = f"decode_window_{n_steps}"
         return jax.jit(loop, donate_argnums=(1,))
 
     return _cached_fn(
@@ -247,6 +249,7 @@ def _spec_loop_fn(cfg: ModelConfig, temperature: float, proposer: str,
                 max_len=max_len, poison=poison,
             )
 
+        loop.__name__ = loop.__qualname__ = f"spec_window_{n_rounds}"
         return jax.jit(loop, donate_argnums=(1,))
 
     return _cached_fn(
@@ -901,6 +904,33 @@ class ServingEngine:
             self.table_uploads += 1
         return self.cache
 
+    def _run_program(self, fn, inputs: Sequence, n_out: int,
+                     slots: Sequence[int], ticks: int = 1, rows: int = 0,
+                     live_rows: int = 0):
+        """Run one jitted step program through the dispatch log's phases:
+        upload (the block table when dirty, and the host arrays among
+        ``inputs``), dispatch ``fn(params, cache, *inputs)``, wait for its
+        outputs, read back the first ``n_out``.  Returns ``(host outputs,
+        the rest of the outputs, record)``: the rest are the new cache and
+        PRNG key in ``fn``'s order, still on the device.  The caller drains
+        under ``telemetry.span("drain", record)``."""
+        rec = telemetry.Dispatch(
+            fn.__name__, ticks, tuple(self.slot_req[s].uid for s in slots),
+            rows, live_rows,
+        )
+        with telemetry.span("upload", rec):
+            cache = self._fresh_cache()
+            args = [jnp.asarray(a) if isinstance(a, np.ndarray) else a
+                    for a in inputs]
+        with telemetry.span("dispatch", rec):
+            out = fn(self.params, cache, *args)
+        with telemetry.span("wait", rec):
+            jax.block_until_ready(out)
+        with telemetry.span("readback", rec):
+            host = [np.asarray(x) for x in out[:n_out]]
+        telemetry.LOG.append(rec)
+        return host, out[n_out:], rec
+
     def _gen_ready(self, s: int) -> bool:
         """Slot ``s`` is in steady-state generation: its next feed is its
         last known token and every later feed is a model output — exactly
@@ -923,22 +953,27 @@ class ServingEngine:
         generating, one dispatch runs up to ``sync_every`` decode ticks on
         device.  Cancellations and deadlines are honored before the
         dispatch; with ``ServeConfig.audit`` the invariant auditor runs
-        after it.  Returns #active slots."""
-        self._sweep_lifecycle()
-        n = self._step_inner()
-        if self.scfg.audit:
-            self.audits_run += 1
-            audit_engine(self)
+        after it.  Returns #active slots.  Each call and each device
+        program it runs go into the dispatch log (``serving.telemetry``)."""
+        t0 = telemetry.clock()
+        with telemetry.span("step"):
+            n = self._step_inner()
+            if self.scfg.audit:
+                self.audits_run += 1
+                audit_engine(self)
+        telemetry.LOG.append(telemetry.Step(t0, telemetry.clock()))
         return n
 
     def _step_inner(self) -> int:
-        self._admit()
-        if self.tables is not None:
-            for s in range(self.scfg.slots):
-                if self.slot_req[s] is not None:
-                    self._grow(s)
-            self._admit()  # preemption may have freed blocks for the queue head
-        active = [s for s in range(self.scfg.slots) if self.slot_req[s] is not None]
+        with telemetry.span("schedule"):
+            self._sweep_lifecycle()
+            self._admit()
+            if self.tables is not None:
+                for s in range(self.scfg.slots):
+                    if self.slot_req[s] is not None:
+                        self._grow(s)
+                self._admit()  # preemption may have freed blocks for the queue head
+            active = [s for s in range(self.scfg.slots) if self.slot_req[s] is not None]
         if not active:
             if self.queue and self.admission_open:
                 # every queued request is waiting out a retry backoff: the
@@ -1046,69 +1081,67 @@ class ServingEngine:
         drains one token buffer.  Returns #active slots, or ``None`` when
         the paged pool cannot cover the worst-case window (caller falls
         back to a per-tick step)."""
-        b = self.scfg.slots
-        feed = np.zeros((b,), np.int32)
-        live = np.zeros((b,), bool)
-        rem = np.zeros((b,), np.int32)
-        for s in active:
-            req = self.slot_req[s]
-            feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
-            live[s] = True
-            limit = req.max_new_tokens or self.scfg.max_new_tokens
-            rem[s] = limit - len(req.output)
-        # clamp the window to the slots' host-known tick spans — token
-        # allowance AND max_len headroom — by halving (not to the exact
-        # span: every distinct length is its own scan trace, so lengths are
-        # bounded to ~log2(sync_every) variants).  Guaranteed-dead tail
-        # iterations would burn full-batch decode steps and delay
-        # boundary-time admission of queued work.
-        n = self.sync_every
-        max_span = max(
-            min(int(rem[s]), self.scfg.max_len - int(self.pos[s]))
-            for s in active
-        )
-        while n // 2 >= max_span:
-            n //= 2
-        spans = {s: min(n, int(rem[s]) + 1) for s in active}
-        if not self._prepare_window(active, spans):
-            return None
-        loop = self._loop_fns.get(n)
-        if loop is None:
-            loop = self._loop_fns[n] = _decode_loop_fn(
-                self.cfg, self.scfg.temperature, n, self.scfg.eos_id,
-                self.scfg.max_len,
-            )
-        toks, emitted, self._key, self.cache = loop(
-            self.params, self._fresh_cache(), jnp.asarray(feed),
-            jnp.asarray(self.pos), self._key, jnp.asarray(live),
-            jnp.asarray(rem),
-        )
-        self.decode_windows += 1
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
-        # drain: replay each in-window tick through the same host-side
-        # bookkeeping the per-tick path runs, so Request state, tick
-        # accounting and EOS recycling stay byte-for-byte identical
-        for t in range(n):
-            row = emitted[t]
-            if not row.any():
-                break  # every slot stopped; later rows are all-False too
+        with telemetry.span("schedule"):
+            b = self.scfg.slots
+            feed = np.zeros((b,), np.int32)
+            live = np.zeros((b,), bool)
+            rem = np.zeros((b,), np.int32)
             for s in active:
-                if not row[s]:
-                    continue
                 req = self.slot_req[s]
-                self.pos[s] += 1
-                req._cursor += 1  # type: ignore[attr-defined]
-                self._emit_token(s, req, int(toks[t, s]))
-            self.tick_tokens.append(int(row.sum()))
-            self.steps_run += 1
-        if self.tables is not None:
-            # return unused grow-ahead pages so boundary-time admission /
-            # preemption sees the same pool a per-tick engine would
-            for s in active:
-                if self.slot_req[s] is not None:
-                    if self.tables.trim(s, int(self.pos[s]) + 1):
-                        self._tables_dirty = True
+                feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
+                live[s] = True
+                limit = req.max_new_tokens or self.scfg.max_new_tokens
+                rem[s] = limit - len(req.output)
+            # clamp the window to the slots' host-known tick spans — token
+            # allowance AND max_len headroom — by halving (not to the exact
+            # span: every distinct length is its own scan trace, so lengths
+            # are bounded to ~log2(sync_every) variants).  Guaranteed-dead
+            # tail iterations would burn full-batch decode steps and delay
+            # boundary-time admission of queued work.
+            n = self.sync_every
+            max_span = max(
+                min(int(rem[s]), self.scfg.max_len - int(self.pos[s]))
+                for s in active
+            )
+            while n // 2 >= max_span:
+                n //= 2
+            spans = {s: min(n, int(rem[s]) + 1) for s in active}
+            if not self._prepare_window(active, spans):
+                return None
+            loop = self._loop_fns.get(n)
+            if loop is None:
+                loop = self._loop_fns[n] = _decode_loop_fn(
+                    self.cfg, self.scfg.temperature, n, self.scfg.eos_id,
+                    self.scfg.max_len,
+                )
+        (toks, emitted), (self._key, self.cache), rec = self._run_program(
+            loop, (feed, self.pos, self._key, live, rem), 2, active, ticks=n,
+        )
+        with telemetry.span("drain", rec):
+            self.decode_windows += 1
+            # replay each in-window tick through the same host-side
+            # bookkeeping the per-tick path runs, so Request state, tick
+            # accounting and EOS recycling stay byte-for-byte identical
+            for t in range(n):
+                row = emitted[t]
+                if not row.any():
+                    break  # every slot stopped; later rows are all-False too
+                for s in active:
+                    if not row[s]:
+                        continue
+                    req = self.slot_req[s]
+                    self.pos[s] += 1
+                    req._cursor += 1  # type: ignore[attr-defined]
+                    self._emit_token(s, req, int(toks[t, s]))
+                self.tick_tokens.append(int(row.sum()))
+                self.steps_run += 1
+            if self.tables is not None:
+                # return unused grow-ahead pages so boundary-time admission /
+                # preemption sees the same pool a per-tick engine would
+                for s in active:
+                    if self.slot_req[s] is not None:
+                        if self.tables.trim(s, int(self.pos[s]) + 1):
+                            self._tables_dirty = True
         return len(active)
 
     # -- speculative draft-verify window --------------------------------
@@ -1125,115 +1158,113 @@ class ServingEngine:
         ``max_len`` headroom for even one round or the grant/COW/guard
         preamble declines (caller falls back to the plain window, which is
         byte-identical by construction)."""
-        scfg = self.scfg
-        k = scfg.draft_len
-        c = k + 1
-        b = scfg.slots
-        feed = np.zeros((b,), np.int32)
-        live = np.zeros((b,), bool)
-        rem = np.zeros((b,), np.int32)
-        for s in active:
-            req = self.slot_req[s]
-            feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
-            live[s] = True
-            limit = req.max_new_tokens or scfg.max_new_tokens
-            rem[s] = limit - len(req.output)
-
-        # a slot's worst-case write span over n rounds: every verify chunk
-        # lands c positions from the current pos, and a live round commits
-        # at least one token, so the furthest write is bounded both by
-        # n * c and by the token allowance plus one round's draft tail
-        def span(s: int, n: int) -> int:
-            return min(n * c, int(rem[s]) + k)
-
-        # clamp rounds by halving (each distinct n is its own scan trace):
-        # first to the emission spans, then until every slot's worst-case
-        # chunk write fits under max_len — unlike the plain window, a
-        # verify chunk writes ahead of what it commits, so headroom is a
-        # hard precondition, not an optimization
-        n = self.sync_every
-        max_rounds = max(
-            -(-min(int(rem[s]), scfg.max_len - int(self.pos[s])) // c)
-            for s in active
-        )
-        while n // 2 >= max_rounds:
-            n //= 2
-        while n > 1 and any(
-            int(self.pos[s]) + span(s, n) > scfg.max_len for s in active
-        ):
-            n //= 2
-        if any(int(self.pos[s]) + span(s, n) > scfg.max_len for s in active):
-            return None  # a slot within c of max_len: plain path finishes it
-        spans = {s: span(s, n) for s in active}
-        if not self._prepare_window(active, spans):
-            return None
-
-        hist = np.zeros((b, scfg.max_len), np.int32)
-        for s in active:
-            req = self.slot_req[s]
-            toks = req.prompt + req.output
-            hist[s, : len(toks)] = toks
-        poison = self._poison_mask(active, site="spec_poison")
-
-        loop = self._spec_loop_fns.get(n)
-        if loop is None:
-            loop = self._spec_loop_fns[n] = _spec_loop_fn(
-                self.cfg, scfg.temperature, self.spec_proposer, n, k,
-                scfg.eos_id, scfg.max_len,
-            )
-        toks, emitted, bad, self._key, self.cache = loop(
-            self.params, self._fresh_cache(), jnp.asarray(feed),
-            jnp.asarray(self.pos), self._key, jnp.asarray(live),
-            jnp.asarray(rem), jnp.asarray(hist), jnp.asarray(poison),
-        )
-        self.spec_windows += 1
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
-        bad = np.asarray(bad)
-        # drain: replay each round through the same host-side bookkeeping
-        # the per-tick path runs — the device emit masks already encode
-        # acceptance, EOS, token limits and max_len, so _emit_token's stop
-        # conditions fire on exactly the tokens the mask delivers
-        for t in range(n):
-            row = emitted[t]
-            rbad = bad[t]
-            if not row.any() and not rbad.any():
-                break  # every slot stopped; later rounds are dead too
-            self.spec_rounds += 1
+        with telemetry.span("schedule"):
+            scfg = self.scfg
+            k = scfg.draft_len
+            c = k + 1
+            b = scfg.slots
+            feed = np.zeros((b,), np.int32)
+            live = np.zeros((b,), bool)
+            rem = np.zeros((b,), np.int32)
             for s in active:
                 req = self.slot_req[s]
-                if req is None:
-                    continue
-                if rbad[s]:
-                    self.poisoned_rows += 1
-                    self._terminate(
-                        req, FAILED, slot=s,
-                        error="poisoned verify logits (no finite value)")
-                    continue
-                if not row[s].any():
-                    continue
-                acc = int(row[s].sum()) - 1  # drafts accepted this round
-                self.spec_proposed += k
-                self.spec_accepted += acc
-                if acc == 0:
-                    self.spec_all_rejected += 1
-                for i in range(c):
-                    if not row[s, i]:
-                        continue
-                    self.pos[s] += 1
-                    req._cursor += 1  # type: ignore[attr-defined]
-                    self._emit_token(s, req, int(toks[t, s, i]))
-                    if req.done:
-                        break
-            self.tick_tokens.append(int(row.sum()))
-            self.steps_run += 1
-        if self.tables is not None:
-            # rejected draft tails sit in pages past pos under the
-            # grow-ahead grant; trim reclaims them with the unused grant
+                feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
+                live[s] = True
+                limit = req.max_new_tokens or scfg.max_new_tokens
+                rem[s] = limit - len(req.output)
+
+            # a slot's worst-case write span over n rounds: every verify chunk
+            # lands c positions from the current pos, and a live round commits
+            # at least one token, so the furthest write is bounded both by
+            # n * c and by the token allowance plus one round's draft tail
+            def span(s: int, n: int) -> int:
+                return min(n * c, int(rem[s]) + k)
+
+            # clamp rounds by halving (each distinct n is its own scan trace):
+            # first to the emission spans, then until every slot's worst-case
+            # chunk write fits under max_len — unlike the plain window, a
+            # verify chunk writes ahead of what it commits, so headroom is a
+            # hard precondition, not an optimization
+            n = self.sync_every
+            max_rounds = max(
+                -(-min(int(rem[s]), scfg.max_len - int(self.pos[s])) // c)
+                for s in active
+            )
+            while n // 2 >= max_rounds:
+                n //= 2
+            while n > 1 and any(
+                int(self.pos[s]) + span(s, n) > scfg.max_len for s in active
+            ):
+                n //= 2
+            if any(int(self.pos[s]) + span(s, n) > scfg.max_len for s in active):
+                return None  # a slot within c of max_len: plain path finishes it
+            spans = {s: span(s, n) for s in active}
+            if not self._prepare_window(active, spans):
+                return None
+
+            hist = np.zeros((b, scfg.max_len), np.int32)
             for s in active:
-                if self.slot_req[s] is not None:
-                    if self.tables.trim(s, int(self.pos[s]) + 1):
-                        self._tables_dirty = True
+                req = self.slot_req[s]
+                toks = req.prompt + req.output
+                hist[s, : len(toks)] = toks
+            poison = self._poison_mask(active, site="spec_poison")
+
+            loop = self._spec_loop_fns.get(n)
+            if loop is None:
+                loop = self._spec_loop_fns[n] = _spec_loop_fn(
+                    self.cfg, scfg.temperature, self.spec_proposer, n, k,
+                    scfg.eos_id, scfg.max_len,
+                )
+        (toks, emitted, bad), (self._key, self.cache), rec = self._run_program(
+            loop, (feed, self.pos, self._key, live, rem, hist, poison), 3,
+            active, ticks=n,
+        )
+        with telemetry.span("drain", rec):
+            self.spec_windows += 1
+            # replay each round through the same host-side bookkeeping
+            # the per-tick path runs — the device emit masks already encode
+            # acceptance, EOS, token limits and max_len, so _emit_token's stop
+            # conditions fire on exactly the tokens the mask delivers
+            for t in range(n):
+                row = emitted[t]
+                rbad = bad[t]
+                if not row.any() and not rbad.any():
+                    break  # every slot stopped; later rounds are dead too
+                self.spec_rounds += 1
+                for s in active:
+                    req = self.slot_req[s]
+                    if req is None:
+                        continue
+                    if rbad[s]:
+                        self.poisoned_rows += 1
+                        self._terminate(
+                            req, FAILED, slot=s,
+                            error="poisoned verify logits (no finite value)")
+                        continue
+                    if not row[s].any():
+                        continue
+                    acc = int(row[s].sum()) - 1  # drafts accepted this round
+                    self.spec_proposed += k
+                    self.spec_accepted += acc
+                    if acc == 0:
+                        self.spec_all_rejected += 1
+                    for i in range(c):
+                        if not row[s, i]:
+                            continue
+                        self.pos[s] += 1
+                        req._cursor += 1  # type: ignore[attr-defined]
+                        self._emit_token(s, req, int(toks[t, s, i]))
+                        if req.done:
+                            break
+                self.tick_tokens.append(int(row.sum()))
+                self.steps_run += 1
+            if self.tables is not None:
+                # rejected draft tails sit in pages past pos under the
+                # grow-ahead grant; trim reclaims them with the unused grant
+                for s in active:
+                    if self.slot_req[s] is not None:
+                        if self.tables.trim(s, int(self.pos[s]) + 1):
+                            self._tables_dirty = True
         return len(active)
 
     # -- prefix-cache bookkeeping ---------------------------------------
@@ -1428,151 +1459,153 @@ class ServingEngine:
         for i, (a, b) in enumerate(pairs):
             src[i] = a
             dst[i] = b
-        self.cache = _copy_pages_fn(self.cfg)(
-            self.cache, jnp.asarray(src), jnp.asarray(dst)
-        )
+        # the copy's outputs stay on the device: it has no wait or readback
+        rec = telemetry.Dispatch("copy_pages", 0, ())
+        with telemetry.span("upload", rec):
+            src, dst = jnp.asarray(src), jnp.asarray(dst)
+        with telemetry.span("dispatch", rec):
+            self.cache = _copy_pages_fn(self.cfg)(self.cache, src, dst)
+        telemetry.LOG.append(rec)
 
     # -- per-tick paths -------------------------------------------------
     def _step_replay(self, active: List[int]) -> int:
-        if self.tables is not None:
-            active, pairs = self._cow_or_preempt(
-                [(s, int(self.pos[s])) for s in active]
-            )
-            self._apply_cow(pairs)
-            active = [s for s, _ in self._guard_work([(s, 1) for s in active])]
-            if not active:
-                self.dispatches -= 1  # nothing actually dispatched
-                return 0
-        feed = np.zeros((self.scfg.slots,), np.int32)
-        live = np.zeros((self.scfg.slots,), bool)
-        full_len: Dict[int, int] = {}
-        for s in active:
-            req = self.slot_req[s]
-            cur = req._cursor  # type: ignore[attr-defined]
-            np_ = len(req.prompt)
-            full_len[s] = np_ + len(req.output)
-            feed[s] = (
-                req.prompt[cur] if cur < np_ else req.output[cur - np_]
-            )
-            live[s] = True
-        poison = self._poison_mask(active)
-        next_tok, bad, self.cache, self._key = self._step(
-            self.params, self._fresh_cache(), jnp.asarray(feed),
-            jnp.asarray(self.pos), self._key, jnp.asarray(live),
-            jnp.asarray(poison),
+        with telemetry.span("schedule"):
+            if self.tables is not None:
+                active, pairs = self._cow_or_preempt(
+                    [(s, int(self.pos[s])) for s in active]
+                )
+                self._apply_cow(pairs)
+                active = [s for s, _ in self._guard_work([(s, 1) for s in active])]
+                if not active:
+                    self.dispatches -= 1  # nothing actually dispatched
+                    return 0
+            feed = np.zeros((self.scfg.slots,), np.int32)
+            live = np.zeros((self.scfg.slots,), bool)
+            full_len: Dict[int, int] = {}
+            for s in active:
+                req = self.slot_req[s]
+                cur = req._cursor  # type: ignore[attr-defined]
+                np_ = len(req.prompt)
+                full_len[s] = np_ + len(req.output)
+                feed[s] = (
+                    req.prompt[cur] if cur < np_ else req.output[cur - np_]
+                )
+                live[s] = True
+            poison = self._poison_mask(active)
+        (next_tok, bad), (self.cache, self._key), rec = self._run_program(
+            self._step, (feed, self.pos, self._key, live, poison), 2, active,
         )
-        next_tok = np.asarray(next_tok)
-        bad = np.asarray(bad)
-        for s in active:
-            req = self.slot_req[s]
-            cur = req._cursor  # type: ignore[attr-defined]
-            self.pos[s] += 1
-            req._cursor = cur + 1  # type: ignore[attr-defined]
-            if bad[s]:
-                self.poisoned_rows += 1
-                self._terminate(req, FAILED, slot=s,
-                                error="poisoned logits row (no finite value)")
-                continue
-            if cur + 1 >= full_len[s]:  # this step produced a real token
-                self._register_prefix(s, req)
-                self._emit_token(s, req, int(next_tok[s]))
-        self.tick_tokens.append(len(active))
-        self.steps_run += 1
+        with telemetry.span("drain", rec):
+            for s in active:
+                req = self.slot_req[s]
+                cur = req._cursor  # type: ignore[attr-defined]
+                self.pos[s] += 1
+                req._cursor = cur + 1  # type: ignore[attr-defined]
+                if bad[s]:
+                    self.poisoned_rows += 1
+                    self._terminate(req, FAILED, slot=s,
+                                    error="poisoned logits row (no finite value)")
+                    continue
+                if cur + 1 >= full_len[s]:  # this step produced a real token
+                    self._register_prefix(s, req)
+                    self._emit_token(s, req, int(next_tok[s]))
+            self.tick_tokens.append(len(active))
+            self.steps_run += 1
         return len(active)
 
     def _step_chunked(self, active: List[int]) -> int:
         """One token-budget tick: decode for generating slots + prompt
         chunks for prefilling slots (oldest admitted first) within the
-        leftover budget."""
-        gen = [s for s in active if self.slot_state[s] == "gen"]
-        pending = []
-        for s in active:
-            if self.slot_state[s] != "prefill":
-                continue
-            req = self.slot_req[s]
-            remaining = len(req.prompt) + len(req.output) - req._cursor  # type: ignore[attr-defined]
-            pending.append((s, req._admit_seq, remaining))  # type: ignore[attr-defined]
-        chunk_lens = plan_prefill_chunks(
-            self.token_budget, len(gen), pending, self.prefill_chunk
-        )
-
-        if gen and self.tables is not None:
-            gen, pairs = self._cow_or_preempt(
-                [(s, int(self.pos[s])) for s in gen]
+        leftover budget: up to two device programs, ``decode_step`` then
+        ``prefill_step``."""
+        with telemetry.span("schedule"):
+            gen = [s for s in active if self.slot_state[s] == "gen"]
+            pending = []
+            for s in active:
+                if self.slot_state[s] != "prefill":
+                    continue
+                req = self.slot_req[s]
+                remaining = len(req.prompt) + len(req.output) - req._cursor  # type: ignore[attr-defined]
+                pending.append((s, req._admit_seq, remaining))  # type: ignore[attr-defined]
+            chunk_lens = plan_prefill_chunks(
+                self.token_budget, len(gen), pending, self.prefill_chunk
             )
-            self._apply_cow(pairs)
-            gen = [s for s, _ in self._guard_work([(s, 1) for s in gen])]
+            if gen and self.tables is not None:
+                gen, pairs = self._cow_or_preempt(
+                    [(s, int(self.pos[s])) for s in gen]
+                )
+                self._apply_cow(pairs)
+                gen = [s for s, _ in self._guard_work([(s, 1) for s in gen])]
+            if gen:
+                feed = np.zeros((self.scfg.slots,), np.int32)
+                live = np.zeros((self.scfg.slots,), bool)
+                for s in gen:
+                    req = self.slot_req[s]
+                    feed[s] = req.output[-1]
+                    live[s] = True
+                poison = self._poison_mask(gen)
         if gen:
-            feed = np.zeros((self.scfg.slots,), np.int32)
-            live = np.zeros((self.scfg.slots,), bool)
-            for s in gen:
-                req = self.slot_req[s]
-                feed[s] = req.output[-1]
-                live[s] = True
-            poison = self._poison_mask(gen)
-            next_tok, bad, self.cache, self._key = self._step(
-                self.params, self._fresh_cache(), jnp.asarray(feed),
-                jnp.asarray(self.pos), self._key, jnp.asarray(live),
-                jnp.asarray(poison),
+            (next_tok, bad), (self.cache, self._key), rec = self._run_program(
+                self._step, (feed, self.pos, self._key, live, poison), 2, gen,
             )
-            next_tok = np.asarray(next_tok)
-            bad = np.asarray(bad)
-            for s in gen:
-                req = self.slot_req[s]
-                self.pos[s] += 1
-                req._cursor += 1  # type: ignore[attr-defined]
-                if bad[s]:
-                    self.poisoned_rows += 1
-                    self._terminate(
-                        req, FAILED, slot=s,
-                        error="poisoned logits row (no finite value)")
-                    continue
-                self._emit_token(s, req, int(next_tok[s]))
+            with telemetry.span("drain", rec):
+                for s in gen:
+                    req = self.slot_req[s]
+                    self.pos[s] += 1
+                    req._cursor += 1  # type: ignore[attr-defined]
+                    if bad[s]:
+                        self.poisoned_rows += 1
+                        self._terminate(
+                            req, FAILED, slot=s,
+                            error="poisoned logits row (no finite value)")
+                        continue
+                    self._emit_token(s, req, int(next_tok[s]))
 
-        # COW during the gen dispatch may have preempted a prefilling slot
-        chunk_lens = {s: n for s, n in chunk_lens.items()
-                      if self.slot_req[s] is not None}
-        if chunk_lens and self.tables is not None:
-            ok, pairs = self._cow_or_preempt(
-                [(s, int(self.pos[s]) + n - 1) for s, n in chunk_lens.items()]
-            )
-            chunk_lens = {s: chunk_lens[s] for s in ok}
-            self._apply_cow(pairs)
-            chunk_lens = dict(self._guard_work(list(chunk_lens.items())))
+        with telemetry.span("schedule"):
+            # COW during the gen dispatch may have preempted a prefilling slot
+            chunk_lens = {s: n for s, n in chunk_lens.items()
+                          if self.slot_req[s] is not None}
+            if chunk_lens and self.tables is not None:
+                ok, pairs = self._cow_or_preempt(
+                    [(s, int(self.pos[s]) + n - 1) for s, n in chunk_lens.items()]
+                )
+                chunk_lens = {s: chunk_lens[s] for s in ok}
+                self._apply_cow(pairs)
+                chunk_lens = dict(self._guard_work(list(chunk_lens.items())))
+            if chunk_lens:
+                width = self.prefill_chunk
+                toks = np.zeros((self.scfg.slots, width), np.int32)
+                lens = np.zeros((self.scfg.slots,), np.int32)
+                for s, n in chunk_lens.items():
+                    req = self.slot_req[s]
+                    cur = req._cursor  # type: ignore[attr-defined]
+                    replay = (req.prompt + req.output)[cur : cur + n]
+                    toks[s, :n] = replay
+                    lens[s] = n
+                poison = self._poison_mask(sorted(chunk_lens))
         if chunk_lens:
-            width = self.prefill_chunk
-            toks = np.zeros((self.scfg.slots, width), np.int32)
-            lens = np.zeros((self.scfg.slots,), np.int32)
-            for s, n in chunk_lens.items():
-                req = self.slot_req[s]
-                cur = req._cursor  # type: ignore[attr-defined]
-                replay = (req.prompt + req.output)[cur : cur + n]
-                toks[s, :n] = replay
-                lens[s] = n
-            poison = self._poison_mask(sorted(chunk_lens))
-            ptok, pbad, self.cache, self._key = self._prefill(
-                self.params, self._fresh_cache(), jnp.asarray(toks),
-                jnp.asarray(self.pos), jnp.asarray(lens), self._key,
-                jnp.asarray(poison),
+            (ptok, pbad), (self.cache, self._key), rec = self._run_program(
+                self._prefill, (toks, self.pos, lens, self._key, poison), 2,
+                sorted(chunk_lens), rows=toks.size,
+                live_rows=sum(chunk_lens.values()),
             )
-            ptok = np.asarray(ptok)
-            pbad = np.asarray(pbad)
-            for s, n in chunk_lens.items():
-                req = self.slot_req[s]
-                self.pos[s] += n
-                req._cursor += n  # type: ignore[attr-defined]
-                if pbad[s]:
-                    self.poisoned_rows += 1
-                    self._terminate(
-                        req, FAILED, slot=s,
-                        error="poisoned logits row (no finite value)")
-                    continue
-                if req._cursor >= len(req.prompt) + len(req.output):  # type: ignore[attr-defined]
-                    # the chunk reached the end of the replay stream: its
-                    # last live logits produce the next real token
-                    self.slot_state[s] = "gen"
-                    self._register_prefix(s, req)
-                    self._emit_token(s, req, int(ptok[s]))
+            with telemetry.span("drain", rec):
+                for s, n in chunk_lens.items():
+                    req = self.slot_req[s]
+                    self.pos[s] += n
+                    req._cursor += n  # type: ignore[attr-defined]
+                    if pbad[s]:
+                        self.poisoned_rows += 1
+                        self._terminate(
+                            req, FAILED, slot=s,
+                            error="poisoned logits row (no finite value)")
+                        continue
+                    if req._cursor >= len(req.prompt) + len(req.output):  # type: ignore[attr-defined]
+                        # the chunk reached the end of the replay stream:
+                        # its last live logits produce the next real token
+                        self.slot_state[s] = "gen"
+                        self._register_prefix(s, req)
+                        self._emit_token(s, req, int(ptok[s]))
 
         self.tick_tokens.append(len(gen) + sum(chunk_lens.values()))
         self.steps_run += 1
