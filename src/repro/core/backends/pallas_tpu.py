@@ -7,6 +7,17 @@ axis, so the hardware DMA double-buffers them and overlaps with compute
 exactly like cp.async/TMA rings on GPUs.  Fragment buffers become VMEM
 scratch accumulators persisting across the ``arbitrary`` axis.
 
+A *bounded* ``T.Pipelined`` loop (its extent a slot's live length, read
+from a scalar-prefetch param) gets no grid axis.  Each grid cell runs PRE
+once, then an in-kernel ``lax.fori_loop`` over its live range only, with
+the loop's tiles DMA'd by hand from operands left in HBM into
+``num_stages``-deep VMEM rings, the next tile in flight while this one is
+computed, and fragments carried through the loop; then POST once.  Where
+Mosaic cannot copy a loop tile by hand (``lowering.grid.walks_in_kernel``)
+the bounded loop lowers as a static loop over its bound: every step runs,
+and the kernel's mask discards the steps outside the live range.  Static
+loops lower as before, to the same kernel text.
+
 With ``schedule.interpret=True`` the same kernel body executes on CPU for
 validation; on a TPU host it is the Mosaic-compiled kernel.
 """
@@ -78,6 +89,16 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
     aliased_js = [j for j, w in enumerate(out_windows) if w.aliased]
     n_in_ops = len(in_windows)
 
+    # ---- a bounded loop walked in the kernel: its tiles are DMA'd by hand --
+    walks = plan.walk
+    manual = [i for i, w in enumerate(in_windows) if walks and w.phase == LOOP]
+    if walks and any(w.phase == LOOP for w in out_windows):
+        raise LoweringError(
+            f"{program.name}: a store inside the bounded loop "
+            f"{pipe.var.name}; store after the loop instead"
+        )
+    depth = max(2, module.num_stages)  # the VMEM plan's buffer count
+
     # ---- scalar-prefetch operands ----------------------------------------
     # T.ScalarTensor params ride ahead of the grid walk in SMEM
     # (PrefetchScalarGridSpec); every index map then receives their refs as
@@ -96,7 +117,9 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
 
     # ---- specs -----------------------------------------------------------
     in_specs = [
-        pl.BlockSpec(w.block_shape, _index_map(w.region)) for w in in_windows
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM) if i in manual
+        else pl.BlockSpec(w.block_shape, _index_map(w.region))
+        for i, w in enumerate(in_windows)
     ]
     alias_in_specs = [
         pl.BlockSpec(
@@ -115,6 +138,13 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
     scratch_shapes = [
         pltpu.VMEM(b.shape, jnp.dtype(b.dtype)) for b in scratch_bufs
     ]
+    # a ring of ``depth`` tiles per DMA'd window (its block less the
+    # collapsed dims), and a DMA semaphore per ring slot
+    scratch_shapes += [
+        pltpu.VMEM((depth,) + in_windows[i].onchip.shape,
+                   jnp.dtype(in_windows[i].param.dtype))
+        for i in manual
+    ] + [pltpu.SemaphoreType.DMA((depth,)) for _ in manual]
     # alias operand indices are positional over *all* pallas_call inputs —
     # scalar-prefetch operands included.  Cross-check against the verifier's
     # canonical wiring: a drift between the operand list assembled here and
@@ -139,6 +169,10 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
         in_refs = refs[:n_in_total]
         out_refs = refs[n_in_total : n_in_total + len(out_windows)]
         scr_refs = refs[n_in_total + len(out_windows) :]
+        n_scr = len(scratch_bufs)
+        rings = dict(zip(manual, scr_refs[n_scr : n_scr + len(manual)]))
+        sems = dict(zip(manual, scr_refs[n_scr + len(manual) :]))
+        cursor: Dict[str, Any] = {}  # "slot": the ring slot of the current k
 
         grid_ids = tuple(pl.program_id(d) for d in range(len(grid)))
         env_scalars = env_builder(*grid_ids)
@@ -165,8 +199,11 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
                     "must be read element by element"
                 )
             if buf.name in window_of:
-                w = in_windows[window_of[buf.name]]
-                val = squeeze(in_refs[window_of[buf.name]][...], w.region)
+                i = window_of[buf.name]
+                if i in rings:
+                    val = rings[i][cursor["slot"]]
+                else:
+                    val = squeeze(in_refs[i][...], in_windows[i].region)
                 val = val.astype(jnp.dtype(buf.dtype))
                 values[buf.name] = val
                 return val
@@ -214,6 +251,9 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
                 )
             return scalar_refs[scalar_pos[buf.name]][idx]
 
+        def scalar_loads(buf, idx_values, idx_exprs):
+            return load_scalar(buf, idx_values)
+
         def scalar_env():
             return dict(env_scalars)
 
@@ -256,6 +296,9 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
             return squeeze(val, region)
 
         def ref_slice(buf: TileBuffer, slices):
+            if window_of.get(buf.name) in rings:
+                ring = rings[window_of[buf.name]]
+                return ring[(cursor["slot"], *slices)].astype(jnp.dtype(buf.dtype))
             if buf.name in window_of:
                 w = in_windows[window_of[buf.name]]
                 it = iter(slices)
@@ -518,17 +561,80 @@ def emit_pallas(module: LoweredModule) -> CompiledKernel:
                 else:
                     raise LoweringError(f"Unhandled op {op!r}")
 
+        def tile_copy(i, k, slot):
+            """The DMA of loop step ``k``'s tile of window ``i`` into ring
+            slot ``slot`` (the start and the wait build the same copy)."""
+            w = in_windows[i]
+            starts = [eval_expr(e, {pipe.var.name: k}, scalar_loads)
+                      for e in w.region.starts]
+            idx = [
+                st if c else None if n == full else pl.ds(st, n)
+                for st, n, c, full in zip(starts, w.region.sizes,
+                                          w.region.collapsed, w.param.shape)
+            ]
+            while idx and idx[-1] is None:  # whole minor dims: no slice
+                idx.pop()
+            idx = tuple(slice(None) if x is None else x for x in idx)
+            return pltpu.make_async_copy(
+                in_refs[i].at[idx], rings[i].at[slot], sems[i].at[slot]
+            )
+
+        def run_walk():
+            """The bounded loop: ``k`` over this cell's live range, the
+            next ``depth - 1`` tiles in flight, fragments carried."""
+            lo, hi = (eval_expr(e, {}, scalar_loads) for e in pipe.bounds)
+            lo = jnp.maximum(jnp.asarray(lo, jnp.int32), 0)
+            hi = jnp.minimum(jnp.asarray(hi, jnp.int32), kext)
+            carried = sorted(
+                {b.name: b for op in pipe.body for b in op.buffers_written()
+                 if b.name in scratch_pos}.items()
+            )
+            names = [n for n, _ in carried]
+
+            def prefetch(k):
+                @pl.when(k < hi)
+                def _():
+                    for i in manual:
+                        tile_copy(i, k, k % depth).start()
+
+            for d in range(depth - 1):
+                prefetch(lo + d)
+
+            def step(k, carry):
+                outer, outer_dirty = dict(values), set(dirty)
+                values.update(zip(names, carry))
+                dirty.update(names)
+                prefetch(k + depth - 1)
+                cursor["slot"] = k % depth
+                for i in manual:
+                    tile_copy(i, k, cursor["slot"]).wait()
+                run_ops(pipe.body, LOOP, {pipe.var.name: k})
+                out = tuple(get(b) for _, b in carried)
+                values.clear()
+                values.update(outer)
+                dirty.clear()
+                dirty.update(outer_dirty)
+                return out
+
+            init = tuple(get(b) for _, b in carried)
+            values.update(zip(names, jax.lax.fori_loop(lo, hi, step, init)))
+            dirty.update(names)
+
         run_ops(phases.pre, PRE, {})
-        if pipe is not None:
+        if walks:
+            run_walk()
+        elif pipe is not None:
             run_ops(pipe.body, LOOP, {})
         run_ops(phases.post, POST, {})
 
         # write back dirty scratch accumulators, in a fixed order: the
-        # kernel's text is part of the persistent compile cache's key
-        for name in sorted(dirty):
-            scr_refs[scratch_pos[name]][...] = values[name].astype(
-                scr_refs[scratch_pos[name]].dtype
-            )
+        # kernel's text is part of the persistent compile cache's key.  A
+        # walk's cell starts afresh: nothing carries over to the next.
+        if not walks:
+            for name in sorted(dirty):
+                scr_refs[scratch_pos[name]][...] = values[name].astype(
+                    scr_refs[scratch_pos[name]].dtype
+                )
 
     # the limit plan_vmem checked against, so Mosaic's smaller scoped
     # default cannot refuse a kernel the planner accepted

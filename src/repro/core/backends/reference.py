@@ -276,8 +276,21 @@ def _emit(module: LoweredModule, sanitize: bool) -> CompiledKernel:
 
             run(phases.pre, {})
             if pipe is not None:
-                for k in range(pipe.extent):
-                    run(pipe.body, {pipe.var.name: k})
+                lo, hi = _loop_range(pipe, globals_, env0)
+                if isinstance(lo, int) and isinstance(hi, int):
+                    for k in range(lo, hi):
+                        run(pipe.body, {pipe.var.name: k})
+                else:
+                    # bounds traced (under jit): walk to the bound, keeping
+                    # a step's writes only where it lies in [lo, hi)
+                    for k in range(pipe.extent):
+                        before = dict(tiles), dict(globals_)
+                        run(pipe.body, {pipe.var.name: k})
+                        live = (k >= lo) & (k < hi)
+                        for state, old in zip((tiles, globals_), before):
+                            for name, val in state.items():
+                                if name in old and val is not old[name]:
+                                    state[name] = jnp.where(live, val, old[name])
             run(phases.post, {})
         if san is not None:
             san.finalize(globals_, jnp)
@@ -298,6 +311,25 @@ def _emit(module: LoweredModule, sanitize: bool) -> CompiledKernel:
     return CompiledKernel(
         program, fn, info, arg_params, out_params, backend=backend
     )
+
+
+def _loop_range(pipe, globals_: Dict, env: Dict):
+    """The ``k`` range one grid cell walks: ``[0, extent)`` for a static
+    loop; a bounded loop's ``[max(start, 0), min(stop, extent))`` as
+    Python ints where the scalar-prefetch params are concrete, else its
+    traced ``(start, stop)`` (the caller then masks the steps outside)."""
+    if pipe.bounds is None:
+        return 0, pipe.extent
+
+    def load(buffer, idx_values, idx_exprs):
+        _check_scalar_index(buffer, idx_values)
+        return globals_[buffer.name][tuple(idx_values)]
+
+    lo, hi = (evaluate(e, env, load) for e in pipe.bounds)
+    lo_i, hi_i = _as_int(lo), _as_int(hi)
+    if lo_i is None or hi_i is None:
+        return lo, hi
+    return max(lo_i, 0), min(hi_i, pipe.extent)
 
 
 @register_backend("reference")

@@ -44,9 +44,10 @@ def estimate_cost(
     out_windows: List[Window],
     vmem,
 ) -> KernelCost:
-    total_steps = int(np.prod(grid))
     pipe = phases.pipeline
-    cells = total_steps // (pipe.extent if pipe is not None else 1)
+    # a bounded loop is costed at its bound (the worst case)
+    cells = int(np.prod([e for _, e in program.grid_axes]))
+    total_steps = cells * (pipe.extent if pipe is not None else 1)
 
     flops = 0
 

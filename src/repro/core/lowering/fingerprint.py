@@ -120,9 +120,12 @@ def _ser_op(op: TileOp, c: _Canon, out: List[str]) -> None:
             out.append(f"  st({c.buf(buf)},[{sidx}],{_ser_expr(val, c)})")
         out.append(")")
     elif isinstance(op, PipelinedOp):
+        live = ""
+        if op.bounds is not None:  # static loops keep their key unchanged
+            live = ",[" + ",".join(_ser_expr(e, c) for e in op.bounds) + ")"
         out.append(
             f"pipelined({c.var(op.var.name)},{op.extent},{op.num_stages},"
-            f"{op.order},{op.stage}]("
+            f"{op.order},{op.stage}{live}]("
         )
         for o in op.body:
             _ser_op(o, c, out)

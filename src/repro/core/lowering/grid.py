@@ -2,7 +2,10 @@
 
 Kernel axes are reversed so the first-declared axis (``bx``) is the
 fastest-varying parallel dimension (CUDA blockIdx.x convention), and the
-pipelined axis is innermost overall so accumulators stay resident.  An
+pipelined axis is innermost overall so accumulators stay resident.  A
+bounded ``T.Pipelined`` loop (``PipelinedOp.bounds``) gets no grid axis
+where it can walk inside the kernel (:func:`walks_in_kernel`): each cell
+then walks its own live range.  An
 active ``T.use_swizzle`` flattens a 2-D parallel grid into one panel-raster
 axis (see schedule.swizzle_decode).
 """
@@ -12,8 +15,9 @@ import dataclasses
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..layout import LANE
 from ..schedule import Schedule, swizzle_decode, validate_swizzle
-from .phases import Phases
+from .phases import LOOP, Phases
 
 
 @dataclasses.dataclass
@@ -22,9 +26,22 @@ class GridPlan:
     env_builder: Callable[..., Dict[str, Any]]
     kdim: Optional[int]  # grid position of the pipelined ("arbitrary") axis
     dimension_semantics: Tuple[str, ...]
+    walk: bool = False  # a bounded loop walked inside the kernel
 
 
-def plan_grid(program, phases: Phases, schedule: Schedule) -> GridPlan:
+def walks_in_kernel(phases: Phases, in_windows) -> bool:
+    """A bounded loop runs inside the kernel, its tiles DMA'd by hand, when
+    every tile it reads has a lane-aligned minor dim: Mosaic slices an HBM
+    operand only along whole (sublane, lane) tiles, so a ``(page_size, 1)``
+    scale column cannot be copied by hand.  Otherwise the loop lowers as a
+    static one, a grid axis over its bound."""
+    pipe = phases.pipeline
+    return pipe is not None and pipe.bounds is not None and all(
+        w.region.sizes[-1] % LANE == 0 for w in in_windows if w.phase == LOOP
+    )
+
+
+def plan_grid(program, phases: Phases, schedule: Schedule, in_windows=()) -> GridPlan:
     kernel_axes = program.grid_axes  # declaration order
     n = len(kernel_axes)
     swz = schedule.grid_swizzle
@@ -32,8 +49,10 @@ def plan_grid(program, phases: Phases, schedule: Schedule) -> GridPlan:
         swz = program.annotations.swizzle
 
     pipe = phases.pipeline
-    kext = pipe.extent if pipe is not None else None
-    kname = pipe.var.name if pipe is not None else None
+    walk = walks_in_kernel(phases, in_windows)
+    on_grid = pipe is not None and not walk
+    kext = pipe.extent if on_grid else None
+    kname = pipe.var.name if on_grid else None
 
     if swz is not None and n == 2:
         (v0, e0), (v1, e1) = kernel_axes
@@ -57,7 +76,7 @@ def plan_grid(program, phases: Phases, schedule: Schedule) -> GridPlan:
             return env
 
         kdim = 1 if kext else None
-        return _with_override(grid, env_builder, kdim, sem, schedule)
+        return _with_override(grid, env_builder, kdim, sem, schedule, walk)
 
     grid = tuple(e for _, e in reversed(kernel_axes)) + ((kext,) if kext else ())
     sem = ("parallel",) * n + (("arbitrary",) if kext else ())
@@ -71,10 +90,11 @@ def plan_grid(program, phases: Phases, schedule: Schedule) -> GridPlan:
         return env
 
     kdim = n if kext else None
-    return _with_override(grid, env_builder, kdim, sem, schedule)
+    return _with_override(grid, env_builder, kdim, sem, schedule, walk)
 
 
-def _with_override(grid, env_builder, kdim, sem, schedule: Schedule) -> GridPlan:
+def _with_override(grid, env_builder, kdim, sem, schedule: Schedule,
+                   walk: bool) -> GridPlan:
     if schedule.dimension_semantics is not None:
         sem = tuple(schedule.dimension_semantics)
-    return GridPlan(grid, env_builder, kdim, sem)
+    return GridPlan(grid, env_builder, kdim, sem, walk)

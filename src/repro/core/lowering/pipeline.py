@@ -49,7 +49,7 @@ def pass_collect_windows(m: LoweredModule) -> None:
 
 
 def pass_plan_grid(m: LoweredModule) -> None:
-    m.grid_plan = plan_grid(m.program, m.phases, m.schedule)
+    m.grid_plan = plan_grid(m.program, m.phases, m.schedule, m.in_windows)
 
 
 def pass_plan_stages(m: LoweredModule) -> None:

@@ -29,6 +29,9 @@ What is proved vs. deferred:
 
 ====================  =========================================
 static start exprs    in-bounds proved here (interval analysis)
+bounded loop          bounds load only scalar params of grid
+                      vars; table reads by the loop var proved
+                      in range over ``[0, max_extent)``
 table-directed axis   ``table_in_range`` obligation -> dispatch guard
 grid var not in any
   output start        write race, rejected here
@@ -373,10 +376,49 @@ def _check_races(
     del proven
 
 
+def _check_loop_bounds(m: LoweredModule) -> None:
+    """A bounded ``T.Pipelined`` loop (``PipelinedOp.bounds``) walks a
+    runtime range clamped to ``[0, max_extent)``.  Its bounds may read only
+    scalar-prefetch params and grid vars (they are evaluated once per grid
+    cell, before the loop), and every scalar read that the loop var indexes
+    in a window start — the block table ``Tables[bz, k]`` — must stay in
+    the table for every ``k`` below the bound: a bound wider than the
+    table is refused here, not read past at run time."""
+    pipe = m.phases.pipeline
+    if pipe is None or pipe.bounds is None:
+        return
+    name, k = m.program.name, pipe.var.name
+    grid_vars = {v.name for v, _ in m.program.grid_axes}
+    for e in pipe.bounds:
+        if any(ld.buffer.scope != SCALAR for ld in loads_in(e)):
+            raise VerifyError(
+                f"{name}: the bounds of loop {k} may load only "
+                f"scalar-prefetch params ({e!r})"
+            )
+        if not free_vars(e) <= grid_vars:
+            raise VerifyError(
+                f"{name}: the bounds of loop {k} may depend only on grid "
+                f"vars ({e!r})"
+            )
+    for w in list(m.in_windows) + list(m.out_windows):
+        for ld in (ld for s in w.region.starts for ld in loads_in(s)):
+            for axis, idx in enumerate(ld.indices):
+                if k not in free_vars(idx) or loads_in(idx):
+                    continue
+                lo, hi = interval(idx)
+                if lo < 0 or hi >= ld.buffer.shape[axis]:
+                    raise VerifyError(
+                        f"{name}: {ld.buffer.name} axis {axis} is read at "
+                        f"[{lo:g}, {hi:g}] by loop {k} (bound {pipe.extent}), "
+                        f"outside its extent {ld.buffer.shape[axis]}"
+                    )
+
+
 def verify_module(m: LoweredModule) -> List[Obligation]:
     """Run all static checks; returns the runtime obligations."""
     name = m.program.name
     obligations: List[Obligation] = []
+    _check_loop_bounds(m)
     for w in list(m.in_windows) + list(m.out_windows):
         _check_bounds(name, w, obligations)
     pipe_var = (

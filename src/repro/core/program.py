@@ -235,7 +235,10 @@ class TileProgram:
                     out.append(b)
 
         for op in self._walk():
-            if isinstance(op, CopyOp):
+            if isinstance(op, PipelinedOp) and op.bounds is not None:
+                for e in op.bounds:
+                    note(e)
+            elif isinstance(op, CopyOp):
                 for e in (*op.src.starts, *op.dst.starts):
                     note(e)
             elif isinstance(op, FillOp):
@@ -375,26 +378,45 @@ class _LoopIter:
 
 
 def Pipelined(
-    extent: int,
+    extent,
     num_stages: int = 2,
     order: Optional[Sequence[int]] = None,
     stage: Optional[Sequence[int]] = None,
+    *,
+    start=0,
+    max_extent: Optional[int] = None,
 ) -> _LoopIter:
-    """Software-pipelined loop (paper §4.4).
+    """Software-pipelined loop (paper §4.4) over ``k`` in ``[start, extent)``.
 
     ``num_stages`` is the multi-buffering depth; ``order``/``stage`` allow an
-    explicitly user-defined pipeline as in the paper.  The TPU lowering turns
-    this loop into an ``arbitrary`` grid axis so that its global->shared
-    copies become BlockSpec-managed double-buffered DMAs overlapped with
-    compute.
+    explicitly user-defined pipeline as in the paper.  With a static integer
+    extent the TPU lowering turns this loop into an ``arbitrary`` grid axis
+    so that its global->shared copies become BlockSpec-managed
+    double-buffered DMAs overlapped with compute.
+
+    ``extent`` and ``start`` may instead be expressions of the grid vars and
+    scalar-prefetch loads (a slot's live page count: ``T.ceildiv(Lens[bz],
+    page_size)``); ``max_extent`` then bounds them, and the loop runs
+    ``[max(start, 0), min(extent, max_extent))`` — the bounded walk, lowered
+    to an in-kernel loop that skips everything past the live range where
+    its tiles are lane-aligned (``lowering.grid.walks_in_kernel``).
     """
-    extent = int(extent)
-    if extent <= 0:
-        raise TraceError(f"T.Pipelined extent must be positive, got {extent}")
+    if max_extent is None:
+        if isinstance(extent, Expr) or isinstance(start, Expr) or start != 0:
+            raise TraceError(
+                "T.Pipelined with an expression extent or a start needs "
+                "max_extent, the static bound of the loop"
+            )
+        bound, bounds = int(extent), None
+    else:
+        bound, bounds = int(max_extent), (wrap(start), wrap(extent))
+    if bound <= 0:
+        raise TraceError(f"T.Pipelined extent must be positive, got {bound}")
     if num_stages < 1:
         raise TraceError("num_stages must be >= 1")
-    var = VarExpr(f"k{next(_name_counter)}", extent=extent)
-    return _LoopIter(PipelinedOp(var, extent, num_stages, [], order, stage), var)
+    var = VarExpr(f"k{next(_name_counter)}", extent=bound)
+    op = PipelinedOp(var, bound, num_stages, [], order, stage, bounds)
+    return _LoopIter(op, var)
 
 
 def serial(extent: int) -> _LoopIter:

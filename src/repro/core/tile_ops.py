@@ -339,10 +339,16 @@ class ParallelOp(TileOp):
 class PipelinedOp(TileOp):
     """``T.Pipelined`` loop: the software-pipeline region (paper §4.4).
 
-    On the TPU lowering this becomes an ``arbitrary`` grid axis whose
-    global->shared copies turn into BlockSpec-managed double-buffered DMA —
-    the Pallas-native analogue of cp.async / TMA rings.  ``num_stages`` and
-    explicit ``order``/``stage`` hints are honored as scheduling metadata
+    On the TPU lowering a static loop becomes an ``arbitrary`` grid axis
+    whose global->shared copies turn into BlockSpec-managed double-buffered
+    DMA — the Pallas-native analogue of cp.async / TMA rings.  A *bounded*
+    loop (``bounds`` set) runs ``var`` over ``[start, stop)``, expressions of
+    the grid vars and scalar-prefetch loads, clamped to ``[0, extent)``:
+    ``extent`` is then the static bound its windows are verified against,
+    and the Pallas lowering walks only the live range (an in-kernel loop
+    with hand-issued page DMAs) where every tile the loop reads is
+    lane-aligned, else runs it as a static loop over ``extent``.  ``num_stages`` and explicit
+    ``order``/``stage`` hints are honored as scheduling metadata
     (multi-buffering depth) and budget-checked by the VMEM planner.
     """
 
@@ -352,6 +358,7 @@ class PipelinedOp(TileOp):
     body: List[TileOp] = dataclasses.field(default_factory=list)
     order: Optional[Sequence[int]] = None
     stage: Optional[Sequence[int]] = None
+    bounds: Optional[Tuple[Expr, Expr]] = None  # (start, stop); None: static
 
     def buffers_read(self):
         out = []
@@ -366,9 +373,10 @@ class PipelinedOp(TileOp):
         return out
 
     def __repr__(self):
+        live = "" if self.bounds is None else f" in [{self.bounds[0]!r}, {self.bounds[1]!r})"
         return (
-            f"Pipelined({self.var.name} < {self.extent}, stages={self.num_stages}, "
-            f"{len(self.body)} ops)"
+            f"Pipelined({self.var.name} < {self.extent}{live}, "
+            f"stages={self.num_stages}, {len(self.body)} ops)"
         )
 
 

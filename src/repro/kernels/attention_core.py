@@ -209,15 +209,21 @@ def scores(acc_s, q, k, extra=()):
         T.gemm(qe, ke, acc_s, transpose_B=True)
 
 
-def attend(ons, acc_s, cols, extent, load_kv, score, mask=None, num_stages=2):
-    """One pipelined online-softmax pass over ``extent`` KV tiles.
+def attend(ons, acc_s, cols, extent, load_kv, score, mask=None, num_stages=2,
+           start=0, max_extent=None):
+    """One pipelined online-softmax pass over KV tiles ``[start, extent)``.
 
     ``load_kv(k)`` stages step ``k``'s tiles and returns ``(k_src, v_src)``
     (the KV-source composition point — contiguous window or block-table
     page gather); ``score(acc_s, k_src, k)`` fills the score tile;
-    ``mask(k)`` returns the step's ``(i, j)`` mask (or None).
+    ``mask(k)`` returns the step's ``(i, j)`` mask (or None).  With
+    ``max_extent``, ``start`` and ``extent`` may be runtime expressions
+    (:func:`live_pages`): the walk skips every tile outside them, which is
+    exact when the mask would have discarded those tiles whole — a fully
+    masked tile adds ``exp2(-inf) = 0`` and rescales by 1.
     """
-    for k in T.Pipelined(extent, num_stages=num_stages):
+    for k in T.Pipelined(extent, num_stages=num_stages, start=start,
+                         max_extent=max_extent):
         k_src, v_src = load_kv(k)
         score(acc_s, k_src, k)
         ons.update(acc_s, cols, v_src, None if mask is None else mask(k))
@@ -231,6 +237,17 @@ def attend(ons, acc_s, cols, extent, load_kv, score, mask=None, num_stages=2):
 def causal(q_pos, k_pos):
     """Key at ``k_pos(j)`` visible to query at ``q_pos(i)`` iff not future."""
     return lambda i, j: q_pos(i) >= k_pos(j)
+
+
+def live_pages(length, page_size, window=None):
+    """``(first, end)``: the pages holding a slot's live KV positions
+    ``[max(0, length - window), length)`` — the decode walk's extent.
+    Plain arithmetic, so it serves a kernel (``length`` a scalar load,
+    giving loop bounds) and the host (NumPy lengths, giving page counts)
+    alike; ``first`` may come out negative, which the walk clamps to 0."""
+    end = (length + (page_size - 1)) // page_size
+    first = 0 if window is None else (length - window) // page_size
+    return first, end
 
 
 def ragged(length, k_pos, window=None):

@@ -32,9 +32,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core import Schedule, compile as tl_compile
+from repro.core import Schedule, analyze, compile as tl_compile
 
+from . import attention_core as AC
 from . import ref
 from .dequant_matmul import dequant_matmul_program
 from .flash_attention import flash_attention_program
@@ -195,6 +197,43 @@ def _oracle_reason(logit_soft_cap=None, chunk=None, page_size=None,
 
 def _resolve(backend: Optional[str]) -> str:
     return backend or default_backend()
+
+
+def paged_walk(seq_lens, page_size: int, max_pages: int,
+               window: Optional[int] = None, *, head_dim: int,
+               kv_dtype: Optional[str] = None, attention: str = "gqa",
+               logit_soft_cap=None, backend: Optional[str] = None) -> np.ndarray:
+    """KV pages one KV head's decode attention visits for each slot of
+    lengths ``seq_lens``, as this module runs the call
+    (``paged_attention(_quant)``, or ``mla_paged(_quant)`` for
+    ``attention="mla"``): the slot's live pages (the extent the kernel
+    gives its page loop, ``attention_core.live_pages``, clamped to the
+    table; 0 for an empty slot) where it walks them in the kernel
+    (:func:`_walks_live_pages`), else the whole table, ``max_pages``."""
+    lens = np.asarray(seq_lens, np.int64)
+    if not _walks_live_pages(_resolve(backend), logit_soft_cap, attention,
+                             head_dim, kv_dtype):
+        return np.full(lens.shape, max_pages, np.int64)
+    first, end = AC.live_pages(lens, page_size, window)
+    return np.clip(end, 0, max_pages) - np.clip(first, 0, max_pages)
+
+
+@functools.lru_cache(maxsize=None)
+def _walks_live_pages(backend: str, logit_soft_cap, attention: str,
+                      head_dim: int, kv_dtype: Optional[str]) -> bool:
+    """Whether a paged decode call visits only live pages: it runs the
+    tile kernel (not the oracle, :func:`_oracle_reason`), the kernel bounds
+    its page loop (the MLA kernels' loops keep a static extent), and the
+    lowering walks that loop inside the kernel (``GridPlan.walk``, asked
+    of the kernel at a small shape with the same tiles; a loop it cannot
+    walk there runs every table page)."""
+    if backend == "xla" or _oracle_reason(logit_soft_cap) or attention == "mla":
+        return False
+    shape = dict(slots=1, heads=1, kv_heads=1, head_dim=head_dim, page_size=16,
+                 max_pages=2, num_pages=2)
+    prog = (paged_attention_quant_program(fmt=kv_dtype, **shape) if kv_dtype
+            else paged_attention_program(**shape))
+    return analyze(prog, Schedule()).grid_plan.walk
 
 
 def _pick_block(n: int, candidates=(128, 64, 32, 16, 8)) -> int:

@@ -17,14 +17,22 @@ are ``(slots, kv_heads, group, head_dim)``, so each grid cell's block spans
 the array's last two dimensions whole (Mosaic's block rule holds for any
 group size, e.g. qwen2's 6); ragged sequence
 lengths (every slot at its own position) and sliding windows compose the
-ragged mask against the ``Lens`` scalar tensor.  Entries of the block
-table beyond a slot's live length must still hold *valid* page ids (the
-pool DMAs them regardless; masking kills their contribution) — the
-serving engine pads tables with page 0.
+ragged mask against the ``Lens`` scalar tensor.
+
+The page walk is bounded by the slot's live length: the loop runs over
+``attention_core.live_pages(Lens[bz], page_size, window)`` only, with
+``max_pages`` as its static bound, so table entries past a slot's live
+pages are never read and a slot of length 0 walks nothing (its output is
+zeros).  The Pallas lowering makes that walk an in-kernel loop over
+hand-issued page DMAs (``core/backends/pallas_tpu.py``) instead of a grid
+axis over every table page; the mask still trims the partial last page
+and the window's first.
 """
 
 import math
 from typing import Optional
+
+import numpy as np
 
 from repro.core import TileProgram
 from repro.core import lang as T
@@ -78,15 +86,16 @@ def paged_attention_program(
                 return K_shared, V_shared
 
             # ragged mask: this slot's live KV positions are
-            # [max(0, len-window), len) — everything else (tail of the
-            # last page, table padding) contributes nothing.
+            # [max(0, len-window), len) — the walk visits only their pages,
+            # and the mask trims the rest of the first and last of them.
             def mask(k):
                 return AC.ragged(Lens[bz], lambda j: k * page_size + j, window)
 
+            first, end = AC.live_pages(Lens[bz], page_size, window)
             AC.attend(
-                ons, acc_s, page_size, max_pages, load_kv,
+                ons, acc_s, page_size, end, load_kv,
                 lambda s, ks, k: AC.scores(s, Q_shared, ks), mask,
-                num_stages=num_stages,
+                num_stages=num_stages, start=first, max_extent=max_pages,
             )
             ons.finalize(Output[bz, bh, 0, 0])
 
@@ -152,15 +161,28 @@ def paged_attention_quant_program(
             def mask(k):
                 return AC.ragged(Lens[bz], lambda j: k * page_size + j, window)
 
+            first, end = AC.live_pages(Lens[bz], page_size, window)
             AC.attend(
-                ons, acc_s, page_size, max_pages, load_kv,
+                ons, acc_s, page_size, end, load_kv,
                 lambda s, ks, k: AC.scores(s, Q_shared, ks), mask,
-                num_stages=num_stages,
+                num_stages=num_stages, start=first, max_extent=max_pages,
             )
             ons.finalize(Output[bz, bh, 0, 0])
 
     return PagedAttnQuant
 
+
+# Live lengths of the ``*_edge_lens`` parity cases, one per slot: the
+# bounded walk's edges at page 16 and 3 table pages — an empty slot (no
+# page walked), one token, exactly one page, one past a page, the full
+# table; under a window of 20 the walk of the last three starts past page 0.
+# The ``lane`` cases (head_dim 128) take the Pallas in-kernel walk the chip
+# runs; at head_dim 16, and for the quantized kernel's (page, 1) scale
+# columns, the bounded loop lowers to the static grid over ``max_pages``
+# (``lowering.grid.walks_in_kernel``).
+EDGE_LENS = (0, 1, 16, 17, 48)
+_EDGE = dict(slots=len(EDGE_LENS), heads=4, kv_heads=2, head_dim=16,
+             page_size=16, max_pages=3, num_pages=16)
 
 # Tiny-shape configs for the pallas-vs-reference parity suite
 # (tests/test_pipeline.py); covers GQA + MQA head groupings, a sliding
@@ -194,6 +216,14 @@ PARITY_CASES = [
         dict(slots=2, heads=2, kv_heads=1, head_dim=16, page_size=16,
              max_pages=2, num_pages=4, fmt="int4"),
     ),
+    ("paged_attention_edge_lens", _EDGE),
+    ("paged_attention_windowed_edge_lens", dict(_EDGE, window=20)),
+    ("paged_attention_quant_int8_edge_lens", dict(_EDGE, fmt="int8")),
+    ("paged_attention_quant_int4_windowed_edge_lens",
+     dict(_EDGE, fmt="int4", window=20)),
+    ("paged_attention_lane_edge_lens", dict(_EDGE, head_dim=128)),
+    ("paged_attention_lane_windowed_edge_lens",
+     dict(_EDGE, head_dim=128, window=20)),
 ]
 
 
@@ -207,14 +237,18 @@ def parity_inputs(name, program, rng):
     """Valid inputs for the parity suite: block tables must hold live page
     ids and lens must be in range — random bytes won't do.  Tables are drawn
     without replacement (each physical page owned by one slot) and lens are
-    ragged: every slot at a different fill level, including a partial page.
-    Quantized cases get full-range packed bytes and positive scales.
+    ragged: every slot at a different fill level, including a partial page
+    (``EDGE_LENS`` for the ``*_edge_lens`` cases).  Quantized cases get
+    full-range packed bytes and positive scales.
     """
     cfg = dict(PARITY_CASES)[name]
     slots, mp, np_ = cfg["slots"], cfg["max_pages"], cfg["num_pages"]
     pages = rng.permutation(np_)[: slots * mp].reshape(slots, mp).astype("int32")
     max_len = mp * cfg["page_size"]
-    lens = (rng.integers(1, max_len + 1, size=slots)).astype("int32")
+    if name.endswith("edge_lens"):
+        lens = np.asarray(EDGE_LENS, "int32")
+    else:
+        lens = (rng.integers(1, max_len + 1, size=slots)).astype("int32")
     args = [pages, lens]
     for p in program.input_params()[2:]:
         if str(p.dtype).startswith("int"):

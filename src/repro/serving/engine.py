@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.errors import GuardError
-from repro.kernels.ops import guard_dispatch
+from repro.kernels.ops import guard_dispatch, paged_walk
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -931,6 +931,28 @@ class ServingEngine:
         telemetry.LOG.append(rec)
         return host, out[n_out:], rec
 
+    def _page_walk(self, rec: telemetry.Dispatch, pos) -> None:
+        """Count on ``rec`` the KV pages the decode program's attention
+        walks, for positions ``pos`` (ticks x slots; a slot attends its
+        ``pos + 1`` tokens), against the pages its block tables hold —
+        summed over ticks, slots and layers (every layer of a paged model
+        attends; ``telemetry.Dispatch``).  What is walked is the kernel
+        layer's answer (``ops.paged_walk``), asked as the layer calls it."""
+        if self.tables is None:
+            return
+        cfg, pos = self.cfg, np.asarray(pos)
+        layers = collections.Counter(lm.static_windows(cfg))  # window -> layers
+        rec.pages_table = pos.size * self.max_pages * sum(layers.values())
+        rec.pages_walked = int(sum(
+            n * paged_walk(
+                pos + 1, self.pool.page_size, self.max_pages, w,
+                head_dim=cfg.head_dim, kv_dtype=cfg.kv_dtype,
+                attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
+                backend=None if cfg.kernel_backend == "auto" else cfg.kernel_backend,
+            ).sum()
+            for w, n in layers.items()
+        ))
+
     def _gen_ready(self, s: int) -> bool:
         """Slot ``s`` is in steady-state generation: its next feed is its
         last known token and every later feed is a model output — exactly
@@ -1118,6 +1140,8 @@ class ServingEngine:
             loop, (feed, self.pos, self._key, live, rem), 2, active, ticks=n,
         )
         with telemetry.span("drain", rec):
+            # a slot's position advances on each tick it was live in
+            self._page_walk(rec, self.pos + np.cumsum(emitted, 0) - emitted)
             self.decode_windows += 1
             # replay each in-window tick through the same host-side
             # bookkeeping the per-tick path runs, so Request state, tick
@@ -1496,6 +1520,7 @@ class ServingEngine:
             self._step, (feed, self.pos, self._key, live, poison), 2, active,
         )
         with telemetry.span("drain", rec):
+            self._page_walk(rec, self.pos[None])
             for s in active:
                 req = self.slot_req[s]
                 cur = req._cursor  # type: ignore[attr-defined]
@@ -1549,6 +1574,7 @@ class ServingEngine:
                 self._step, (feed, self.pos, self._key, live, poison), 2, gen,
             )
             with telemetry.span("drain", rec):
+                self._page_walk(rec, self.pos[None])
                 for s in gen:
                     req = self.slot_req[s]
                     self.pos[s] += 1

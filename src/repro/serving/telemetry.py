@@ -72,13 +72,19 @@ class Dispatch:
     ``ticks`` counts the decode ticks (or speculative rounds, or prefill
     chunks) the program covers; 0 for ``copy_pages``.  ``uids`` are the
     requests in its slots, in slot order.  A prefill program runs ``rows``
-    (slots × chunk) rows, of which ``live_rows`` hold prompt tokens."""
+    (slots × chunk) rows, of which ``live_rows`` hold prompt tokens.  A
+    paged decode program's attention visits ``pages_walked`` KV pages of
+    the ``pages_table`` its block tables hold (one KV head's walk, summed
+    over ticks, slots and layers; equal where the walk is not bounded by
+    the live length)."""
 
     program: str
     ticks: int
     uids: Tuple[int, ...]
     rows: int = 0
     live_rows: int = 0
+    pages_walked: int = 0
+    pages_table: int = 0
     upload: Optional[Span] = None
     dispatch: Optional[Span] = None
     wait: Optional[Span] = None
@@ -137,6 +143,7 @@ def summary(records: List, t0: float, t1: float) -> dict:
     * ``decode_ms_per_tick``: enqueue start to results ready of the decode
       programs (``decode_step``, ``decode_window_*``), per tick they ran.
     * ``prefill_rows``, ``prefill_live_rows``: summed over ``prefill_step``.
+    * ``pages_walked``, ``pages_table``: summed over the decode programs.
     """
     window_s = t1 - t0
     steps = [(r.t0, r.t1) for r in records if isinstance(r, Step)]
@@ -174,6 +181,8 @@ def summary(records: List, t0: float, t1: float) -> dict:
                                if ticks else None),
         "prefill_rows": sum(r.rows for r in prefill),
         "prefill_live_rows": sum(r.live_rows for r in prefill),
+        "pages_walked": sum(r.pages_walked for r in decode),
+        "pages_table": sum(r.pages_table for r in decode),
     }
 
 

@@ -12,14 +12,19 @@ this file.  Keep these tests in this one file so one worker holds it.
 import collections
 import dataclasses
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.core import Schedule
+from repro.core import Schedule, analyze, compile as tl_compile, program_fingerprint
 from repro.kernels import ops
+from repro.kernels.flash_attention import flash_attention_program
+from repro.kernels.matmul import matmul_program
+from repro.kernels.paged_attention import paged_attention_program
+from repro.kernels.prefill_attention import prefill_attention_program
 
 QWEN = get_config("qwen2_1_5b")
 SLOTS, MAX_LEN, PAGE, CHUNK = 8, 1024, 16, 128
@@ -152,3 +157,64 @@ def test_serving_step_compiles_for_v5e(step, one_chip, mosaic):
     )
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# The decode shapes of the benchmark's two cells (benchmarks/chip): slots,
+# table pages, query heads, KV heads.  qwen2_1_5b: group 6 over 2 KV heads;
+# deepseek_7b: MHA, 32 KV heads at group 1.
+CELLS = {"qwen2_1_5b": (8, 288, 12, 2), "deepseek_7b": (8, 96, 32, 32)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_bounded_paged_attention_compiles_for_v5e(cell, one_chip, mosaic):
+    """The bounded walk at the cells' shapes: a (slots, kv_heads) grid whose
+    cells loop over their live pages with hand-issued DMAs."""
+    slots, max_pages, hq, hkv = CELLS[cell]
+    pages = slots * max_pages + 1
+    shapes = (sds(slots, hq, 128), sds(hkv, pages, PAGE, 128), sds(hkv, pages, PAGE, 128),
+              sds(slots, max_pages, dtype="int32"), sds(slots, dtype="int32"))
+    text = compiled_text(lambda *a: ops.paged_attention(*a, backend="pallas"), *shapes,
+                         sharding=one_chip)
+    assert "tpu_custom_call" in text
+    assert not ops.FALLBACKS, dict(ops.FALLBACKS)
+    m = analyze(paged_attention_program(slots, hq, hkv, 128, PAGE, max_pages, pages,
+                                        dtype=str(QWEN.dtype)), Schedule())
+    assert m.grid == (slots, hkv) and m.grid_plan.walk
+
+
+# Static-extent kernels as the parent of the bounded walk lowered them:
+# program fingerprint (the compile caches' key), grid, dimension semantics,
+# and a digest of the traced Mosaic kernel body.  (XLA's persistent cache
+# also hashes the kernel's source line numbers, which any edit of the
+# backend moves; the body itself must not change.)
+STATIC = {
+    "FlashAttn": (
+        lambda: flash_attention_program(1, 12, 2, 256, 256, 128, True, 128, 128,
+                                        "bfloat16", "float32", 2, None),
+        "f04377ede345565ecba18b0f7ad036e0f3cd28c8be3420ceb404594113fc8300",
+        (1, 12, 2, 2), "f0ba35ca95a0811a"),
+    "PrefillAttn": (
+        lambda: prefill_attention_program(8, 12, 2, 128, 128, 16, 288, 2305, None,
+                                          "bfloat16", "float32", 2, None),
+        "205e2ac90d65760ae295c6fea43657389df51041aa480e56071f9e06667f4b63",
+        (8, 8, 2, 288), "b813355d74d2c40a"),
+    "Matmul": (
+        lambda: matmul_program(1024, 1536, 8960, "bfloat16", "bfloat16", "float32",
+                               128, 128, 256, 2),
+        "2e5692dbaf64e74815f4f67e82705b740ed23a66585f9eb85768a3c73e44967b",
+        (8, 12, 35), "2b0ce32d8279fcff"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STATIC))
+def test_static_extent_kernels_keep_their_lowering(name):
+    make, fingerprint, grid, body = STATIC[name]
+    prog = make()
+    m = analyze(prog, Schedule())
+    assert program_fingerprint(prog) == fingerprint
+    assert m.grid == grid and not m.grid_plan.walk
+    assert m.dimension_semantics == ("parallel",) * (len(grid) - 1) + ("arbitrary",)
+    kern = tl_compile(prog, Schedule())
+    args = [jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype)) for p in kern.arg_params]
+    text = str(jax.make_jaxpr(kern)(*args))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == body

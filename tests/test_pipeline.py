@@ -9,6 +9,8 @@ Two halves:
   both ``target="pallas"`` (interpret mode) and ``target="reference"`` on
   tiny shapes must agree numerically.
 """
+import collections
+
 import numpy as np
 import pytest
 
@@ -251,6 +253,162 @@ def test_backend_parity(name, rng):
         np.testing.assert_allclose(
             np.asarray(p), np.asarray(r), rtol=1e-4, atol=2e-3
         )
+
+
+# ---------------------------------------------------------------------------
+# The bounded page walk: PagedAttn / PagedAttnQuant visit only live pages
+# ---------------------------------------------------------------------------
+
+_PAGED = sorted(n for n in _CASES if n.startswith("paged_attention"))
+
+
+def _paged_oracle(name, args):
+    """``ref.paged_attention(_quant)`` on a paged parity case's inputs, in
+    the kernels' (slots, kv_heads, group, head_dim) output layout."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ref
+    from repro.kernels.paged_attention import PARITY_CASES
+
+    cfg = dict(PARITY_CASES)[name]
+    tables, lens, q, *pools = (jnp.asarray(a) for a in args)
+    slots, hkv, group, d = q.shape
+    qh = q.reshape(slots, hkv * group, d)
+    if "quant" in name:
+        out = ref.paged_attention_quant(qh, *pools, tables, lens, fmt=cfg["fmt"],
+                                        window=cfg.get("window"))
+    else:
+        out = ref.paged_attention(qh, *pools, tables, lens, window=cfg.get("window"))
+    return np.asarray(out).reshape(q.shape)
+
+
+@pytest.mark.parametrize("target", ["pallas", "reference"])
+@pytest.mark.parametrize("name", _PAGED)
+def test_paged_walk_matches_oracle(name, target, rng):
+    """Both backends' bounded walk against the XLA oracle, which masks
+    the whole table: skipping dead pages changes nothing, empty slots
+    (``EDGE_LENS``' 0) read zeros.  The ``lane`` cases (head_dim 128) run
+    the Pallas in-kernel walk; the others its static-grid lowering."""
+    prog = _CASES[name]
+    assert analyze(prog, Schedule()).grid_plan.walk == ("_lane_" in name)
+    sched = Schedule(interpret=True) if target == "pallas" else None
+    kern = tl_compile(prog, sched, target=target)
+    args = parity_inputs(name, prog, rng)
+    np.testing.assert_allclose(np.asarray(kern(*args)), _paged_oracle(name, args),
+                               rtol=1e-4, atol=2e-3)
+
+
+@pytest.mark.parametrize("window", [None, 20])
+def test_reference_walks_only_live_pages(window, monkeypatch):
+    """The reference interpreter loads exactly the pages holding each
+    slot's live positions, per (KV head, slot): ``ceil(len / page)`` from
+    the window's first live page, none for an empty slot."""
+    from repro.core.backends import reference
+    from repro.core.tile_ops import CopyOp
+    from repro.kernels.paged_attention import (
+        EDGE_LENS,
+        PARITY_CASES,
+        paged_attention_program,
+    )
+
+    cfg = dict(dict(PARITY_CASES)["paged_attention_edge_lens"], window=window)
+    prog = paged_attention_program(**cfg)
+    seen = []
+    ref_op = reference._ref_op
+
+    def spy(op, globals_, tiles, env, jnp, san=None):
+        if isinstance(op, CopyOp) and op.src.buffer.name == "KPages":
+            seen.append((env["bx"], env["by"], int(env[prog.pipelined_ops()[0].var.name])))
+        return ref_op(op, globals_, tiles, env, jnp, san)
+
+    monkeypatch.setattr(reference, "_ref_op", spy)
+    args = parity_inputs("paged_attention_edge_lens", prog, np.random.default_rng(0))
+    tl_compile(prog, target="reference", use_cache=False)(*args)
+    ps = cfg["page_size"]
+    want = []
+    for bz, n in enumerate(EDGE_LENS):
+        first = 0 if window is None else max(0, n - window) // ps
+        for bh in range(cfg["kv_heads"]):
+            want += [(bh, bz, k) for k in range(first, -(-n // ps))]
+    assert sorted(seen) == sorted(want)
+    per_cell = collections.Counter((bh, bz) for bh, bz, _ in seen)
+    assert per_cell[(0, 4)] == (3 if window is None else 2)  # 48 tokens
+    assert (0, 0) not in per_cell  # the empty slot walks nothing
+
+
+def test_reference_bounded_walk_under_jit(rng):
+    """Under ``jax.jit`` the loop's bounds are traced: the reference walks
+    to the static bound and keeps only the live steps' writes, giving what
+    it gives eagerly."""
+    import jax
+
+    name = "paged_attention_windowed_edge_lens"
+    prog = _CASES[name]
+    kern = tl_compile(prog, target="reference")
+    args = parity_inputs(name, prog, rng)
+    np.testing.assert_allclose(np.asarray(jax.jit(kern)(*args)),
+                               np.asarray(kern(*args)), rtol=1e-5, atol=1e-6)
+
+
+def test_bounded_loop_lowering():
+    """The fp kernel walks inside the kernel: its grid is (slots, kv_heads)
+    alone.  The quantized kernel's (page, 1) scale columns cannot be DMA'd
+    by hand, so its bounded loop keeps the grid axis over max_pages."""
+    from repro.kernels.paged_attention import (
+        paged_attention_program,
+        paged_attention_quant_program,
+    )
+
+    shape = dict(slots=8, heads=12, kv_heads=2, head_dim=128, page_size=16,
+                 max_pages=288, num_pages=2305, dtype="bfloat16")
+    m = analyze(paged_attention_program(**shape), Schedule())
+    assert m.grid == (8, 2) and m.grid_plan.walk and m.grid_plan.kdim is None
+    assert m.dimension_semantics == ("parallel", "parallel")
+    q = analyze(paged_attention_quant_program(fmt="int8", **shape), Schedule())
+    assert q.grid == (8, 2, 288) and not q.grid_plan.walk and q.grid_plan.kdim == 2
+
+
+def test_bounded_loop_wider_than_its_table_is_refused():
+    """A bound past the block table's width would read table entries that
+    do not exist: the verifier refuses it at lowering time."""
+    from repro.core.errors import VerifyError
+
+    @T.prim_func
+    def TooWide(
+        Tables: T.ScalarTensor((2, 3), "int32"),
+        Lens: T.ScalarTensor((2,), "int32"),
+        Pages: T.Tensor((8, 16, 128), "float32"),
+        Out: T.Tensor((2, 16, 128), "float32"),
+    ):
+        with T.Kernel(2) as bz:
+            P_s = T.alloc_shared((16, 128))
+            acc = T.alloc_fragment((16, 128))
+            T.clear(acc)
+            for k in T.Pipelined(T.ceildiv(Lens[bz], 16), max_extent=4):
+                T.copy(Pages[Tables[bz, k], 0, 0], P_s)
+                for i, j in T.Parallel(16, 128):
+                    acc[i, j] = acc[i, j] + P_s[i, j]
+            T.copy(acc, Out[bz, 0, 0])
+
+    with pytest.raises(VerifyError, match="Tables axis 1"):
+        analyze(TooWide, Schedule(), use_cache=False)
+
+
+def test_expression_extent_needs_a_bound():
+    from repro.core.errors import TraceError
+
+    with pytest.raises(TraceError, match="max_extent"):
+        @T.prim_func
+        def Unbounded(
+            Lens: T.ScalarTensor((2,), "int32"),
+            Out: T.Tensor((2, 8, 128), "float32"),
+        ):
+            with T.Kernel(2) as bz:
+                acc = T.alloc_fragment((8, 128))
+                T.clear(acc)
+                for k in T.Pipelined(T.ceildiv(Lens[bz], 16)):
+                    T.fill(acc, 1.0)
+                T.copy(acc, Out[bz, 0, 0])
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,12 @@
 (``repro.serving.telemetry``): what each step ran, for whom, and when each
 host phase began and ended."""
 import collections
+import functools
 import glob
 import os
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -235,3 +237,70 @@ def test_serve_prints_one_line_from_the_log():
     assert "schedule 1.000, caller 0.000" in line and "ms/tick" not in line
     assert line.endswith("prefill live rows 16/64 (25.0%)")
     assert "cut short" in dispatch_line(None)
+
+
+def test_paged_walk_counts_the_live_pages():
+    """``ops.paged_walk``: the pages each slot's bounded decode walk visits,
+    from the page of the window's first live position to the last live
+    page, clamped to the table — where the call runs the tile kernel and
+    its lowering walks in the kernel; the whole table wherever else."""
+    from repro.kernels import ops
+
+    lens = [0, 1, 16, 17, 40, 48]
+    walk = functools.partial(ops.paged_walk, lens, 16, 3, head_dim=128, backend="pallas")
+    assert walk().tolist() == [0, 1, 1, 2, 3, 3]
+    # window 20: the live range starts at max(0, len - 20)
+    assert walk(window=20).tolist() == [0, 1, 1, 2, 2, 2]
+    full = [3] * len(lens)
+    assert walk(backend="xla").tolist() == full  # the oracle masks the table
+    assert walk(logit_soft_cap=30.0).tolist() == full  # routed to the oracle
+    assert walk(attention="mla").tolist() == full  # static extent
+    # tiles the lowering cannot DMA by hand: the static grid over the table
+    assert walk(kv_dtype="int8").tolist() == full
+    assert walk(head_dim=64).tolist() == full
+
+
+@pytest.mark.parametrize("bounded", [True, False])
+@pytest.mark.parametrize("sync_every", [1, 8])
+def test_decode_records_count_their_page_walk(sync_every, bounded, monkeypatch, rng):
+    """Each decode program's record counts the KV pages its attention
+    walks against the pages its tables hold, over ticks, slots and layers:
+    with the walk bounded, ceil((pos + 1) / page) per slot and tick, a
+    slot's position advancing on the ticks it was live; unbounded (the XLA
+    oracle the CPU engine runs), the whole table."""
+    from repro.kernels import ops
+
+    eng = _engine(sync_every=sync_every, max_new_tokens=20)
+    if bounded:  # as ops answers for the tile kernel's in-kernel walk
+        monkeypatch.setattr(ops, "_walks_live_pages", lambda *a: True)
+    calls = []
+    run = eng._run_program
+
+    def spy(fn, inputs, *a, **kw):
+        pos = np.array(inputs[1])  # the engine advances self.pos in place
+        out = run(fn, inputs, *a, **kw)
+        calls.append((out[2], pos, out[0][1]))
+        return out
+
+    monkeypatch.setattr(eng, "_run_program", spy)
+    _submit(eng, rng, 5, lo=5, hi=30)
+    eng.run()
+    layers, ps, width = eng.cfg.num_layers, eng.scfg.page_size, eng.max_pages
+    decode = [(rec, pos, emitted) for rec, pos, emitted in calls
+              if rec.program == "decode_step" or rec.program.startswith("decode_window_")]
+    assert decode
+    for rec, pos, emitted in decode:
+        assert rec.pages_table == rec.ticks * eng.scfg.slots * width * layers
+        if not bounded:
+            assert rec.pages_walked == rec.pages_table
+            continue
+        # a window's second output is its emitted mask (ticks x slots); a
+        # slot advances on the ticks it was live in
+        walked, p = 0, pos.astype(int)
+        for t in range(rec.ticks):
+            walked += layers * sum(-(-(x + 1) // ps) for x in p.tolist())
+            if emitted.ndim == 2:
+                p = p + emitted[t]
+        assert rec.pages_walked == walked
+    others = [rec for rec, _, _ in calls if rec not in [d[0] for d in decode]]
+    assert all(rec.pages_walked == rec.pages_table == 0 for rec in others)
