@@ -182,8 +182,8 @@ def make_hints(cfg: ModelConfig, mesh: Mesh, cell: Cell, opt_level: int = 0):
     * attention: shard heads over `model` when divisible; otherwise shard
       the q sequence axis (bounds the S^2 score tensor — flash-style
       partitioning) and keep K/V replicated on `model`.
-    * MoE expert buffers: EP over `model` when E divides, else shard the
-      capacity axis over the data axes (TP stays inside the expert FFN).
+    * MoE expert activations: EP over `model` when E divides, tokens over
+      the data axes (TP stays inside the expert FFN).
 
     ``opt_level >= 1`` adds the §Perf collective optimizations:
     * "block_out": SP-constrain attention/FFN outputs so the row-parallel
@@ -232,13 +232,13 @@ def make_hints(cfg: ModelConfig, mesh: Mesh, cell: Cell, opt_level: int = 0):
         hooks["attn_q"] = constrain_with(attn_q)
         hooks["attn_kv"] = constrain_with(attn_kv)
     if cfg.moe and cfg.moe.num_experts:
-        def moe_expert(shape):  # (G, E, cap, D): groups over data, EP over model
-            gdim, e = shape[0], shape[1]
+        def moe_expert(shape):  # (E, T, F): EP over model, tokens over data
+            e, tokens = shape[0], shape[1]
             dp = shd.dp_axes(mesh)
             dp_ax = dp if len(dp) > 1 else (dp[0] if dp else None)
-            g_ax = dp_ax if (dp_ax is not None and div(gdim, dp_ax)) else None
+            t_ax = dp_ax if (dp_ax is not None and div(tokens, dp_ax)) else None
             e_ax = "model" if (div(e, "model") and e >= tp) else None
-            return P(g_ax, e_ax, None, None)
+            return P(e_ax, t_ax, None)
 
         hooks["moe_expert"] = constrain_with(moe_expert)
 

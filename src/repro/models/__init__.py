@@ -1,7 +1,7 @@
 # Model zoo: unified config + decoder-only LM (dense/moe/mla/ssm/hybrid) and
 # encoder-decoder (whisper).  Pure-function APIs over param pytrees.
 from . import encdec, layers, lm
-from .config import MLAConfig, ModelConfig, MoEConfig, SSMConfig
+from .config import MLAConfig, ModelConfig, MoEConfig, SSMConfig, YarnScaling
 
 __all__ = [
     "encdec",
@@ -11,4 +11,5 @@ __all__ = [
     "MoEConfig",
     "SSMConfig",
     "MLAConfig",
+    "YarnScaling",
 ]

@@ -12,13 +12,25 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass
 class MoEConfig:
+    """Routed experts beside optional shared ones.  The router scores all
+    ``num_experts``; this device holds ``held_experts`` of them (None: all),
+    from index ``first_held_expert`` on, and computes their part of each
+    layer's output (expert parallelism, one device's share).  Leading
+    ``first_dense_layers`` use a dense FFN of ``ModelConfig.d_ff``."""
+
     num_experts: int = 0
     experts_per_token: int = 0
     d_ff_expert: int = 0
     num_shared_experts: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
+    held_experts: Optional[int] = None
+    first_held_expert: int = 0
+    norm_topk_prob: bool = True  # renormalize the top-k gates to sum to 1
     router_aux_weight: float = 0.01
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.held_experts is None else self.held_experts
 
 
 @dataclasses.dataclass
@@ -37,12 +49,26 @@ class SSMConfig:
 
 
 @dataclasses.dataclass
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` with ``type: yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass
 class MLAConfig:
     kv_lora_rank: int = 512
     q_lora_rank: int = 0  # 0 = full-rank q projection
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    rope_scaling: Optional[YarnScaling] = None  # on the rope dims
 
 
 @dataclasses.dataclass
@@ -132,17 +158,18 @@ class ModelConfig:
             nh = self.ssm.num_heads(d)
             per_layer += d * (2 * di + 2 * self.ssm.state_dim * (1 if self.family == "ssm" else 1) + nh)
             per_layer += di * d
-        if self.moe is not None and self.moe.num_experts:
-            fe = self.moe.d_ff_expert
-            experts = self.moe.experts_per_token if active_only else self.moe.num_experts
-            per_layer += experts * 3 * d * fe
-            per_layer += self.moe.num_shared_experts * 3 * d * fe
-            per_layer += d * self.moe.num_experts  # router
-        elif f:
-            mult = 3 if self.act in ("silu", "geglu") else 2
-            per_layer += mult * d * f
         per_layer += 2 * d  # norms
-        n += self.num_layers * per_layer
+        dense_ffn = (3 if self.act in ("silu", "geglu") else 2) * d * f
+        n_moe = 0
+        if self.moe is not None and self.moe.num_experts:
+            mo = self.moe
+            n_moe = self.num_layers - mo.first_dense_layers
+            experts = min(mo.experts_per_token, mo.held) if active_only else mo.held
+            n += n_moe * (
+                (experts + mo.num_shared_experts) * 3 * d * mo.d_ff_expert
+                + d * mo.num_experts  # router
+            )
+        n += self.num_layers * per_layer + (self.num_layers - n_moe) * dense_ffn
         if self.is_encoder_decoder:
             # encoder self-attn + ffn, and decoder cross-attention extras
             n += self.encoder_layers * (
@@ -181,14 +208,15 @@ class ModelConfig:
                 cfg.moe, num_experts=4, experts_per_token=2, d_ff_expert=32,
                 num_shared_experts=min(cfg.moe.num_shared_experts, 1),
                 first_dense_layers=min(cfg.moe.first_dense_layers, 1),
+                held_experts=None, first_held_expert=0,
             )
         if cfg.ssm is not None:
             cfg.ssm = dataclasses.replace(
                 cfg.ssm, state_dim=16, head_dim=16, conv_width=4, chunk=16
             )
         if cfg.mla is not None:
-            cfg.mla = MLAConfig(
-                kv_lora_rank=32, q_lora_rank=0, qk_nope_head_dim=16,
+            cfg.mla = dataclasses.replace(
+                cfg.mla, kv_lora_rank=32, q_lora_rank=0, qk_nope_head_dim=16,
                 qk_rope_head_dim=8, v_head_dim=16,
             )
         return cfg

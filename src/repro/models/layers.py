@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops, ref
 
-from .config import ModelConfig
+from .config import ModelConfig, YarnScaling
 
 Params = Dict[str, Any]
 
@@ -95,16 +95,39 @@ def unembed(params: Params, x, cfg: ModelConfig):
     return jnp.einsum("...d,dv->...v", x.astype(jnp.float32), w.astype(jnp.float32))
 
 
-def rope_freqs(head_dim: int, theta: float, fraction: float = 1.0):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor for a context stretched ``factor`` times
+    (``yarn_get_mscale`` of the published DeepSeek-V2 modeling code)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(head_dim: int, theta: float, fraction: float = 1.0,
+               yarn: Optional[YarnScaling] = None):
+    """Inverse frequencies of the rotated pairs, and how many dims rotate.
+    With ``yarn`` (arXiv:2309.00071): pairs that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, pairs that turn more than ``beta_fast`` times keep their
+    frequency, and a linear ramp blends the pairs between."""
     rot = int(head_dim * fraction) // 2 * 2
     inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    if yarn is not None:
+        def dim_of(turns):  # the pair index that turns ``turns`` times
+            ctx = yarn.original_max_position_embeddings
+            return rot * math.log(ctx / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+        high = min(math.ceil(dim_of(yarn.beta_slow)), rot - 1)
+        ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / yarn.factor * ramp + inv * (1.0 - ramp)
     return inv, rot
 
 
-def apply_rope(x, positions, theta: float, fraction: float = 1.0):
+def apply_rope(x, positions, theta: float, fraction: float = 1.0,
+               yarn: Optional[YarnScaling] = None):
     """x: (..., S, H, D) or (..., S, D); positions: (..., S)."""
     d = x.shape[-1]
-    inv, rot = rope_freqs(d, theta, fraction)
+    inv, rot = rope_freqs(d, theta, fraction, yarn)
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, rot/2)
     if x.ndim == ang.ndim + 1:  # head axis present
         ang = ang[..., None, :]
@@ -114,6 +137,10 @@ def apply_rope(x, positions, theta: float, fraction: float = 1.0):
     r1 = x1 * cos - x2 * sin
     r2 = x1 * sin + x2 * cos
     out = jnp.stack([r1, r2], axis=-1).reshape(*x1.shape[:-1], rot)
+    if yarn is not None and yarn.mscale != yarn.mscale_all_dim:
+        # the published cos/sin carry mscale / mscale_all_dim; 1 where equal
+        out = out * (yarn_mscale(yarn.factor, yarn.mscale)
+                     / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     if rot < d:
         out = jnp.concatenate([out, x[..., rot:].astype(jnp.float32)], axis=-1)
     return out.astype(x.dtype)
@@ -408,6 +435,21 @@ def attention_decode(params, x, cfg: ModelConfig, cache, pos, window=None,
 # ---------------------------------------------------------------------------
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``q_head_dim ** -0.5``, times ``yarn_mscale(factor,
+    mscale_all_dim) ** 2`` under YaRN, as DeepSeek-V2 publishes it."""
+    m = cfg.mla
+    sm = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    y = m.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        sm *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return sm
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    return apply_rope(x, positions, cfg.rope_theta, yarn=cfg.mla.rope_scaling)
+
+
 def init_mla(key, cfg: ModelConfig) -> Params:
     m = cfg.mla
     d, h = cfg.d_model, cfg.num_heads
@@ -434,11 +476,9 @@ def mla_full(params, x, cfg: ModelConfig, positions):
         b, s, h, m.qk_nope_head_dim + m.qk_rope_head_dim
     )
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = _mla_rope(q_pe, positions, cfg)
     c_kv = rmsnorm(jnp.einsum("bsd,de->bse", x, params["w_dkv"]), params["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(
-        jnp.einsum("bsd,de->bse", x, params["w_kpe"]), positions, cfg.rope_theta
-    )
+    k_pe = _mla_rope(jnp.einsum("bsd,de->bse", x, params["w_kpe"]), positions, cfg)
     k_nope = jnp.einsum("bsr,re->bse", c_kv, params["w_uk"]).reshape(
         b, s, h, m.qk_nope_head_dim
     )
@@ -447,11 +487,11 @@ def mla_full(params, x, cfg: ModelConfig, positions):
     qfull = jnp.concatenate([q_nope, q_pe], axis=-1).transpose(0, 2, 1, 3)
     kfull = jnp.concatenate([k_nope, k_pe_h], axis=-1).transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    sm = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    out = ops.attention(
-        qfull, kfull, vt, causal=True, sm_scale=sm,
-        backend=cfg.kernel_backend if cfg.kernel_backend != "auto" else None,
-    )
+    with jax.named_scope("mla.attend"):
+        out = ops.attention(
+            qfull, kfull, vt, causal=True, sm_scale=mla_softmax_scale(cfg),
+            backend=cfg.kernel_backend if cfg.kernel_backend != "auto" else None,
+        )
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * m.v_head_dim)
     return jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["w_o"])
 
@@ -514,7 +554,6 @@ def _mla_out_proj(params, out_lat, x_dtype, cfg: ModelConfig):
 def mla_decode(params, x, cfg: ModelConfig, cache, pos, window=None):
     """Latent-cache decode: absorb W_uk into q and attend in latent space —
     the FlashMLA serving path (paper Fig. 18), backed by our MLA kernel."""
-    m = cfg.mla
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q_nope, q_pe, c_kv, k_pe = _mla_decode_qkv(params, x, cfg, posb[:, None])
@@ -525,13 +564,13 @@ def mla_decode(params, x, cfg: ModelConfig, cache, pos, window=None):
     cache_ckv = jax.vmap(upd)(cache["c_kv"], c_kv[:, None, None, :], posb)
     cache_kpe = jax.vmap(upd)(cache["k_pe"], k_pe[:, :, None, :], posb)
     q_lat = _mla_absorbed_q(params, q_nope, cfg)
-    sm = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     # attend over the latent cache (mask positions beyond pos via kv_len)
-    out_lat = ref.mla_masked(
-        q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype),
-        cache_ckv[:, :, 0], cache_kpe[:, :, 0], pos + 1, sm,
-        window=window, logit_soft_cap=cfg.logit_soft_cap,
-    )
+    with jax.named_scope("mla.attend"):
+        out_lat = ref.mla_masked(
+            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype),
+            cache_ckv[:, :, 0], cache_kpe[:, :, 0], pos + 1,
+            mla_softmax_scale(cfg), window=window, logit_soft_cap=cfg.logit_soft_cap,
+        )
     proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
     return proj, {"c_kv": cache_ckv, "k_pe": cache_kpe}
 
@@ -546,16 +585,14 @@ def _mla_decode_qkv(params, x, cfg: ModelConfig, posv):
         b, h, m.qk_nope_head_dim + m.qk_rope_head_dim
     )
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_pe = apply_rope(
-        q_pe.reshape(b, 1, h, m.qk_rope_head_dim), posv, cfg.rope_theta
+    q_pe = _mla_rope(
+        q_pe.reshape(b, 1, h, m.qk_rope_head_dim), posv, cfg
     ).reshape(b, h, m.qk_rope_head_dim)
     c_kv = rmsnorm(
         jnp.einsum("bd,de->be", x[:, 0], params["w_dkv"]), params["kv_norm"], cfg.norm_eps
     )
-    k_pe = apply_rope(
-        jnp.einsum("bd,de->be", x[:, 0], params["w_kpe"]).reshape(b, 1, -1),
-        posv,
-        cfg.rope_theta,
+    k_pe = _mla_rope(
+        jnp.einsum("bd,de->be", x[:, 0], params["w_kpe"]).reshape(b, 1, -1), posv, cfg
     )
     return q_nope, q_pe, c_kv, k_pe
 
@@ -568,7 +605,6 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     table, then the absorbed queries attend the gathered pages with a
     ragged length mask (ops.mla_paged: the paged MLA tile kernel, or its
     oracle on XLA hosts)."""
-    m = cfg.mla
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q_nope, q_pe, c_kv, k_pe = _mla_decode_qkv(params, x, cfg, posb[:, None])
@@ -577,7 +613,7 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     offset = posb % page_size
     phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
     q_lat = _mla_absorbed_q(params, q_nope, cfg)
-    sm = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    sm = mla_softmax_scale(cfg)
     backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
         sdt = cache["ckv_scale_pages"].dtype
@@ -587,12 +623,13 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
         kpe_pages = cache["kpe_pages"].at[phys, offset].set(pq)
         ckv_scales = cache["ckv_scale_pages"].at[phys, offset].set(cs.astype(sdt))
         kpe_scales = cache["kpe_scale_pages"].at[phys, offset].set(ps.astype(sdt))
-        out_lat = ops.mla_paged_quant(
-            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages,
-            kpe_pages, ckv_scales, kpe_scales, tables, posb + 1,
-            fmt=cfg.kv_dtype, sm_scale=sm, window=window,
-            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
-        )
+        with jax.named_scope("mla.attend"):
+            out_lat = ops.mla_paged_quant(
+                q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages,
+                kpe_pages, ckv_scales, kpe_scales, tables, posb + 1,
+                fmt=cfg.kv_dtype, sm_scale=sm, window=window,
+                logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+            )
         proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
         return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
                       "ckv_scale_pages": ckv_scales,
@@ -600,11 +637,12 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     cdt = cache["ckv_pages"].dtype
     ckv_pages = cache["ckv_pages"].at[phys, offset].set(c_kv.astype(cdt))
     kpe_pages = cache["kpe_pages"].at[phys, offset].set(k_pe[:, 0].astype(cdt))
-    out_lat = ops.mla_paged(
-        q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages, kpe_pages,
-        tables, posb + 1, sm_scale=sm, window=window,
-        logit_soft_cap=cfg.logit_soft_cap, backend=backend,
-    )
+    with jax.named_scope("mla.attend"):
+        out_lat = ops.mla_paged(
+            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages, kpe_pages,
+            tables, posb + 1, sm_scale=sm, window=window,
+            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+        )
     proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
     return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages}
 
@@ -618,15 +656,13 @@ def _mla_prefill_qkv(params, x, cfg: ModelConfig, posmat):
         b, c, h, m.qk_nope_head_dim + m.qk_rope_head_dim
     )
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim :]
-    q_pe = apply_rope(q_pe, posmat, cfg.rope_theta)
+    q_pe = _mla_rope(q_pe, posmat, cfg)
     c_kv = rmsnorm(
         jnp.einsum("bsd,de->bse", x, params["w_dkv"]), params["kv_norm"], cfg.norm_eps
     )
-    k_pe = apply_rope(
-        jnp.einsum("bsd,de->bse", x, params["w_kpe"]), posmat, cfg.rope_theta
-    )
+    k_pe = _mla_rope(jnp.einsum("bsd,de->bse", x, params["w_kpe"]), posmat, cfg)
     q_lat = _mla_absorbed_q(params, q_nope, cfg)  # (b, c, h, r)
-    sm = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    sm = mla_softmax_scale(cfg)
     # (b, h, c, ·) for the kernels/oracles
     return (q_lat.transpose(0, 2, 1, 3), q_pe.transpose(0, 2, 1, 3),
             c_kv, k_pe, sm)
@@ -646,26 +682,28 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables, lens,
     q_lat, q_pe, c_kv, k_pe, sm = _mla_prefill_qkv(params, x, cfg, posmat)
     backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
-        out_lat, ckv_pages, kpe_pages, ckv_scales, kpe_scales = (
-            ops.mla_prefill_quant(
-                q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
-                cache["ckv_pages"], cache["kpe_pages"],
-                cache["ckv_scale_pages"], cache["kpe_scale_pages"],
-                tables, posb, jnp.asarray(lens, jnp.int32), fmt=cfg.kv_dtype,
-                sm_scale=sm, window=window,
-                logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+        with jax.named_scope("mla.attend"):
+            out_lat, ckv_pages, kpe_pages, ckv_scales, kpe_scales = (
+                ops.mla_prefill_quant(
+                    q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
+                    cache["ckv_pages"], cache["kpe_pages"],
+                    cache["ckv_scale_pages"], cache["kpe_scale_pages"],
+                    tables, posb, jnp.asarray(lens, jnp.int32), fmt=cfg.kv_dtype,
+                    sm_scale=sm, window=window,
+                    logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+                )
             )
-        )
         proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
         return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
                       "ckv_scale_pages": ckv_scales,
                       "kpe_scale_pages": kpe_scales}
-    out_lat, ckv_pages, kpe_pages = ops.mla_prefill(
-        q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
-        cache["ckv_pages"], cache["kpe_pages"], tables, posb,
-        jnp.asarray(lens, jnp.int32), sm_scale=sm, window=window,
-        logit_soft_cap=cfg.logit_soft_cap, backend=backend,
-    )
+    with jax.named_scope("mla.attend"):
+        out_lat, ckv_pages, kpe_pages = ops.mla_prefill(
+            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
+            cache["ckv_pages"], cache["kpe_pages"], tables, posb,
+            jnp.asarray(lens, jnp.int32), sm_scale=sm, window=window,
+            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+        )
     proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
     return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages}
 
@@ -684,11 +722,12 @@ def mla_prefill(params, x, cfg: ModelConfig, cache, pos, lens, window=None):
     size = cache["c_kv"].shape[1]
     r = jnp.arange(size, dtype=jnp.int32)[None, :]  # (1, S)
     ctx_pos = jnp.where(r < posb[:, None], r, -1)
-    out_lat = ref.mla_prefill(
-        q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
-        cache["c_kv"][:, :, 0], cache["k_pe"][:, :, 0], ctx_pos, posmat,
-        lens, sm_scale=sm, window=window, logit_soft_cap=cfg.logit_soft_cap,
-    )
+    with jax.named_scope("mla.attend"):
+        out_lat = ref.mla_prefill(
+            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
+            cache["c_kv"][:, :, 0], cache["k_pe"][:, :, 0], ctx_pos, posmat,
+            lens, sm_scale=sm, window=window, logit_soft_cap=cfg.logit_soft_cap,
+        )
     proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
     # write the chunk into the strip as a gather-select over cache entries
     rel = r - posb[:, None]  # (B, S)
@@ -741,17 +780,19 @@ def mlp(params: Params, x, cfg: ModelConfig):
 
 
 # ---------------------------------------------------------------------------
-# MoE (GShard-style capacity dispatch; EP- or TP-shardable expert weights)
+# MoE: dropless top-k routing over the held experts
 # ---------------------------------------------------------------------------
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
+    """The router scores every routed expert; expert weights exist only for
+    the ``held`` experts (``MoEConfig``)."""
     mo = cfg.moe
-    d, fe, e = cfg.d_model, mo.d_ff_expert, mo.num_experts
+    d, fe, e = cfg.d_model, mo.d_ff_expert, mo.held
     ks = _split(key, 5)
     scale = 1.0 / math.sqrt(d)
     p = {
-        "router": _dense_init(ks[0], d, e, "float32"),
+        "router": _dense_init(ks[0], d, mo.num_experts, "float32"),
         "w_gate": (jax.random.normal(ks[1], (e, d, fe), jnp.float32) * scale).astype(cfg.dtype),
         "w_up": (jax.random.normal(ks[2], (e, d, fe), jnp.float32) * scale).astype(cfg.dtype),
         "w_down": (jax.random.normal(ks[3], (e, fe, d), jnp.float32) / math.sqrt(fe)).astype(cfg.dtype),
@@ -761,83 +802,63 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _moe_groups(t: int, batch: int) -> int:
-    """Dispatch-group count: groups align with the data-parallel shards so
-    every scatter/gather is shard-local (GShard grouping).  Must divide t."""
-    for g in (16, 8, 4, 2):
-        if t % g == 0 and t // g >= 1:
-            return g
-    return 1
+def moe_layers(cfg: ModelConfig) -> int:
+    """How many layers of ``cfg`` route through :func:`moe`."""
+    mo = cfg.moe
+    if mo is None or not mo.num_experts:
+        return 0
+    return cfg.num_layers - mo.first_dense_layers
 
 
-def moe(params: Params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output, aux_loss).
+def moe_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Expert FFN rows one :func:`moe` layer computes for ``tokens`` token
+    rows: every held expert runs every row (dense compute)."""
+    return tokens * cfg.moe.held
 
-    Capacity-based top-k routing with **grouped scatter/gather dispatch**
-    (GShard grouping): tokens are split into G groups (aligned with the
-    data shards), each group scatters into its own (E, cap_g) expert
-    buffers via a vmapped (batched) scatter — so the SPMD partitioner sees
-    a scatter with a leading batch dim and never rewrites it into a
-    cross-shard one-hot contraction.  Expert buffers (G, E, cap_g, D) shard
-    G over data and E over `model` (EP) when E divides; dispatch cost stays
-    O(T·k·D) and all shapes are static."""
+
+def moe(params: Params, x, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns ``(output, aux_loss, routed)``.
+
+    Dropless top-k routing: the router's softmax over all ``num_experts``
+    picks each token's ``experts_per_token`` (gates renormalized only with
+    ``norm_topk_prob``), and the layer adds, for every token, the gated
+    output of each held expert it picked, plus the shared experts.  Every
+    held expert runs every token (einsums over the held experts, gate 0
+    where unpicked), so no token is ever dropped and a token's output does
+    not depend on its batch-mates.  What experts held elsewhere add is left
+    out: this is one device's part of an expert-parallel layer.
+    ``routed`` (B, S) int32 counts each token's picks among the held
+    experts; ``aux_loss`` is the Switch-style load-balance loss over all
+    experts."""
     mo = cfg.moe
     b, s, d = x.shape
     t = b * s
     e, k = mo.num_experts, mo.experts_per_token
-    G = _moe_groups(t, b)
-    tg = t // G
-    cap = max(1, int(mo.capacity_factor * tg * k / e))
-    xg = x.reshape(G, tg, d)
-    logits = jnp.einsum(
-        "gtd,de->gte", xg.astype(jnp.float32), params["router"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (G, tg, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    # position of each (token, slot) within its expert queue, per group
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)  # (G, tg, k, e)
-    flat = onehot.reshape(G, tg * k, e)
-    pos_in_expert = jnp.cumsum(flat, axis=1) - flat
-    pos = jnp.sum(pos_in_expert * flat, axis=-1)  # (G, tg*k)
-    keep = (pos < cap).astype(x.dtype)
-    slot = jnp.clip(pos, 0, cap - 1)
-    eidx = gate_idx.reshape(G, tg * k)
-
-    # batched scatter: every group's tokens land in its own expert buffers
-    updates = (
-        xg[:, :, None, :] * keep.reshape(G, tg, k)[..., None]
-    ).reshape(G, tg * k, d)
-
-    def scatter_one(ei, sl, upd):
-        buf = jnp.zeros((e, cap, d), x.dtype)
-        return buf.at[ei, sl].add(upd, mode="drop")
-
-    expert_in = jax.vmap(scatter_one)(eidx, slot, updates)  # (G, e, cap, d)
-    expert_in = _hint("moe_expert", expert_in)
-    g_ = jnp.einsum("gecd,edf->gecf", expert_in, params["w_gate"])
-    u = jnp.einsum("gecd,edf->gecf", expert_in, params["w_up"])
-    h = jax.nn.silu(g_) * u
-    expert_out = _hint(
-        "moe_expert", jnp.einsum("gecf,efd->gecd", h, params["w_down"])
-    )
-
-    def gather_one(buf, ei, sl):
-        return buf[ei, sl]  # (tg*k, d)
-
-    gathered = jax.vmap(gather_one)(expert_out, eidx, slot)
-    wts = (gate_vals.reshape(G, tg * k) * keep)[..., None].astype(gathered.dtype)
-    out = jnp.sum((gathered * wts).reshape(G, tg, k, d), axis=2)
-    out = out.reshape(t, d)
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum(
+            "td,de->te", xt.astype(jnp.float32), params["router"].astype(jnp.float32)
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (t, k)
+        if mo.norm_topk_prob:
+            gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+        held = mo.first_held_expert + jnp.arange(mo.held, dtype=gate_idx.dtype)
+        picked = gate_idx[:, :, None] == held  # (t, k, held)
+        gates = jnp.sum(jnp.where(picked, gate_vals[:, :, None], 0.0), axis=1)
+        routed = jnp.sum(picked, axis=(1, 2), dtype=jnp.int32)
+    with jax.named_scope("moe.experts"):
+        g_ = jnp.einsum("td,edf->etf", xt, params["w_gate"])
+        u = jnp.einsum("td,edf->etf", xt, params["w_up"])
+        h = jax.nn.silu(g_) * u * gates.T[:, :, None].astype(x.dtype)
+        out = jnp.einsum("etf,efd->td", _hint("moe_expert", h), params["w_down"])
     if "shared" in params:
-        out = out + mlp(params["shared"], x.reshape(t, d), cfg)
+        out = out + mlp(params["shared"], xt, cfg)
     # load-balance auxiliary loss (Switch-style)
-    density = jnp.mean(
-        jax.nn.one_hot(gate_idx[..., 0], e, dtype=jnp.float32), axis=(0, 1)
-    )
-    density_prob = jnp.mean(probs, axis=(0, 1))
+    density = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32), axis=0)
+    density_prob = jnp.mean(probs, axis=0)
     aux = jnp.sum(density * density_prob) * e * mo.router_aux_weight
-    return out.reshape(b, s, d).astype(x.dtype), aux
+    return out.reshape(b, s, d).astype(x.dtype), aux, routed.reshape(b, s)
 
 
 # ---------------------------------------------------------------------------

@@ -48,13 +48,6 @@ def _init_block(key, cfg: ModelConfig, layer_idx: int, dense_ffn: bool) -> Dict:
             p["moe"] = L.init_moe(ks[2], cfg)
         elif cfg.d_ff:
             p["mlp"] = L.init_mlp(ks[2], cfg)
-        elif cfg.moe:
-            # dense prefix layer of an MoE model: widen to ~active-expert FLOPs
-            p["mlp"] = L.init_mlp(
-                ks[2], cfg,
-                d_ff=cfg.moe.d_ff_expert
-                * max(cfg.moe.experts_per_token + cfg.moe.num_shared_experts, 1),
-            )
     return p
 
 
@@ -138,7 +131,7 @@ def _block_full(p, x, cfg: ModelConfig, positions, window, rope_fraction):
     x = x + delta
     if "moe" in p:
         h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        out, aux = L.moe(p["moe"], h2, cfg)
+        out, aux, _ = L.moe(p["moe"], h2, cfg)
         x = x + L._hint("block_out", out)
     elif "mlp" in p:
         h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
@@ -543,60 +536,87 @@ def _block_decode(p, x, cfg: ModelConfig, cache, pos, window,
             sc = _per_slot(live, sc, cache["ssm"])
         new_cache["ssm"] = sc
     x = x + delta
+    x, routed = _ffn(p, x, cfg)
+    return x, new_cache, routed
+
+
+def _ffn(p, x, cfg: ModelConfig):
+    """A block's FFN half for decode and prefill: ``(x, routed)``, where
+    ``routed`` counts each token's picks among the held experts (None for
+    a block without experts)."""
     if "moe" in p:
         h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        out, _ = L.moe(p["moe"], h2, cfg)
-        x = x + out
-    elif "mlp" in p:
+        out, _, routed = L.moe(p["moe"], h2, cfg)
+        return x + out, routed
+    if "mlp" in p:
         h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
         x = x + L.mlp(p["mlp"], h2, cfg)
-    return x, new_cache
+    return x, None
+
+
+def _plus(acc, n):
+    return acc if n is None else acc + n
+
+
+def _routed_zeros(cfg: ModelConfig, rows):
+    """The starting count of held-expert picks for ``rows`` (B, S) tokens:
+    zeros where the model has MoE layers, None where it has none."""
+    return jnp.zeros(rows, jnp.int32) if L.moe_layers(cfg) else None
 
 
 def decode_step(params, cfg: ModelConfig, cache: Cache, token, pos,
-                unroll: int = 1, live=None):
+                unroll: int = 1, live=None, *, count_routed: bool = False):
     """One decode step: token (B,) int32, pos scalar int32 -> (logits, cache).
 
     ``live`` (optional (B,) bool) marks slots genuinely stepping — see
     :func:`_block_decode`; serving passes it so parked slots cannot mutate
-    recurrent state and recycled slots start from clean state.
+    recurrent state and recycled slots start from clean state.  With
+    ``count_routed`` a third result counts each slot's picks among the
+    held experts over all layers ((B,) int32; None without MoE layers).
     """
     x = L.embed(params["embed"], token[:, None]).astype(cfg.dtype)
     wlist = static_windows(cfg)
     n_prefix = len(params["prefix_layers"])
     layout, tables = cache.layout, cache.tables
+    routed = _routed_zeros(cfg, x.shape[:2])
     new_prefix = []
     for i, p in enumerate(params["prefix_layers"]):
-        x, c = _block_decode(p, x, cfg, cache.prefix[i], pos, wlist[i],
-                             layout, tables, live)
+        x, c, r = _block_decode(p, x, cfg, cache.prefix[i], pos, wlist[i],
+                                layout, tables, live)
+        routed = _plus(routed, r)
         new_prefix.append(c)
 
     if cache.stacked:
         wcommon = wlist[n_prefix] if cfg.num_layers > n_prefix else None
 
-        def body(x, inp):
+        def body(carry, inp):
+            x, acc = carry
             p, c = inp
-            x, cnew = _block_decode(p, x, cfg, c, pos, wcommon, layout,
-                                    tables, live)
-            return x, cnew
+            x, cnew, r = _block_decode(p, x, cfg, c, pos, wcommon, layout,
+                                       tables, live)
+            return (x, _plus(acc, r)), cnew
 
-        x, new_rest = jax.lax.scan(
-            body, x, (params["layers"], cache.rest), unroll=unroll
+        (x, routed), new_rest = jax.lax.scan(
+            body, (x, routed), (params["layers"], cache.rest), unroll=unroll
         )
     else:
         new_rest = []
         layer_list = _unstack(params["layers"], cfg.num_layers - n_prefix)
         for j, (p, c) in enumerate(zip(layer_list, cache.rest)):
-            x, cnew = _block_decode(p, x, cfg, c, pos, wlist[n_prefix + j],
-                                    layout, tables, live)
+            x, cnew, r = _block_decode(p, x, cfg, c, pos, wlist[n_prefix + j],
+                                       layout, tables, live)
+            routed = _plus(routed, r)
             new_rest.append(cnew)
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.unembed(params["embed"], x, cfg)[:, 0]
     if cfg.logit_soft_cap:
         logits = cfg.logit_soft_cap * jnp.tanh(logits / cfg.logit_soft_cap)
-    return logits, Cache(new_prefix, new_rest, cache.stacked, cache.max_len,
-                         layout, cache.page_size, tables)
+    cache = Cache(new_prefix, new_rest, cache.stacked, cache.max_len, layout,
+                  cache.page_size, tables)
+    if count_routed:
+        return logits, cache, None if routed is None else routed[:, 0]
+    return logits, cache
 
 
 def decode_loop(params, cfg: ModelConfig, cache: Cache, feed, pos, key,
@@ -631,27 +651,36 @@ def decode_loop(params, cfg: ModelConfig, cache: Cache, feed, pos, key,
     on slot reuse) and recurrent state is held by the ``live`` mask inside
     ``decode_step``, so a dead iteration is behaviorally a no-op.
 
-    Returns ``(tokens (n_steps, B), emitted (n_steps, B) bool, key, cache)``
-    — ``emitted[t, b]`` marks a token the host must deliver; rows after the
-    last live iteration are all-False.
+    Returns ``(tokens (n_steps, B), emitted (n_steps, B) bool, key, cache,
+    routed)`` — ``emitted[t, b]`` marks a token the host must deliver; rows
+    after the last live iteration are all-False.  ``routed`` counts the
+    live slots' picks among the held experts over every tick and layer
+    (int32; None without MoE layers).
     """
+    counting = L.moe_layers(cfg) > 0
+
     def body(carry, _):
-        cache, feed, pos, key, live, remaining = carry
-        logits, cache = decode_step(params, cfg, cache, feed, pos,
-                                    unroll=unroll, live=live)
+        cache, feed, pos, key, live, remaining, routed = carry
+        if counting:
+            logits, cache, r = decode_step(params, cfg, cache, feed, pos, unroll=unroll,
+                                           live=live, count_routed=True)
+            routed = routed + jnp.sum(jnp.where(live, r, 0))
+        else:
+            logits, cache = decode_step(params, cfg, cache, feed, pos,
+                                        unroll=unroll, live=live)
         tok, key = sample_fn(logits, key, live.any())
         tok = jnp.where(live, tok, feed)
         pos = jnp.where(live, pos + 1, pos)
         remaining = jnp.where(live, remaining - 1, remaining)
         stop = (tok == eos_id) | (remaining <= 0) | (pos >= max_len)
-        return (cache, tok, pos, key, live & ~stop, remaining), (tok, live)
+        return (cache, tok, pos, key, live & ~stop, remaining, routed), (tok, live)
 
     carry = (cache, jnp.asarray(feed, jnp.int32), jnp.asarray(pos, jnp.int32),
-             key, live, jnp.asarray(remaining, jnp.int32))
-    (cache, _, _, key, _, _), (toks, emitted) = jax.lax.scan(
+             key, live, jnp.asarray(remaining, jnp.int32), _routed_zeros(cfg, ()))
+    (cache, _, _, key, _, _, routed), (toks, emitted) = jax.lax.scan(
         body, carry, None, length=n_steps
     )
-    return toks, emitted, key, cache
+    return toks, emitted, key, cache, routed
 
 
 def _block_prefill(p, x, cfg: ModelConfig, cache, pos, lens, window,
@@ -685,14 +714,8 @@ def _block_prefill(p, x, cfg: ModelConfig, cache, pos, lens, window,
         )
         new_cache["kv"] = kv
     x = x + delta
-    if "moe" in p:
-        h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        out, _ = L.moe(p["moe"], h2, cfg)
-        x = x + out
-    elif "mlp" in p:
-        h2 = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + L.mlp(p["mlp"], h2, cfg)
-    return x, new_cache
+    x, routed = _ffn(p, x, cfg)
+    return x, new_cache, routed
 
 
 def supports_chunked_prefill(cfg: ModelConfig) -> bool:
@@ -707,8 +730,10 @@ def _prefill_trunk(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
                    unroll: int = 1):
     """The shared chunk-wide forward pass behind :func:`prefill_step` and
     :func:`verify_step`: embed, every block's chunk attention + KV page
-    writes, final norm.  Returns ``(x (B, C, d), Cache)`` — the hidden
-    states of every chunk position, before any logits projection."""
+    writes, final norm.  Returns ``(x (B, C, d), Cache, routed)`` — the
+    hidden states of every chunk position, before any logits projection,
+    and each position's picks among the held experts over all layers
+    ((B, C) int32; None without MoE layers)."""
     if not supports_chunked_prefill(cfg):
         raise NotImplementedError(
             f"chunked prefill supports attention archs (GQA/MLA); {cfg.name} "
@@ -720,39 +745,43 @@ def _prefill_trunk(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
     n_prefix = len(params["prefix_layers"])
     layout, tables = cache.layout, cache.tables
     lens = jnp.asarray(lens, jnp.int32)
+    routed = _routed_zeros(cfg, x.shape[:2])
     new_prefix = []
     for i, p in enumerate(params["prefix_layers"]):
-        x, c = _block_prefill(p, x, cfg, cache.prefix[i], pos, lens, wlist[i],
-                              layout, tables)
+        x, c, r = _block_prefill(p, x, cfg, cache.prefix[i], pos, lens, wlist[i],
+                                 layout, tables)
+        routed = _plus(routed, r)
         new_prefix.append(c)
 
     if cache.stacked:
         wcommon = wlist[n_prefix] if cfg.num_layers > n_prefix else None
 
-        def body(x, inp):
+        def body(carry, inp):
+            x, acc = carry
             p, c = inp
-            x, cnew = _block_prefill(p, x, cfg, c, pos, lens, wcommon,
-                                     layout, tables)
-            return x, cnew
+            x, cnew, r = _block_prefill(p, x, cfg, c, pos, lens, wcommon,
+                                        layout, tables)
+            return (x, _plus(acc, r)), cnew
 
-        x, new_rest = jax.lax.scan(
-            body, x, (params["layers"], cache.rest), unroll=unroll
+        (x, routed), new_rest = jax.lax.scan(
+            body, (x, routed), (params["layers"], cache.rest), unroll=unroll
         )
     else:
         new_rest = []
         layer_list = _unstack(params["layers"], cfg.num_layers - n_prefix)
         for j, (p, c) in enumerate(zip(layer_list, cache.rest)):
-            x, cnew = _block_prefill(p, x, cfg, c, pos, lens,
-                                     wlist[n_prefix + j], layout, tables)
+            x, cnew, r = _block_prefill(p, x, cfg, c, pos, lens,
+                                        wlist[n_prefix + j], layout, tables)
+            routed = _plus(routed, r)
             new_rest.append(cnew)
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x, Cache(new_prefix, new_rest, cache.stacked, cache.max_len,
-                    layout, cache.page_size, tables)
+                    layout, cache.page_size, tables), routed
 
 
 def prefill_step(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
-                 unroll: int = 1):
+                 unroll: int = 1, *, count_routed: bool = False):
     """One chunked-prefill step: a (B, C) block of prompt tokens advances
     every slot with ``lens[b] > 0`` by ``lens[b]`` positions in a single
     forward pass (vs C batched decode steps under token replay).
@@ -762,11 +791,13 @@ def prefill_step(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
     Returns ``(logits, cache)`` where ``logits`` (B, V) belong to each
     slot's *last live* chunk token — exactly what sampling needs when a
     chunk completes its prompt.  Works against both cache layouts through
-    the same ``Cache`` interface as ``decode_step``.
+    the same ``Cache`` interface as ``decode_step``.  With ``count_routed``
+    a third result counts each chunk position's picks among the held
+    experts over all layers ((B, C) int32; None without MoE layers).
     """
     lens = jnp.asarray(lens, jnp.int32)
-    x, cache = _prefill_trunk(params, cfg, cache, tokens, pos, lens,
-                              unroll=unroll)
+    x, cache, routed = _prefill_trunk(params, cfg, cache, tokens, pos, lens,
+                                      unroll=unroll)
     # each slot's last live chunk position feeds the logits (idle slots
     # gather row 0 — garbage the engine ignores)
     last = jnp.clip(lens - 1, 0, x.shape[1] - 1)
@@ -774,11 +805,13 @@ def prefill_step(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
     logits = L.unembed(params["embed"], x_last, cfg)
     if cfg.logit_soft_cap:
         logits = cfg.logit_soft_cap * jnp.tanh(logits / cfg.logit_soft_cap)
+    if count_routed:
+        return logits, cache, routed
     return logits, cache
 
 
 def verify_step(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
-                unroll: int = 1):
+                unroll: int = 1, *, count_routed: bool = False):
     """Speculative-decode verify: score every chunk position in one pass.
 
     This *is* chunked prefill — the same ``_prefill_trunk`` (same kernels,
@@ -787,13 +820,16 @@ def verify_step(params, cfg: ModelConfig, cache: Cache, tokens, pos, lens,
     token, verify unembeds the whole chunk, because accept/rollback needs
     the model's next-token distribution after *every* draft prefix.
     Returns ``(logits (B, C, V), cache)``; rows of idle slots
-    (``lens == 0``) are garbage the caller masks.
+    (``lens == 0``) are garbage the caller masks.  ``count_routed`` adds
+    ``routed`` as :func:`prefill_step` does.
     """
-    x, cache = _prefill_trunk(params, cfg, cache, tokens, pos, lens,
-                              unroll=unroll)
+    x, cache, routed = _prefill_trunk(params, cfg, cache, tokens, pos, lens,
+                                      unroll=unroll)
     logits = L.unembed(params["embed"], x, cfg)
     if cfg.logit_soft_cap:
         logits = cfg.logit_soft_cap * jnp.tanh(logits / cfg.logit_soft_cap)
+    if count_routed:
+        return logits, cache, routed
     return logits, cache
 
 
@@ -878,8 +914,10 @@ def spec_decode_loop(params, cfg: ModelConfig, cache: Cache, feed, pos, key,
     engine to FAIL exactly that request.
 
     Returns ``(targets (n, B, C), emitted (n, B, C) bool, bad (n, B) bool,
-    key, cache)`` with ``C = draft_len + 1``; ``emitted[t, b, i]`` marks
-    target ``i`` of round ``t`` as a token the host must deliver, in order.
+    key, cache, routed)`` with ``C = draft_len + 1``; ``emitted[t, b, i]``
+    marks target ``i`` of round ``t`` as a token the host must deliver, in
+    order; ``routed`` counts the live slots' verify rows' picks among the
+    held experts (int32; None without MoE layers).
     """
     c = draft_len + 1
     feed = jnp.asarray(feed, jnp.int32)
@@ -889,12 +927,14 @@ def spec_decode_loop(params, cfg: ModelConfig, cache: Cache, feed, pos, key,
     h = history.shape[1]
 
     def body(carry, _):
-        cache, feed, pos, key, live, remaining, history = carry
+        cache, feed, pos, key, live, remaining, history, routed = carry
         drafts = propose_fn(history, pos, feed, draft_len)
         chunk = jnp.concatenate([feed[:, None], drafts], axis=1)
         lens = jnp.where(live, c, 0).astype(jnp.int32)
-        logits, cache = verify_step(params, cfg, cache, chunk, pos, lens,
-                                    unroll=unroll)
+        logits, cache, r = verify_step(params, cfg, cache, chunk, pos, lens,
+                                       unroll=unroll, count_routed=True)
+        if r is not None:
+            routed = routed + jnp.sum(jnp.where(live[:, None], r, 0))
         logits = jnp.where(poison[:, None, None], jnp.nan, logits)
         bad = jnp.any(~jnp.any(jnp.isfinite(logits), axis=-1), axis=-1) & live
         tgt, key = sample_fn(logits, key, live.any())
@@ -931,17 +971,17 @@ def spec_decode_loop(params, cfg: ModelConfig, cache: Cache, feed, pos, key,
             | (pos >= max_len)
             | bad
         )
-        return (cache, feed, pos, key, live & ~stop, remaining, history), (
+        return (cache, feed, pos, key, live & ~stop, remaining, history, routed), (
             tgt, emit, bad,
         )
 
     carry = (cache, feed, jnp.asarray(pos, jnp.int32), key, live,
              jnp.asarray(remaining, jnp.int32),
-             jnp.asarray(history, jnp.int32))
-    (cache, _, _, key, _, _, _), (toks, emitted, bad) = jax.lax.scan(
+             jnp.asarray(history, jnp.int32), _routed_zeros(cfg, ()))
+    (cache, _, _, key, _, _, _, routed), (toks, emitted, bad) = jax.lax.scan(
         body, carry, None, length=n_rounds
     )
-    return toks, emitted, bad, key, cache
+    return toks, emitted, bad, key, cache, routed
 
 
 def _unstack(tree, n):

@@ -104,6 +104,7 @@ import numpy as np
 
 from repro.core.errors import GuardError
 from repro.kernels.ops import guard_dispatch, paged_walk
+from repro.models import layers as L
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -139,6 +140,15 @@ _STEP_FNS: "collections.OrderedDict[tuple, object]" = collections.OrderedDict()
 _STEP_FNS_MAX = 8
 
 
+def _with_count(out, count):
+    """``out`` with one more entry along its first axis, whose first element
+    is ``count``: a count the host wants rides back in the transfer that
+    reads ``out``, with no copy of its own (``ServingEngine._run_program``
+    takes it off again)."""
+    extra = jnp.zeros((out[:1].size,), out.dtype).at[0].set(count.astype(out.dtype))
+    return jnp.concatenate([out, extra.reshape(out[:1].shape)])
+
+
 def _cached_fn(key, build):
     fn = _STEP_FNS.get(key)
     if fn is None:
@@ -157,16 +167,25 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
     ``poison`` is the fault injector's NaN overwrite mask (all-False in
     normal operation) and ``bad`` flags rows whose logits held no finite
     value — injected or genuine — so the engine can fail exactly the
-    affected request instead of emitting garbage."""
+    affected request instead of emitting garbage.  A model with MoE layers
+    appends its live slots' held-expert picks to ``tokens``
+    (:func:`_with_count`)."""
 
     def build():
         snap = copy.deepcopy(cfg)
+        routes = L.moe_layers(snap) > 0
 
         def decode_step(p, c, tok, pos, key, live, poison):
-            logits, c = lm.decode_step(p, snap, c, tok, pos, live=live)
+            if routes:
+                logits, c, routed = lm.decode_step(p, snap, c, tok, pos, live=live,
+                                                   count_routed=True)
+            else:
+                logits, c = lm.decode_step(p, snap, c, tok, pos, live=live)
             logits = jnp.where(poison[:, None], jnp.nan, logits)
             bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
             tok, key = sample_step(logits, key, temperature=temperature)
+            if routes:
+                tok = _with_count(tok, jnp.sum(jnp.where(live, routed, 0)))
             return tok, bad, c, key
 
         return jax.jit(decode_step, donate_argnums=(1,))
@@ -179,16 +198,25 @@ def _prefill_step_fn(cfg: ModelConfig, temperature: float):
     is a trace-time shape, so differing ``prefill_chunk`` values simply
     trace separate entries under the same wrapper).  Sampling is fused like
     the decode step: the returned tokens are what a chunk that completes
-    its prompt emits.  ``poison``/``bad`` mirror the decode step."""
+    its prompt emits.  ``poison``/``bad`` and the held-expert picks (of the
+    live chunk positions) mirror the decode step."""
 
     def build():
         snap = copy.deepcopy(cfg)
+        routes = L.moe_layers(snap) > 0
 
         def prefill_step(p, c, toks, pos, lens, key, poison):
-            logits, c = lm.prefill_step(p, snap, c, toks, pos, lens)
+            if routes:
+                logits, c, routed = lm.prefill_step(p, snap, c, toks, pos, lens,
+                                                    count_routed=True)
+            else:
+                logits, c = lm.prefill_step(p, snap, c, toks, pos, lens)
             logits = jnp.where(poison[:, None], jnp.nan, logits)
             bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
             tok, key = sample_step(logits, key, temperature=temperature)
+            if routes:
+                chunk_live = jnp.arange(toks.shape[1]) < lens[:, None]
+                tok = _with_count(tok, jnp.sum(jnp.where(chunk_live, routed, 0)))
             return tok, bad, c, key
 
         return jax.jit(prefill_step, donate_argnums=(1,))
@@ -210,11 +238,14 @@ def _decode_loop_fn(cfg: ModelConfig, temperature: float, n_steps: int,
                                gate=gate)
 
         def loop(p, c, feed, pos, key, live, remaining):
-            return lm.decode_loop(
+            toks, emitted, key, c, routed = lm.decode_loop(
                 p, snap, c, feed, pos, key, live, remaining,
                 n_steps=n_steps, sample_fn=sample_fn, eos_id=eos_id,
                 max_len=max_len,
             )
+            if routed is not None:
+                toks = _with_count(toks, routed)
+            return toks, emitted, key, c
 
         loop.__name__ = loop.__qualname__ = f"decode_window_{n_steps}"
         return jax.jit(loop, donate_argnums=(1,))
@@ -242,12 +273,15 @@ def _spec_loop_fn(cfg: ModelConfig, temperature: float, proposer: str,
                                     gate=gate)
 
         def loop(p, c, feed, pos, key, live, remaining, history, poison):
-            return lm.spec_decode_loop(
+            toks, emitted, bad, key, c, routed = lm.spec_decode_loop(
                 p, snap, c, feed, pos, key, live, remaining, history,
                 n_rounds=n_rounds, draft_len=draft_len, propose_fn=propose,
                 sample_fn=sample_fn, accept_fn=spec_accept, eos_id=eos_id,
                 max_len=max_len, poison=poison,
             )
+            if routed is not None:
+                toks = _with_count(toks, routed)
+            return toks, emitted, bad, key, c
 
         loop.__name__ = loop.__qualname__ = f"spec_window_{n_rounds}"
         return jax.jit(loop, donate_argnums=(1,))
@@ -494,6 +528,7 @@ class ServingEngine:
             # inside the jit-cache keys) — the engine just forwards it
             cfg = dataclasses.replace(cfg, kv_dtype=serve_cfg.kv_dtype)
         self.cfg = cfg
+        self._moe_layers = L.moe_layers(cfg)
         self.params = params
         self.scfg = serve_cfg
         b = serve_cfg.slots
@@ -913,7 +948,11 @@ class ServingEngine:
         outputs, read back the first ``n_out``.  Returns ``(host outputs,
         the rest of the outputs, record)``: the rest are the new cache and
         PRNG key in ``fn``'s order, still on the device.  The caller drains
-        under ``telemetry.span("drain", record)``."""
+        under ``telemetry.span("drain", record)``.  For a model with MoE
+        layers the record counts the expert rows the program computed
+        (``rows`` token rows for prefill, one per returned token otherwise)
+        and the picks that live tokens made of them, which the program
+        appended to its first output (:func:`_with_count`)."""
         rec = telemetry.Dispatch(
             fn.__name__, ticks, tuple(self.slot_req[s].uid for s in slots),
             rows, live_rows,
@@ -928,6 +967,10 @@ class ServingEngine:
             jax.block_until_ready(out)
         with telemetry.span("readback", rec):
             host = [np.asarray(x) for x in out[:n_out]]
+        if self._moe_layers:
+            rec.expert_rows_routed = int(host[0][-1].reshape(-1)[0])
+            host[0] = host[0][:-1]
+            rec.expert_rows = self._moe_layers * L.moe_rows(self.cfg, rows or host[0].size)
         telemetry.LOG.append(rec)
         return host, out[n_out:], rec
 

@@ -76,7 +76,9 @@ class Dispatch:
     paged decode program's attention visits ``pages_walked`` KV pages of
     the ``pages_table`` its block tables hold (one KV head's walk, summed
     over ticks, slots and layers; equal where the walk is not bounded by
-    the live length)."""
+    the live length).  A program of a model with MoE layers computes
+    ``expert_rows`` rows of the held experts' FFNs (over layers), of which
+    ``expert_rows_routed`` serve a live token that picked that expert."""
 
     program: str
     ticks: int
@@ -85,6 +87,8 @@ class Dispatch:
     live_rows: int = 0
     pages_walked: int = 0
     pages_table: int = 0
+    expert_rows: int = 0
+    expert_rows_routed: int = 0
     upload: Optional[Span] = None
     dispatch: Optional[Span] = None
     wait: Optional[Span] = None
@@ -144,6 +148,9 @@ def summary(records: List, t0: float, t1: float) -> dict:
       programs (``decode_step``, ``decode_window_*``), per tick they ran.
     * ``prefill_rows``, ``prefill_live_rows``: summed over ``prefill_step``.
     * ``pages_walked``, ``pages_table``: summed over the decode programs.
+    * ``expert_rows``, ``expert_rows_routed``: summed over every program;
+      ``decode_s``, ``decode_expert_rows_routed``: the decode programs'
+      enqueue-to-ready seconds and routed rows.
     """
     window_s = t1 - t0
     steps = [(r.t0, r.t1) for r in records if isinstance(r, Step)]
@@ -169,6 +176,7 @@ def summary(records: List, t0: float, t1: float) -> dict:
     readbacks = [(r.readback[1] - r.readback[0], r.program) for r in waited]
     decode = [r for r in waited if _is_decode(r.program)]
     ticks = sum(r.ticks for r in decode)
+    decode_s = sum(r.wait[1] - r.dispatch[0] for r in decode)
     prefill = [r for r in runs if r.program == "prefill_step"]
     return {
         "window_s": window_s,
@@ -177,12 +185,15 @@ def summary(records: List, t0: float, t1: float) -> dict:
         "host_gap_s": gap_s,
         "host_gap_split": split,
         "longest_readback": list(max(readbacks)) if readbacks else None,
-        "decode_ms_per_tick": (1e3 * sum(r.wait[1] - r.dispatch[0] for r in decode) / ticks
-                               if ticks else None),
+        "decode_ms_per_tick": 1e3 * decode_s / ticks if ticks else None,
         "prefill_rows": sum(r.rows for r in prefill),
         "prefill_live_rows": sum(r.live_rows for r in prefill),
         "pages_walked": sum(r.pages_walked for r in decode),
         "pages_table": sum(r.pages_table for r in decode),
+        "expert_rows": sum(r.expert_rows for r in runs),
+        "expert_rows_routed": sum(r.expert_rows_routed for r in runs),
+        "decode_s": decode_s,
+        "decode_expert_rows_routed": sum(r.expert_rows_routed for r in decode),
     }
 
 

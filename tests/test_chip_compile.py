@@ -159,6 +159,41 @@ def test_serving_step_compiles_for_v5e(step, one_chip, mosaic):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("step", ["decode", "prefill", "window"])
+def test_mla_moe_serving_step_compiles_for_v5e(step, one_chip, mosaic):
+    """deepseek_v2_lite_16b's jitted steps at published widths, at the
+    benchmark cell's 16 slots x 4,608 positions, holding 8 of 64 experts,
+    cut to its dense layer and one MoE layer: ``PagedMLA`` and
+    ``PrefillMLA`` compile through Mosaic beside the expert einsums, and
+    nothing falls back."""
+    from repro.models import lm
+    from repro.serving import engine as E
+
+    base = get_config("deepseek_v2_lite_16b")
+    cfg = dataclasses.replace(base, num_layers=2, kernel_backend="pallas",
+                              moe=dataclasses.replace(base.moe, held_experts=8))
+    slots, max_len = 16, 4608
+    num_pages = slots * (max_len // PAGE)
+    params = jax.eval_shape(lambda k: lm.init(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_cache(
+        cfg, slots, max_len, layout="paged", page_size=PAGE, num_blocks=num_pages))
+    vec, flag = sds(slots, dtype="int32"), sds(slots, dtype="bool")
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    fn, args = {
+        "decode": (E._decode_step_fn(cfg, 0.0), (params, cache, vec, vec, key, flag, flag)),
+        "prefill": (E._prefill_step_fn(cfg, 0.0),
+                    (params, cache, sds(slots, CHUNK, dtype="int32"), vec, vec, key, flag)),
+        "window": (E._decode_loop_fn(cfg, 0.0, 8, -1, max_len),
+                   (params, cache, vec, vec, key, flag, vec)),
+    }[step]
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), args
+    )
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not ops.FALLBACKS, dict(ops.FALLBACKS)
+
+
 # The decode shapes of the benchmark's two cells (benchmarks/chip): slots,
 # table pages, query heads, KV heads.  qwen2_1_5b: group 6 over 2 KV heads;
 # deepseek_7b: MHA, 32 KV heads at group 1.

@@ -127,16 +127,18 @@ def test_decode_matches_forward_ssm(key, rng):
 
 
 def test_moe_balanced_dispatch(key, rng):
-    """MoE keeps shapes static and routes every token somewhere (cap allowing)."""
+    """MoE keeps shapes static and routes every token to its top-k experts:
+    dropless, so with every expert held each token counts k picks."""
     from repro.models import layers as L
 
     cfg = get_config("granite_moe_3b_a800m").reduced()
     params = lm.init(cfg, key)
     p_moe = jax.tree.map(lambda a: a[0], params["layers"])["moe"]
     x = jnp.asarray(rng.standard_normal((2, 16, cfg.d_model), dtype=np.float32))
-    out, aux = L.moe(p_moe, x, cfg)
+    out, aux, routed = L.moe(p_moe, x, cfg)
     assert out.shape == x.shape
     assert float(aux) >= 0
+    assert np.all(np.asarray(routed) == cfg.moe.experts_per_token)
 
 def test_param_counts_in_range():
     """Full configs should land near their nameplate sizes."""
@@ -173,15 +175,10 @@ def test_decode_matches_forward_whisper(key, rng):
 
 
 def test_decode_matches_forward_mla(key, rng):
-    """MLA latent-cache decode must match the expanded training attention.
-
-    The MoE capacity factor is raised to dropless levels: capacity overflow
-    drops tokens in the batched forward but never in one-token decode, which
-    is expected GShard behavior, not an MLA bug (verified separately)."""
-    import dataclasses
-
+    """MLA latent-cache decode must match the expanded training attention
+    (the MoE layers route dropless, so the batched forward and one-token
+    decode route every token alike)."""
     cfg = get_config("deepseek_v2_lite_16b").reduced()
-    cfg.moe = dataclasses.replace(cfg.moe, capacity_factor=16.0)
     params = lm.init(cfg, key)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(1, 8)), jnp.int32)
     full_logits, _ = lm.forward(params, cfg, toks)
