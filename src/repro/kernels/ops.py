@@ -206,33 +206,78 @@ def paged_walk(seq_lens, page_size: int, max_pages: int,
     """KV pages one KV head's decode attention visits for each slot of
     lengths ``seq_lens``, as this module runs the call
     (``paged_attention(_quant)``, or ``mla_paged(_quant)`` for
-    ``attention="mla"``): the slot's live pages (the extent the kernel
-    gives its page loop, ``attention_core.live_pages``, clamped to the
-    table; 0 for an empty slot) where it walks them in the kernel
-    (:func:`_walks_live_pages`), else the whole table, ``max_pages``."""
+    ``attention="mla"``, whose one KV head is ``head_dim`` = latent + rope
+    wide): the slot's live pages (the extent the kernel gives its page
+    loop, ``attention_core.live_pages``, clamped to the table; 0 for an
+    empty slot) where it walks them in the kernel (:func:`_walks_live_pages`),
+    else the whole table, ``max_pages``."""
     lens = np.asarray(seq_lens, np.int64)
     if not _walks_live_pages(_resolve(backend), logit_soft_cap, attention,
-                             head_dim, kv_dtype):
+                             head_dim, kv_dtype, False):
         return np.full(lens.shape, max_pages, np.int64)
     first, end = AC.live_pages(lens, page_size, window)
     return np.clip(end, 0, max_pages) - np.clip(first, 0, max_pages)
 
 
+def prefill_walk(start_lens, chunk_lens, chunk: int, page_size: int,
+                 max_pages: int, window: Optional[int] = None, *,
+                 head_dim: int, kv_dtype: Optional[str] = None,
+                 attention: str = "gqa", logit_soft_cap=None,
+                 backend: Optional[str] = None) -> Tuple[np.ndarray, np.ndarray]:
+    """``(walked, table)`` per slot: the prior pages one KV head's
+    chunked-prefill attention visits for a slot of ``start_lens`` prior
+    tokens and ``chunk_lens`` live chunk tokens, summed over the kernel's
+    ``chunk // page_size`` grid cells, and the pages those cells' tables
+    hold (cells × ``max_pages``), as this module runs the call
+    (``prefill_attention(_quant)``, or ``mla_prefill(_quant)`` for
+    ``attention="mla"``).  Where the kernel walks in the kernel
+    (:func:`_walks_live_pages`), a cell holding a live row visits the
+    pages ``[0, start // page_size)`` — from the first page the window
+    reaches from the cell's lowest query under a window — and a cell with
+    none visits nothing; elsewhere every cell visits the whole table."""
+    starts = np.asarray(start_lens, np.int64)[:, None]
+    lens = np.asarray(chunk_lens, np.int64)[:, None]
+    cells = np.arange(max(chunk // page_size, 1))[None, :]
+    table = np.full(starts.shape[0], cells.size * max_pages, np.int64)
+    if _oracle_reason(logit_soft_cap, chunk, page_size, max_pages) or not (
+            _walks_live_pages(_resolve(backend), logit_soft_cap, attention,
+                              head_dim, kv_dtype, True)):
+        return table, table
+    first, _ = AC.live_pages(starts + cells * page_size + 1, page_size, window)
+    end = np.where(cells * page_size < lens, starts // page_size, 0)
+    first, end = np.clip(first, 0, max_pages), np.clip(end, 0, max_pages)
+    return np.maximum(end - first, 0).sum(axis=1), table
+
+
 @functools.lru_cache(maxsize=None)
 def _walks_live_pages(backend: str, logit_soft_cap, attention: str,
-                      head_dim: int, kv_dtype: Optional[str]) -> bool:
-    """Whether a paged decode call visits only live pages: it runs the
-    tile kernel (not the oracle, :func:`_oracle_reason`), the kernel bounds
-    its page loop (the MLA kernels' loops keep a static extent), and the
-    lowering walks that loop inside the kernel (``GridPlan.walk``, asked
-    of the kernel at a small shape with the same tiles; a loop it cannot
-    walk there runs every table page)."""
-    if backend == "xla" or _oracle_reason(logit_soft_cap) or attention == "mla":
+                      head_dim: int, kv_dtype: Optional[str],
+                      prefill: bool) -> bool:
+    """Whether a paged decode (or chunked-prefill) call visits only live
+    pages: it runs the tile kernel (not the oracle, :func:`_oracle_reason`),
+    the kernel bounds its page loop, and the lowering walks that loop
+    inside the kernel (``GridPlan.walk``, asked of the kernel at a small
+    shape with the same tiles; a loop it cannot walk there runs every
+    table page).  The quantized MLA kernels keep two packed pools whose
+    ``(page, 1)`` scale columns no lowering DMAs by hand, and a static
+    page loop."""
+    if backend == "xla" or _oracle_reason(logit_soft_cap):
         return False
-    shape = dict(slots=1, heads=1, kv_heads=1, head_dim=head_dim, page_size=16,
-                 max_pages=2, num_pages=2)
-    prog = (paged_attention_quant_program(fmt=kv_dtype, **shape) if kv_dtype
-            else paged_attention_program(**shape))
+    shape = dict(slots=1, page_size=16, max_pages=2, num_pages=2)
+    if attention == "mla":
+        if kv_dtype:
+            return False
+        mla = dict(shape, heads=1, dim=head_dim, pe_dim=0)
+        prog = (mla_prefill_program(chunk=16, **mla) if prefill
+                else mla_paged_program(**mla))
+    else:
+        gqa = dict(shape, heads=1, kv_heads=1, head_dim=head_dim)
+        if prefill:
+            prog = (prefill_attention_quant_program(chunk=16, fmt=kv_dtype, **gqa)
+                    if kv_dtype else prefill_attention_program(chunk=16, **gqa))
+        else:
+            prog = (paged_attention_quant_program(fmt=kv_dtype, **gqa) if kv_dtype
+                    else paged_attention_program(**gqa))
     return analyze(prog, Schedule()).grid_plan.walk
 
 
@@ -548,27 +593,29 @@ def mla(q, q_pe, kv, k_pe, *, sm_scale=None, backend: Optional[str] = None,
     return kern(q, q_pe, kv, k_pe)
 
 
-def mla_paged(q_lat, q_pe, ckv_pages, kpe_pages, block_tables, seq_lens, *,
+def mla_paged(q_lat, q_pe, pages, block_tables, seq_lens, *,
               sm_scale=None, window: Optional[int] = None,
               logit_soft_cap: Optional[float] = None,
               backend: Optional[str] = None, block_h: int = 64,
               num_stages: int = 2):
-    """Paged MLA decode: latent queries (B, H, R) against latent/rope page
-    pools gathered through a block table (see kernels/mla.py).  The Pallas
-    path is the scalar-prefetch tile kernel; the XLA path is ref.mla_paged
-    (what the serving engine runs on CPU hosts).  Soft-capped models route
-    to the oracle — same policy as paged_attention."""
+    """Paged MLA decode: latent queries (B, H, R) and rope queries
+    (B, H, Dpe) against the joint page pool ``(P, page, W)`` of rows
+    ``[latent | rope | 0]`` gathered through a block table (see
+    kernels/mla.py).  The Pallas path is the scalar-prefetch tile kernel,
+    which walks each slot's live pages; the XLA path is ref.mla_paged (what
+    the serving engine runs on CPU hosts).  Soft-capped models route to the
+    oracle — same policy as paged_attention.  Returns (B, H, R)."""
     be = _resolve(backend)
     why = _oracle_reason(logit_soft_cap)
     if be == "xla" or why:
         if be != "xla":
             _fallback("mla_paged", why)
-        return ref.mla_paged(q_lat, q_pe, ckv_pages, kpe_pages, block_tables,
-                             seq_lens, sm_scale=sm_scale, window=window,
+        return ref.mla_paged(q_lat, q_pe, pages, block_tables, seq_lens,
+                             sm_scale=sm_scale, window=window,
                              logit_soft_cap=logit_soft_cap)
     b, h, r = q_lat.shape
     pe = q_pe.shape[-1]
-    num_pages, page_size, _ = ckv_pages.shape
+    num_pages, page_size, width = pages.shape
     max_pages = block_tables.shape[1]
     bh = min(block_h, h)
     while h % bh:
@@ -582,32 +629,35 @@ def mla_paged(q_lat, q_pe, ckv_pages, kpe_pages, block_tables, seq_lens, *,
             str(q_lat.dtype), "float32", num_stages, sm_scale, window,
         ),
     )
-    return kern(block_tables, seq_lens, q_lat, q_pe, ckv_pages, kpe_pages)
+    out = kern(block_tables, seq_lens, ref.mla_rows(q_lat, q_pe, width), pages)
+    return out[..., :r]
 
 
-def mla_prefill(q_lat, q_pe, ckv_new, kpe_new, ckv_pages, kpe_pages,
-                block_tables, start_lens, chunk_lens, *, sm_scale=None,
+def mla_prefill(q_lat, q_pe, ckv_new, kpe_new, pages, block_tables,
+                start_lens, chunk_lens, *, sm_scale=None,
                 window: Optional[int] = None,
                 logit_soft_cap: Optional[float] = None,
                 backend: Optional[str] = None, num_stages: int = 2):
-    """MLA chunked prefill over the latent page pools.
+    """MLA chunked prefill over the joint latent page pool.
 
     ``q_lat``/``q_pe`` are the chunk's absorbed queries (B, H, C, ·);
     ``ckv_new``/``kpe_new`` (B, C, ·) the chunk's own latents;
     ``start_lens`` (B,) prior resident tokens (the chunk's write offset)
     and ``chunk_lens`` (B,) the live tokens within the chunk.  Returns
-    ``(out, ckv_pages', kpe_pages')`` — the chunk's latents are written
-    into the pool pages through the block table, dead positions landing in
-    the reserved garbage page 0.  Same contract split as
+    ``(out, pages')`` — the chunk's joint rows ``[latent | rope | 0]`` are
+    written into the pool pages through the block table, dead positions
+    landing in the reserved garbage page 0.  Same contract split as
     :func:`prefill_attention`: the Pallas tile kernel writes pages from
-    inside the kernel and requires chunk-aligned starts; the XLA path is
-    the ref.mla_prefill oracle plus an explicit masked scatter.
+    inside the kernel, walks only the prior pages its live rows need and
+    requires chunk-aligned starts; the XLA path is the ref.mla_prefill
+    oracle plus an explicit masked scatter.
     """
     be = _resolve(backend)
     b, h, chunk, r = q_lat.shape
     pe = q_pe.shape[-1]
-    num_pages, page_size, _ = ckv_pages.shape
+    num_pages, page_size, width = pages.shape
     max_pages = block_tables.shape[1]
+    rows_new = ref.mla_rows(jnp.asarray(ckv_new), jnp.asarray(kpe_new), width)
     why = _oracle_reason(logit_soft_cap, chunk, page_size, max_pages)
     if be != "xla" and why:
         _fallback("mla_prefill", why)
@@ -622,14 +672,13 @@ def mla_prefill(q_lat, q_pe, ckv_new, kpe_new, ckv_pages, kpe_pages,
             ),
         )
         # pack queries chunk-major with their head: row = i*heads + h
-        qp = q_lat.transpose(0, 2, 1, 3).reshape(b, chunk * h, r)
-        qpep = q_pe.transpose(0, 2, 1, 3).reshape(b, chunk * h, pe)
-        ckv_p, kpe_p, out = kern(
-            block_tables, start_lens, chunk_lens, qp, qpep, ckv_new, kpe_new,
-            ckv_pages, kpe_pages,
+        qp = ref.mla_rows(q_lat, q_pe, width).transpose(0, 2, 1, 3)
+        pages, out = kern(
+            block_tables, start_lens, chunk_lens,
+            qp.reshape(b, chunk * h, width), rows_new.astype(q_lat.dtype), pages,
         )
-        out = out.reshape(b, chunk, h, r).transpose(0, 2, 1, 3)
-        return out, ckv_p, kpe_p
+        out = out.reshape(b, chunk, h, width)[..., :r].transpose(0, 2, 1, 3)
+        return out, pages
 
     # ---- XLA path: masked scatter + gather through the table -------------
     pos = start_lens[:, None].astype(jnp.int32) + jnp.arange(chunk, dtype=jnp.int32)
@@ -638,22 +687,19 @@ def mla_prefill(q_lat, q_pe, ckv_new, kpe_new, ckv_pages, kpe_pages,
     valid = jnp.arange(chunk)[None, :] < chunk_lens[:, None]
     phys = jnp.where(valid, phys, 0)  # dead tail -> reserved garbage page
     off = pos % page_size
-    ckv_pages, kpe_pages = jnp.asarray(ckv_pages), jnp.asarray(kpe_pages)
-    pdt = ckv_pages.dtype
-    ckv_p = ckv_pages.at[phys, off].set(jnp.asarray(ckv_new).astype(pdt))
-    kpe_p = kpe_pages.at[phys, off].set(jnp.asarray(kpe_new).astype(pdt))
+    pages = jnp.asarray(pages)
+    pages = pages.at[phys, off].set(rows_new.astype(pages.dtype))
 
+    ctx = pages[block_tables].reshape(b, -1, width)
     s_total = max_pages * page_size
     si = jnp.arange(s_total, dtype=jnp.int32)
     ctx_pos = jnp.where(si[None, :] < start_lens[:, None], si[None, :], -1)
     out = ref.mla_prefill(
-        q_lat, q_pe, ckv_new, kpe_new,
-        ckv_p[block_tables].reshape(b, -1, r),
-        kpe_p[block_tables].reshape(b, -1, pe),
+        q_lat, q_pe, ckv_new, kpe_new, ctx[..., :r], ctx[..., r : r + pe],
         ctx_pos, pos, chunk_lens, sm_scale=sm_scale, window=window,
         logit_soft_cap=logit_soft_cap,
     )
-    return out, ckv_p, kpe_p
+    return out, pages
 
 
 def mla_paged_quant(q_lat, q_pe, ckv_pages, kpe_pages, ckv_scales, kpe_scales,

@@ -186,10 +186,12 @@ def mla_paged_quant(
     logit_soft_cap: Optional[float] = None,
     out_dtype=None,
 ) -> jax.Array:
-    """Quantized paged MLA decode oracle (latent + rope pools both packed)."""
+    """Quantized paged MLA decode oracle (latent + rope pools both packed):
+    dequantize, join the two pools into :func:`mla_rows`, then the fp
+    oracle."""
     ckv = dequantize_rows(ckv_pages, ckv_scales, fmt).astype(q_lat.dtype)
     kpe = dequantize_rows(kpe_pages, kpe_scales, fmt).astype(q_lat.dtype)
-    return mla_paged(q_lat, q_pe, ckv, kpe, block_tables, seq_lens,
+    return mla_paged(q_lat, q_pe, mla_rows(ckv, kpe), block_tables, seq_lens,
                      sm_scale=sm_scale, window=window,
                      logit_soft_cap=logit_soft_cap, out_dtype=out_dtype)
 
@@ -451,11 +453,20 @@ def mla_masked(
     return jnp.einsum("bhs,bsr->bhr", p, c_kv.astype(jnp.float32))
 
 
+def mla_rows(latent: jax.Array, rope: jax.Array, width: Optional[int] = None) -> jax.Array:
+    """Joint MLA rows ``[latent | rope | 0]`` along the last axis, ``width``
+    lanes in all (default: no zero tail) — the layout of the paged MLA
+    kernels' pages and queries (kernels/mla.py)."""
+    width = width or latent.shape[-1] + rope.shape[-1]
+    tail = width - latent.shape[-1] - rope.shape[-1]
+    zeros = jnp.zeros(latent.shape[:-1] + (tail,), latent.dtype)
+    return jnp.concatenate([latent, rope.astype(latent.dtype), zeros], axis=-1)
+
+
 def mla_paged(
     q_lat: jax.Array,  # (B, H, R)
     q_pe: jax.Array,  # (B, H, Dpe)
-    ckv_pages: jax.Array,  # (P, page_size, R) latent page pool
-    kpe_pages: jax.Array,  # (P, page_size, Dpe)
+    pages: jax.Array,  # (P, page_size, W) joint pool [latent | rope | 0]
     block_tables: jax.Array,  # (B, max_pages) int32 physical page ids
     seq_lens: jax.Array,  # (B,) int32 live length per slot
     sm_scale: Optional[float] = None,
@@ -463,17 +474,18 @@ def mla_paged(
     logit_soft_cap: Optional[float] = None,
     out_dtype=None,
 ) -> jax.Array:
-    """Paged MLA decode oracle: gather each slot's latent/rope pages through
-    its block table, then the shared masked latent attention.  Because the
-    gather reconstructs logical token order, outputs are identical to the
-    contiguous strip path — the property the serving equivalence tests pin."""
+    """Paged MLA decode oracle: gather each slot's joint pages through its
+    block table, split the latent and rope lanes, then the shared masked
+    latent attention.  Because the gather reconstructs logical token
+    order, outputs are identical to the contiguous strip path — the
+    property the serving equivalence tests pin."""
     b, h, r = q_lat.shape
+    pe = q_pe.shape[-1]
     if sm_scale is None:
-        sm_scale = 1.0 / np.sqrt(r + q_pe.shape[-1])
-    ckv = ckv_pages[block_tables].reshape(b, -1, r)
-    kpe = kpe_pages[block_tables].reshape(b, -1, kpe_pages.shape[-1])
-    out = mla_masked(q_lat, q_pe, ckv, kpe, seq_lens, sm_scale,
-                     window=window, logit_soft_cap=logit_soft_cap)
+        sm_scale = 1.0 / np.sqrt(r + pe)
+    rows = pages[block_tables].reshape(b, -1, pages.shape[-1])
+    out = mla_masked(q_lat, q_pe, rows[..., :r], rows[..., r : r + pe], seq_lens,
+                     sm_scale, window=window, logit_soft_cap=logit_soft_cap)
     return out.astype(out_dtype or q_lat.dtype)
 
 

@@ -118,6 +118,14 @@ class ModelConfig:
         return self.attention != "none"
 
     @property
+    def kv_head_dim(self) -> int:
+        """Width of one cached KV head's row: ``head_dim``, or MLA's latent
+        rank plus its rope dims (absorbed MLA attends one such head)."""
+        if self.attention == "mla":
+            return self.mla.kv_lora_rank + self.mla.qk_rope_head_dim
+        return self.head_dim
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch run 500k-token decode? (SSM / hybrid / windowed.)"""
         if self.family in ("ssm",):

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.kernels.mla import page_width
 
 from .config import ModelConfig, YarnScaling
 
@@ -506,11 +507,13 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int):
 
 
 def init_mla_paged_cache(cfg: ModelConfig, num_blocks: int, page_size: int):
-    """Latent page pools for one layer: the latent is shared by every query
-    head, so pages carry no head axis — ``(num_blocks, page_size, rank)``
-    plus the rope part.  The per-token footprint is ``rank + rope_dim``
-    instead of ``2 * heads * head_dim``: latent paging keeps MLA's KV
-    compression through the block pool.
+    """Latent page pool for one layer: the latent is shared by every query
+    head, so pages carry no head axis.  Each token is one joint row
+    ``[latent | rope | 0]`` of ``page_width(rank, rope)`` lanes (640 at
+    DeepSeek-V2's widths), the page the paged MLA kernels walk
+    (kernels/mla.py) — about ``rank + rope_dim`` values per token instead
+    of ``2 * heads * head_dim``: latent paging keeps MLA's KV compression
+    through the block pool.
 
     With ``cfg.kv_dtype`` set the latent and rope pools store packed int8
     plus per-row scale pools, same contract as :func:`init_paged_kv_cache`."""
@@ -525,10 +528,8 @@ def init_mla_paged_cache(cfg: ModelConfig, num_blocks: int, page_size: int):
             "ckv_scale_pages": jnp.zeros((num_blocks, page_size, 1), cfg.dtype),
             "kpe_scale_pages": jnp.zeros((num_blocks, page_size, 1), cfg.dtype),
         }
-    return {
-        "ckv_pages": jnp.zeros((num_blocks, page_size, m.kv_lora_rank), cfg.dtype),
-        "kpe_pages": jnp.zeros((num_blocks, page_size, m.qk_rope_head_dim), cfg.dtype),
-    }
+    width = page_width(m.kv_lora_rank, m.qk_rope_head_dim)
+    return {"latent_pages": jnp.zeros((num_blocks, page_size, width), cfg.dtype)}
 
 
 def _mla_absorbed_q(params, q_nope, cfg: ModelConfig):
@@ -608,7 +609,7 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q_nope, q_pe, c_kv, k_pe = _mla_decode_qkv(params, x, cfg, posb[:, None])
-    page_size = cache["ckv_pages"].shape[1]
+    page_size = jax.tree.leaves(cache)[0].shape[1]  # every pool: (pages, page, ·)
     logical = posb // page_size
     offset = posb % page_size
     phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
@@ -634,17 +635,17 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
         return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
                       "ckv_scale_pages": ckv_scales,
                       "kpe_scale_pages": kpe_scales}
-    cdt = cache["ckv_pages"].dtype
-    ckv_pages = cache["ckv_pages"].at[phys, offset].set(c_kv.astype(cdt))
-    kpe_pages = cache["kpe_pages"].at[phys, offset].set(k_pe[:, 0].astype(cdt))
+    pages = cache["latent_pages"]
+    row = ref.mla_rows(c_kv.astype(pages.dtype), k_pe[:, 0], pages.shape[-1])
+    pages = pages.at[phys, offset].set(row)
     with jax.named_scope("mla.attend"):
         out_lat = ops.mla_paged(
-            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages, kpe_pages,
+            q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), pages,
             tables, posb + 1, sm_scale=sm, window=window,
             logit_soft_cap=cfg.logit_soft_cap, backend=backend,
         )
     proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
-    return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages}
+    return proj, {"latent_pages": pages}
 
 
 def _mla_prefill_qkv(params, x, cfg: ModelConfig, posmat):
@@ -698,14 +699,14 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables, lens,
                       "ckv_scale_pages": ckv_scales,
                       "kpe_scale_pages": kpe_scales}
     with jax.named_scope("mla.attend"):
-        out_lat, ckv_pages, kpe_pages = ops.mla_prefill(
+        out_lat, pages = ops.mla_prefill(
             q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
-            cache["ckv_pages"], cache["kpe_pages"], tables, posb,
+            cache["latent_pages"], tables, posb,
             jnp.asarray(lens, jnp.int32), sm_scale=sm, window=window,
             logit_soft_cap=cfg.logit_soft_cap, backend=backend,
         )
     proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
-    return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages}
+    return proj, {"latent_pages": pages}
 
 
 def mla_prefill(params, x, cfg: ModelConfig, cache, pos, lens, window=None):

@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.errors import GuardError
-from repro.kernels.ops import guard_dispatch, paged_walk
+from repro.kernels.ops import guard_dispatch, paged_walk, prefill_walk
 from repro.models import layers as L
 from repro.models import lm
 from repro.models.config import ModelConfig
@@ -989,12 +989,37 @@ class ServingEngine:
         rec.pages_walked = int(sum(
             n * paged_walk(
                 pos + 1, self.pool.page_size, self.max_pages, w,
-                head_dim=cfg.head_dim, kv_dtype=cfg.kv_dtype,
-                attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
-                backend=None if cfg.kernel_backend == "auto" else cfg.kernel_backend,
+                **self._walk_kw(),
             ).sum()
             for w, n in layers.items()
         ))
+
+    def _prefill_walk(self, rec: telemetry.Dispatch, starts, lens) -> None:
+        """Count on ``rec`` the prior KV pages the prefill program's
+        attention walks for slots of ``starts`` prior tokens and ``lens``
+        live chunk tokens, against the pages its grid cells' tables hold —
+        summed over cells, slots and layers, as ``ops.prefill_walk``
+        answers for the call the layer makes."""
+        if self.tables is None:
+            return
+        layers = collections.Counter(lm.static_windows(self.cfg))
+        for w, n in layers.items():
+            walked, table = prefill_walk(
+                starts, lens, self.prefill_chunk, self.pool.page_size,
+                self.max_pages, w, **self._walk_kw(),
+            )
+            rec.prior_pages_walked += n * int(walked.sum())
+            rec.prior_pages_table += n * int(table.sum())
+
+    def _walk_kw(self) -> dict:
+        """The kernel-selecting facts of the attention calls, as the
+        layers pass them to ``ops``."""
+        cfg = self.cfg
+        return dict(
+            head_dim=cfg.kv_head_dim, kv_dtype=cfg.kv_dtype,
+            attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
+            backend=None if cfg.kernel_backend == "auto" else cfg.kernel_backend,
+        )
 
     def _gen_ready(self, s: int) -> bool:
         """Slot ``s`` is in steady-state generation: its next feed is its
@@ -1659,6 +1684,7 @@ class ServingEngine:
                 live_rows=sum(chunk_lens.values()),
             )
             with telemetry.span("drain", rec):
+                self._prefill_walk(rec, self.pos, lens)
                 for s, n in chunk_lens.items():
                     req = self.slot_req[s]
                     self.pos[s] += n

@@ -76,9 +76,13 @@ class Dispatch:
     paged decode program's attention visits ``pages_walked`` KV pages of
     the ``pages_table`` its block tables hold (one KV head's walk, summed
     over ticks, slots and layers; equal where the walk is not bounded by
-    the live length).  A program of a model with MoE layers computes
-    ``expert_rows`` rows of the held experts' FFNs (over layers), of which
-    ``expert_rows_routed`` serve a live token that picked that expert."""
+    the live length).  A prefill program's attention visits
+    ``prior_pages_walked`` prior KV pages of the ``prior_pages_table`` its
+    grid cells' tables hold (one KV head's walk, summed over the chunk's
+    grid cells, slots and layers).  A program of a model with MoE layers
+    computes ``expert_rows`` rows of the held experts' FFNs (over layers),
+    of which ``expert_rows_routed`` serve a live token that picked that
+    expert."""
 
     program: str
     ticks: int
@@ -87,6 +91,8 @@ class Dispatch:
     live_rows: int = 0
     pages_walked: int = 0
     pages_table: int = 0
+    prior_pages_walked: int = 0
+    prior_pages_table: int = 0
     expert_rows: int = 0
     expert_rows_routed: int = 0
     upload: Optional[Span] = None
@@ -148,6 +154,8 @@ def summary(records: List, t0: float, t1: float) -> dict:
       programs (``decode_step``, ``decode_window_*``), per tick they ran.
     * ``prefill_rows``, ``prefill_live_rows``: summed over ``prefill_step``.
     * ``pages_walked``, ``pages_table``: summed over the decode programs.
+    * ``prior_pages_walked``, ``prior_pages_table``: summed over
+      ``prefill_step``.
     * ``expert_rows``, ``expert_rows_routed``: summed over every program;
       ``decode_s``, ``decode_expert_rows_routed``: the decode programs'
       enqueue-to-ready seconds and routed rows.
@@ -190,6 +198,8 @@ def summary(records: List, t0: float, t1: float) -> dict:
         "prefill_live_rows": sum(r.live_rows for r in prefill),
         "pages_walked": sum(r.pages_walked for r in decode),
         "pages_table": sum(r.pages_table for r in decode),
+        "prior_pages_walked": sum(r.prior_pages_walked for r in prefill),
+        "prior_pages_table": sum(r.prior_pages_table for r in prefill),
         "expert_rows": sum(r.expert_rows for r in runs),
         "expert_rows_routed": sum(r.expert_rows_routed for r in runs),
         "decode_s": decode_s,

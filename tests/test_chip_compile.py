@@ -23,6 +23,7 @@ from repro.core import Schedule, analyze, compile as tl_compile, program_fingerp
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_program
 from repro.kernels.matmul import matmul_program
+from repro.kernels.mla import mla_paged_program, mla_prefill_program, page_width
 from repro.kernels.paged_attention import paged_attention_program
 from repro.kernels.prefill_attention import prefill_attention_program
 
@@ -117,17 +118,48 @@ def test_kernel_compiles_for_v5e(name, one_chip, mosaic):
     assert not ops.FALLBACKS, dict(ops.FALLBACKS)
 
 
+def mla_shapes(slots, max_pages, prefill=False):
+    """``ops.mla_paged`` / ``ops.mla_prefill`` arguments at deepseek_v2_lite_16b's
+    widths: 16 heads, latent 512 + rope 64 in joint pages of 640 lanes."""
+    m = get_config("deepseek_v2_lite_16b").mla
+    h, r, pe = 16, m.kv_lora_rank, m.qk_rope_head_dim
+    pages = sds(slots * max_pages + 1, PAGE, page_width(r, pe))
+    table, vec = sds(slots, max_pages, dtype="int32"), sds(slots, dtype="int32")
+    if prefill:
+        return (sds(slots, h, CHUNK, r), sds(slots, h, CHUNK, pe), sds(slots, CHUNK, r),
+                sds(slots, CHUNK, pe), pages, table, vec, vec)
+    return sds(slots, h, r), sds(slots, h, pe), pages, table, vec
+
+
 def test_mla_paged_compiles_at_sixteen_heads(one_chip, mosaic):
     """deepseek_v2_lite_16b's latent decode: 16 heads in one ``block_h``
     window spans the head axis whole, so Mosaic's block rule holds."""
-    m = get_config("deepseek_v2_lite_16b").mla
-    h, r, pe = 16, m.kv_lora_rank, m.qk_rope_head_dim
-    shapes = (sds(SLOTS, h, r), sds(SLOTS, h, pe), sds(NUM_PAGES, PAGE, r),
-              sds(NUM_PAGES, PAGE, pe), sds(SLOTS, MAX_PAGES, dtype="int32"),
-              sds(SLOTS, dtype="int32"))
-    text = compiled_text(lambda *a: ops.mla_paged(*a, backend="pallas"), *shapes,
+    text = compiled_text(lambda *a: ops.mla_paged(*a, backend="pallas"),
+                         *mla_shapes(SLOTS, MAX_PAGES), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["mla_paged", "mla_prefill"])
+def test_bounded_mla_compiles_for_v5e(kernel, one_chip, mosaic):
+    """``PagedMLA`` and ``PrefillMLA`` at the deepseek_v2_lite_16b cell's
+    shape (16 slots x 288 table pages): the joint 640-lane page is a tile
+    Mosaic DMAs by hand, so each (head block, slot) or (chunk page, slot)
+    cell walks its live pages inside the kernel, and nothing falls back."""
+    slots, max_pages = 16, 288
+    text = compiled_text(lambda *a: getattr(ops, kernel)(*a, backend="pallas"),
+                         *mla_shapes(slots, max_pages, kernel == "mla_prefill"),
                          sharding=one_chip)
     assert "tpu_custom_call" in text
+    assert not ops.FALLBACKS, dict(ops.FALLBACKS)
+    m = get_config("deepseek_v2_lite_16b").mla
+    shape = dict(slots=slots, heads=16, dim=m.kv_lora_rank, pe_dim=m.qk_rope_head_dim,
+                 page_size=PAGE, max_pages=max_pages, num_pages=slots * max_pages + 1,
+                 dtype="bfloat16")
+    prog = (mla_prefill_program(chunk=CHUNK, **shape) if kernel == "mla_prefill"
+            else mla_paged_program(**shape))
+    plan = analyze(prog, Schedule())
+    assert plan.grid_plan.walk and plan.grid_plan.kdim is None
+    assert plan.grid == (slots, CHUNK // PAGE if kernel == "mla_prefill" else 1)
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "window"])

@@ -143,6 +143,60 @@ class TestMLA:
         expect = np.asarray(ref.mla(q, qpe, kv, kpe))
         np.testing.assert_allclose(out, expect, atol=2e-3)
 
+    def test_joint_pool_oracle_matches_two_pools(self, rng):
+        """The paged oracles over the joint pool ``[latent | rope | 0]``
+        give what the two separate latent and rope pools gave: decode
+        (``ref.mla_paged``) against the masked latent attention over the
+        two gathered pools, and prefill (``ops.mla_prefill``'s XLA path)
+        against two masked scatters and ``ref.mla_prefill`` over their
+        gathers.  The zero lanes add nothing to a score."""
+        import jax.numpy as jnp
+
+        from repro.kernels.mla import page_width
+
+        slots, heads, r, pe, ps, mp, np_, chunk = 3, 4, 96, 16, 16, 4, 16, 32
+        width = page_width(r, pe)
+        assert width == 128
+        tables = jnp.asarray((rng.permutation(np_ - 1)[: slots * mp] + 1)
+                             .reshape(slots, mp).astype(np.int32))
+        ckv = jnp.asarray(rng.standard_normal((np_, ps, r), dtype=np.float32))
+        kpe = jnp.asarray(rng.standard_normal((np_, ps, pe), dtype=np.float32))
+        pages = ref.mla_rows(ckv, kpe, width)
+        assert not np.asarray(pages[..., r + pe :]).any()
+
+        lens = jnp.asarray([1, 37, 64], jnp.int32)
+        q_lat = jnp.asarray(rng.standard_normal((slots, heads, r), dtype=np.float32))
+        q_pe = jnp.asarray(rng.standard_normal((slots, heads, pe), dtype=np.float32))
+        two = ref.mla_masked(q_lat, q_pe, ckv[tables].reshape(slots, -1, r),
+                             kpe[tables].reshape(slots, -1, pe), lens,
+                             1.0 / np.sqrt(r + pe))
+        np.testing.assert_allclose(
+            np.asarray(ref.mla_paged(q_lat, q_pe, pages, tables, lens)),
+            np.asarray(two), rtol=1e-5, atol=1e-5)
+
+        starts = jnp.asarray([0, 16, 32], jnp.int32)
+        clens = jnp.asarray([20, 32, 7], jnp.int32)
+        ql = jnp.asarray(rng.standard_normal((slots, heads, chunk, r), dtype=np.float32))
+        qp = jnp.asarray(rng.standard_normal((slots, heads, chunk, pe), dtype=np.float32))
+        cn = jnp.asarray(rng.standard_normal((slots, chunk, r), dtype=np.float32))
+        pn = jnp.asarray(rng.standard_normal((slots, chunk, pe), dtype=np.float32))
+        out, new_pages = ops.mla_prefill(ql, qp, cn, pn, pages, tables, starts,
+                                         clens, backend="xla")
+        pos = starts[:, None] + jnp.arange(chunk, dtype=jnp.int32)
+        phys = jnp.take_along_axis(tables, jnp.clip(pos // ps, 0, mp - 1), axis=1)
+        phys = jnp.where(jnp.arange(chunk)[None, :] < clens[:, None], phys, 0)
+        ckv2 = ckv.at[phys, pos % ps].set(cn)
+        kpe2 = kpe.at[phys, pos % ps].set(pn)
+        si = jnp.arange(mp * ps, dtype=jnp.int32)
+        ctx_pos = jnp.where(si[None, :] < starts[:, None], si[None, :], -1)
+        want = ref.mla_prefill(ql, qp, cn, pn, ckv2[tables].reshape(slots, -1, r),
+                               kpe2[tables].reshape(slots, -1, pe), ctx_pos, pos,
+                               clens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(new_pages),
+                                      np.asarray(ref.mla_rows(ckv2, kpe2, width)))
+
     def test_loc_budget(self):
         """Paper headline: MLA in ~70 lines of Python."""
         prog = mla_program(1, 16, 1, 128, 64, 16, 32, 16)

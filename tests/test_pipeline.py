@@ -298,6 +298,66 @@ def test_paged_walk_matches_oracle(name, target, rng):
                                rtol=1e-4, atol=2e-3)
 
 
+_MLA_FP = sorted(n for n in _CASES if n.startswith(("mla_paged", "mla_prefill"))
+                 and "quant" not in n)
+
+
+def _mla_check(name, args, got):
+    """A paged MLA case's kernel outputs against the XLA oracle over the
+    joint pool: the latent lanes of every live query row (the oracle's
+    rows with no key are nan, the kernel's zeros), and for prefill each
+    slot's live rows in its pages and the pages no table holds."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+    from repro.kernels.mla import PARITY_CASES
+
+    cfg = dict(PARITY_CASES)[name]
+    r, pe, w = cfg["dim"], cfg["pe_dim"], cfg.get("window")
+    if name.startswith("mla_paged"):
+        tables, lens, q, pages = (jnp.asarray(a) for a in args)
+        want = np.asarray(ref.mla_paged(q[..., :r], q[..., r : r + pe], pages,
+                                        tables, lens, window=w))
+        live = np.asarray(lens) > 0
+        got = np.asarray(got)
+        np.testing.assert_allclose(got[live, :, :r], want[live], rtol=1e-4, atol=2e-3)
+        assert not got[~live].any()  # an empty slot reads zeros
+        return
+    tables, starts, lens, q, kv, pages = (jnp.asarray(a) for a in args)
+    slots, chunk = kv.shape[:2]
+    q = q.reshape(slots, chunk, cfg["heads"], -1).transpose(0, 2, 1, 3)
+    want, want_pages = ops.mla_prefill(
+        q[..., :r], q[..., r : r + pe], kv[..., :r], kv[..., r : r + pe], pages,
+        tables, starts, lens, window=w, backend="xla")
+    got_pages, out = (np.asarray(x) for x in got)
+    out = out.reshape(slots, chunk, cfg["heads"], -1)[..., :r]
+    want = np.asarray(want).transpose(0, 2, 1, 3)
+    live = np.arange(chunk)[None, :] < np.asarray(lens)[:, None]
+    np.testing.assert_allclose(out[live], want[live], rtol=1e-4, atol=2e-3)
+    want_pages = np.asarray(want_pages)
+    for b, n in enumerate(np.asarray(starts + lens)):  # every slot's live rows
+        rows = lambda p: p[np.asarray(tables[b])].reshape(-1, p.shape[-1])[:n]
+        np.testing.assert_allclose(rows(got_pages), rows(want_pages), atol=1e-6)
+    spare = np.setdiff1d(np.arange(1, pages.shape[0]), np.asarray(tables))
+    np.testing.assert_array_equal(got_pages[spare], np.asarray(pages)[spare])
+
+
+@pytest.mark.parametrize("target", ["pallas", "reference"])
+@pytest.mark.parametrize("name", _MLA_FP)
+def test_mla_walk_matches_oracle(name, target, rng):
+    """``PagedMLA`` and ``PrefillMLA`` on the joint page (128 lanes here):
+    the Pallas lowering walks their bounded loops inside the kernel, and
+    both backends match the oracle, which masks the whole table — an empty
+    slot and a dead chunk page walk nothing, a window's walk starts past
+    page 0."""
+    prog = _CASES[name]
+    assert analyze(prog, Schedule()).grid_plan.walk
+    sched = Schedule(interpret=True) if target == "pallas" else None
+    kern = tl_compile(prog, sched, target=target)
+    args = parity_inputs(name, prog, rng)
+    _mla_check(name, args, kern(*args))
+
+
 @pytest.mark.parametrize("window", [None, 20])
 def test_reference_walks_only_live_pages(window, monkeypatch):
     """The reference interpreter loads exactly the pages holding each

@@ -999,15 +999,18 @@ def test_mla_paged_kernel_matches_oracle(rng):
     for name, cfg in PARITY_CASES:
         if not name.startswith("mla_paged") or "quant" in name:
             continue
+        r, pe = cfg["dim"], cfg["pe_dim"]
         prog = mla_paged_program(**cfg)
         kern = tl_compile(prog, Schedule(interpret=True), target="pallas")
-        tbl, lens, q, qpe, ckv, kpe = parity_inputs(name, prog, rng)
-        out = np.asarray(kern(tbl, lens, q, qpe, ckv, kpe))
+        tbl, lens, q, pages = parity_inputs(name, prog, rng)
+        out = np.asarray(kern(tbl, lens, q, pages))[..., :r]
+        live = lens > 0  # the oracle's softmax over no key is nan
         oracle = np.asarray(
-            ref.mla_paged(q, qpe, ckv, kpe, tbl, lens,
+            ref.mla_paged(q[..., :r], q[..., r : r + pe], pages, tbl, lens,
                           window=cfg.get("window"))
         )
-        np.testing.assert_allclose(out, oracle, rtol=1e-4, atol=2e-3)
+        np.testing.assert_allclose(out[live], oracle[live], rtol=1e-4, atol=2e-3)
+        assert not out[~live].any()
 
 
 def test_mla_soft_cap_routes_to_oracle(rng):
@@ -1017,14 +1020,16 @@ def test_mla_soft_cap_routes_to_oracle(rng):
     from repro.kernels.mla import PARITY_CASES, parity_inputs, mla_paged_program
 
     cfg = dict(PARITY_CASES)["mla_paged"]
+    r, pe = cfg["dim"], cfg["pe_dim"]
     prog = mla_paged_program(**cfg)
-    tbl, lens, q, qpe, ckv, kpe = parity_inputs("mla_paged", prog, rng)
-    capped = ops.mla_paged(q, qpe, ckv, kpe, tbl, lens,
+    tbl, lens, q, pages = parity_inputs("mla_paged", prog, rng)
+    qlat, qpe = q[..., :r], q[..., r : r + pe]
+    capped = ops.mla_paged(qlat, qpe, pages, tbl, lens,
                            logit_soft_cap=1.0, backend="pallas")
-    oracle = ref.mla_paged(q, qpe, ckv, kpe, tbl, lens, logit_soft_cap=1.0)
+    oracle = ref.mla_paged(qlat, qpe, pages, tbl, lens, logit_soft_cap=1.0)
     np.testing.assert_allclose(np.asarray(capped), np.asarray(oracle),
                                rtol=1e-5, atol=1e-6)
-    uncapped = ref.mla_paged(q, qpe, ckv, kpe, tbl, lens)
+    uncapped = ref.mla_paged(qlat, qpe, pages, tbl, lens)
     assert not np.allclose(np.asarray(capped), np.asarray(uncapped), atol=1e-4)
 
 

@@ -254,10 +254,89 @@ def test_paged_walk_counts_the_live_pages():
     full = [3] * len(lens)
     assert walk(backend="xla").tolist() == full  # the oracle masks the table
     assert walk(logit_soft_cap=30.0).tolist() == full  # routed to the oracle
-    assert walk(attention="mla").tolist() == full  # static extent
+    # MLA's one KV head is a joint latent + rope row, 128 lanes here
+    assert walk(attention="mla").tolist() == [0, 1, 1, 2, 3, 3]
     # tiles the lowering cannot DMA by hand: the static grid over the table
     assert walk(kv_dtype="int8").tolist() == full
     assert walk(head_dim=64).tolist() == full
+
+
+def test_mla_paged_walk_counts_live_pages_for_fp_only():
+    """``ops.paged_walk`` for MLA at DeepSeek-V2-Lite's one KV head (latent
+    512 + rope 64, a joint page of 640 lanes): the fp kernel walks each
+    slot's live pages in the kernel; the quantized kernel keeps its packed
+    pools, its scale columns and its static grid over the whole table."""
+    from repro.kernels import ops
+
+    lens = [0, 1, 16, 17, 40, 48]
+    walk = functools.partial(ops.paged_walk, lens, 16, 3, head_dim=576,
+                             attention="mla", backend="pallas")
+    assert walk().tolist() == [0, 1, 1, 2, 3, 3]
+    assert walk(window=20).tolist() == [0, 1, 1, 2, 2, 2]
+    assert walk(kv_dtype="int8").tolist() == [3] * len(lens)
+
+
+def test_prefill_walk_counts_the_prior_pages():
+    """``ops.prefill_walk``: per slot, the prior pages the chunk's grid
+    cells walk and the pages their tables hold.  The fp MLA kernel walks
+    ``[0, start // page)`` in each cell holding a live row, from the first
+    page the window reaches from the cell's lowest query; a cell with no
+    live row walks nothing.  ``PrefillAttn``, the quantized MLA kernel and
+    the oracle walk the whole table in every cell."""
+    from repro.kernels import ops
+
+    starts, lens = [32, 0, 32, 16, 32], [0, 20, 20, 32, 9]  # chunk 32: 2 cells
+    walk = functools.partial(ops.prefill_walk, starts, lens, 32, 16, 4,
+                             head_dim=576, attention="mla", backend="pallas")
+    walked, table = walk()
+    assert walked.tolist() == [0, 0, 4, 2, 2] and table.tolist() == [8] * 5
+    # window 20: the second cell of the 32-token starts reaches back from
+    # position 48 to 29, so from page 1
+    assert walk(window=20)[0].tolist() == [0, 0, 3, 2, 2]
+    for whole in (walk(kv_dtype="int8"), walk(backend="xla"),
+                  walk(attention="gqa", head_dim=128), walk(logit_soft_cap=30.0)):
+        assert whole[0].tolist() == whole[1].tolist() == [8] * 5
+
+
+@pytest.mark.parametrize("bounded", [True, False])
+def test_prefill_records_count_their_prior_walk(bounded, monkeypatch, rng):
+    """Each prefill program's record counts the prior pages its attention
+    walks against the pages its grid cells' tables hold, over cells, slots
+    and layers; decode programs count none.  Bounded, a slot's cells walk
+    its prior pages while they hold live rows; unbounded (the XLA oracle
+    the CPU engine runs, and ``PrefillAttn``), the whole table."""
+    from repro.kernels import ops
+
+    eng = _engine(slots=2)
+    if bounded:  # as ops answers for the fp MLA kernel's in-kernel walk
+        monkeypatch.setattr(ops, "_walks_live_pages", lambda *a: True)
+    calls = []
+    run = eng._run_program
+
+    def spy(fn, inputs, *a, **kw):
+        starts, lens = np.array(inputs[1]), np.array(inputs[2])
+        out = run(fn, inputs, *a, **kw)
+        calls.append((out[2], starts, lens))
+        return out
+
+    monkeypatch.setattr(eng, "_run_program", spy)
+    _submit(eng, rng, 3, lo=20, hi=60)
+    eng.run()
+    layers, ps, width = eng.cfg.num_layers, eng.scfg.page_size, eng.max_pages
+    cells = eng.prefill_chunk // ps
+    prefill = [c for c in calls if c[0].program == "prefill_step"]
+    # some chunk resumes a prompt past its first page
+    assert any(n and st for _, starts, lens in prefill for st, n in zip(starts, lens))
+    for rec, starts, lens in prefill:
+        assert rec.prior_pages_table == layers * eng.scfg.slots * cells * width
+        if not bounded:
+            assert rec.prior_pages_walked == rec.prior_pages_table
+            continue
+        want = sum(starts[s] // ps for s in range(len(lens))
+                   for c in range(cells) if c * ps < lens[s])
+        assert rec.prior_pages_walked == layers * want
+    assert all(c[0].prior_pages_table == c[0].prior_pages_walked == 0
+               for c in calls if c[0].program != "prefill_step")
 
 
 @pytest.mark.parametrize("bounded", [True, False])
