@@ -92,7 +92,7 @@ def _self_attn(p, x, cfg, causal, kv_len=None, cache=None, pos=None):
     out = ops.attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
         causal=causal,
-        backend=cfg.kernel_backend if cfg.kernel_backend != "auto" else None,
+        backend=L.kernel_backend(cfg),
     )
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
     return jnp.einsum("bse,ed->bsd", out.astype(x.dtype), p["wo"]), None

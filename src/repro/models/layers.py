@@ -25,6 +25,20 @@ from .config import ModelConfig, YarnScaling
 Params = Dict[str, Any]
 
 
+def kernel_backend(cfg: ModelConfig) -> Optional[str]:
+    """The ``backend`` every kernel call below passes to ``ops`` (None:
+    ``ops`` picks per call)."""
+    return None if cfg.kernel_backend == "auto" else cfg.kernel_backend
+
+
+def paged_kernel_facts(cfg: ModelConfig) -> Dict[str, Any]:
+    """What picks the kernel of the paged attention calls below, as
+    ``ops.paged_walk`` / ``ops.prefill_walk`` take it."""
+    return dict(head_dim=cfg.kv_head_dim, kv_dtype=cfg.kv_dtype,
+                attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
+                backend=kernel_backend(cfg))
+
+
 # ---------------------------------------------------------------------------
 # Sharding hints: step builders install activation constraints that apply
 # while their step function traces (GSPMD needs interior hints when the
@@ -195,7 +209,7 @@ def attention_full(params, x, cfg: ModelConfig, positions, window=None,
     vt = _hint("attn_kv", v.transpose(0, 2, 1, 3))
     out = ops.attention(
         qt, kt, vt, causal=True, window=window,
-        backend=cfg.kernel_backend if cfg.kernel_backend != "auto" else None,
+        backend=kernel_backend(cfg),
         logit_soft_cap=cfg.logit_soft_cap,
     )
     out = _hint("attn_q", out)
@@ -268,7 +282,6 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     logical = posb // page_size
     offset = posb % page_size
     phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
-    backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
         # Quantize the appended row per (head, slot) and scatter packed bytes
         # plus the per-token scale; attention dequantizes inline at gather.
@@ -282,7 +295,7 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
         out = ops.paged_attention_quant(
             q[:, 0], knew, vnew, ksnew, vsnew, tables, posb + 1,
             fmt=cfg.kv_dtype, window=window,
-            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+            logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
         )
         out = out.reshape(b, 1, h * hd)
         proj = jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["wo"])
@@ -298,7 +311,7 @@ def attention_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     )
     out = ops.paged_attention(
         q[:, 0], knew, vnew, tables, posb + 1, window=window,
-        logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+        logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
     )
     out = out.reshape(b, 1, h * hd)
     proj = jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["wo"])
@@ -323,14 +336,14 @@ def attention_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     posmat = posb[:, None] + jnp.arange(c, dtype=jnp.int32)
     q = apply_rope(q, posmat, cfg.rope_theta, rope_fraction)
     k = apply_rope(k, posmat, cfg.rope_theta, rope_fraction)
-    backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
         out, kp, vp, ksp, vsp = ops.prefill_attention_quant(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), cache["k_pages"], cache["v_pages"],
             cache["k_scale_pages"], cache["v_scale_pages"],
             tables, posb, jnp.asarray(lens, jnp.int32), fmt=cfg.kv_dtype,
-            window=window, logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+            window=window, logit_soft_cap=cfg.logit_soft_cap,
+            backend=kernel_backend(cfg),
         )
         out = out.transpose(0, 2, 1, 3).reshape(b, c, h * hd)
         proj = jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["wo"])
@@ -340,7 +353,7 @@ def attention_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables,
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), cache["k_pages"], cache["v_pages"],
         tables, posb, jnp.asarray(lens, jnp.int32), window=window,
-        logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+        logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
     )
     out = out.transpose(0, 2, 1, 3).reshape(b, c, h * hd)
     proj = jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["wo"])
@@ -491,7 +504,7 @@ def mla_full(params, x, cfg: ModelConfig, positions):
     with jax.named_scope("mla.attend"):
         out = ops.attention(
             qfull, kfull, vt, causal=True, sm_scale=mla_softmax_scale(cfg),
-            backend=cfg.kernel_backend if cfg.kernel_backend != "auto" else None,
+            backend=kernel_backend(cfg),
         )
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * m.v_head_dim)
     return jnp.einsum("bse,ed->bsd", out.astype(x.dtype), params["w_o"])
@@ -615,7 +628,6 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
     phys = jnp.take_along_axis(tables, logical[:, None], axis=1)[:, 0]
     q_lat = _mla_absorbed_q(params, q_nope, cfg)
     sm = mla_softmax_scale(cfg)
-    backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
         sdt = cache["ckv_scale_pages"].dtype
         cq, cs = ref.quantize_rows(c_kv, cfg.kv_dtype)
@@ -629,7 +641,7 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
                 q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), ckv_pages,
                 kpe_pages, ckv_scales, kpe_scales, tables, posb + 1,
                 fmt=cfg.kv_dtype, sm_scale=sm, window=window,
-                logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+                logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
             )
         proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
         return proj, {"ckv_pages": ckv_pages, "kpe_pages": kpe_pages,
@@ -642,7 +654,7 @@ def mla_decode_paged(params, x, cfg: ModelConfig, cache, pos, tables,
         out_lat = ops.mla_paged(
             q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), pages,
             tables, posb + 1, sm_scale=sm, window=window,
-            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+            logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
         )
     proj = _mla_out_proj(params, out_lat, x.dtype, cfg)[:, None]
     return proj, {"latent_pages": pages}
@@ -681,7 +693,6 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables, lens,
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     posmat = posb[:, None] + jnp.arange(c, dtype=jnp.int32)
     q_lat, q_pe, c_kv, k_pe, sm = _mla_prefill_qkv(params, x, cfg, posmat)
-    backend = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
     if cfg.kv_dtype is not None:
         with jax.named_scope("mla.attend"):
             out_lat, ckv_pages, kpe_pages, ckv_scales, kpe_scales = (
@@ -691,7 +702,7 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables, lens,
                     cache["ckv_scale_pages"], cache["kpe_scale_pages"],
                     tables, posb, jnp.asarray(lens, jnp.int32), fmt=cfg.kv_dtype,
                     sm_scale=sm, window=window,
-                    logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+                    logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
                 )
             )
         proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
@@ -703,7 +714,7 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, cache, pos, tables, lens,
             q_lat.astype(cfg.dtype), q_pe.astype(cfg.dtype), c_kv, k_pe,
             cache["latent_pages"], tables, posb,
             jnp.asarray(lens, jnp.int32), sm_scale=sm, window=window,
-            logit_soft_cap=cfg.logit_soft_cap, backend=backend,
+            logit_soft_cap=cfg.logit_soft_cap, backend=kernel_backend(cfg),
         )
     proj = _mla_out_proj(params, out_lat.transpose(0, 2, 1, 3), x.dtype, cfg)
     return proj, {"latent_pages": pages}
@@ -944,7 +955,7 @@ def mamba2_full(params: Params, x, cfg: ModelConfig):
 
 def _ssd_batched(c, bm, x, dt, a_log, chunk, cfg: ModelConfig):
     """SSD with per-batch a_log (heads folded into batch)."""
-    be = cfg.kernel_backend if cfg.kernel_backend != "auto" else None
+    be = kernel_backend(cfg)
     bsz, s, n = c.shape
     p = x.shape[-1]
     nc = s // chunk

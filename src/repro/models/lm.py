@@ -12,6 +12,7 @@ All functions are pure; params/caches are pytrees of jnp arrays.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
@@ -19,6 +20,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels import ops
 
 from . import layers as L
 from .config import ModelConfig
@@ -464,6 +467,40 @@ def page_leaf_shapes(cache: Cache) -> list:
     jax.tree_util.tree_map_with_path(visit, cache.prefix)
     jax.tree_util.tree_map_with_path(visit, cache.rest)
     return out
+
+
+def pages_walked(cfg: ModelConfig, seq_lens, page_size: int,
+                 max_pages: int) -> Tuple[int, int]:
+    """``(walked, table)`` for a paged decode step whose slots attend
+    ``seq_lens`` tokens (any shape): the KV pages one KV head's attention
+    walks and those its block tables hold, over slots and layers, as
+    ``ops.paged_walk`` answers for each layer's call."""
+    lens, windows = np.asarray(seq_lens), static_windows(cfg)
+    kw = L.paged_kernel_facts(cfg)
+    walked = sum(n * int(ops.paged_walk(lens, page_size, max_pages, w, **kw).sum())
+                 for w, n in collections.Counter(windows).items())
+    return walked, lens.size * max_pages * len(windows)
+
+
+def prior_pages_walked(cfg: ModelConfig, starts, lens, chunk: int,
+                       page_size: int, max_pages: int) -> Tuple[int, int]:
+    """``(walked, table)`` for a paged prefill step whose slots hold
+    ``starts`` prior tokens and ``lens`` live tokens of ``chunk``: the
+    prior KV pages one KV head's attention walks and those its grid cells'
+    tables hold, over cells, slots and layers, as ``ops.prefill_walk``
+    answers for each layer's call."""
+    kw, walked, table = L.paged_kernel_facts(cfg), 0, 0
+    for w, n in collections.Counter(static_windows(cfg)).items():
+        wk, tb = ops.prefill_walk(starts, lens, chunk, page_size, max_pages, w, **kw)
+        walked, table = walked + n * int(wk.sum()), table + n * int(tb.sum())
+    return walked, table
+
+
+def expert_rows(cfg: ModelConfig, token_rows: int) -> int:
+    """Rows of the held experts' FFNs a step computes for ``token_rows``
+    token rows over its MoE layers; 0 for a model with none."""
+    n = L.moe_layers(cfg)
+    return n * L.moe_rows(cfg, token_rows) if n else 0
 
 
 def _per_slot(mask, tree_a, tree_b):

@@ -103,8 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.errors import GuardError
-from repro.kernels.ops import guard_dispatch, paged_walk, prefill_walk
-from repro.models import layers as L
+from repro.kernels.ops import guard_dispatch
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -161,6 +160,21 @@ def _cached_fn(key, build):
     return fn
 
 
+def _sample_tail(logits, poison, key, temperature, routed, live_of):
+    """The tail the per-tick programs share: overwrite the ``poison`` rows
+    with NaN, flag as ``bad`` the rows whose logits hold no finite value,
+    sample, and append the held-expert picks of ``routed`` where
+    ``live_of()`` holds (``routed`` is None for a model with no MoE
+    layers).  ``live_of`` builds its mask only after sampling, where the
+    programs have always traced it.  Returns ``(tokens, bad, key)``."""
+    logits = jnp.where(poison[:, None], jnp.nan, logits)
+    bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
+    tok, key = sample_step(logits, key, temperature=temperature)
+    if routed is not None:
+        tok = _with_count(tok, jnp.sum(jnp.where(live_of(), routed, 0)))
+    return tok, bad, key
+
+
 def _decode_step_fn(cfg: ModelConfig, temperature: float):
     """Fused decode tick: model step + sampling in one jit'd program.
     Returns ``(tokens, bad, cache, key)`` — logits stay on device.
@@ -173,19 +187,17 @@ def _decode_step_fn(cfg: ModelConfig, temperature: float):
 
     def build():
         snap = copy.deepcopy(cfg)
-        routes = L.moe_layers(snap) > 0
+        routes = lm.expert_rows(snap, 1) > 0
 
         def decode_step(p, c, tok, pos, key, live, poison):
+            routed = None
             if routes:
                 logits, c, routed = lm.decode_step(p, snap, c, tok, pos, live=live,
                                                    count_routed=True)
             else:
                 logits, c = lm.decode_step(p, snap, c, tok, pos, live=live)
-            logits = jnp.where(poison[:, None], jnp.nan, logits)
-            bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
-            tok, key = sample_step(logits, key, temperature=temperature)
-            if routes:
-                tok = _with_count(tok, jnp.sum(jnp.where(live, routed, 0)))
+            tok, bad, key = _sample_tail(logits, poison, key, temperature,
+                                         routed, lambda: live)
             return tok, bad, c, key
 
         return jax.jit(decode_step, donate_argnums=(1,))
@@ -203,20 +215,18 @@ def _prefill_step_fn(cfg: ModelConfig, temperature: float):
 
     def build():
         snap = copy.deepcopy(cfg)
-        routes = L.moe_layers(snap) > 0
+        routes = lm.expert_rows(snap, 1) > 0
 
         def prefill_step(p, c, toks, pos, lens, key, poison):
+            routed = None
             if routes:
                 logits, c, routed = lm.prefill_step(p, snap, c, toks, pos, lens,
                                                     count_routed=True)
             else:
                 logits, c = lm.prefill_step(p, snap, c, toks, pos, lens)
-            logits = jnp.where(poison[:, None], jnp.nan, logits)
-            bad = ~jnp.any(jnp.isfinite(logits), axis=-1)
-            tok, key = sample_step(logits, key, temperature=temperature)
-            if routes:
-                chunk_live = jnp.arange(toks.shape[1]) < lens[:, None]
-                tok = _with_count(tok, jnp.sum(jnp.where(chunk_live, routed, 0)))
+            tok, bad, key = _sample_tail(
+                logits, poison, key, temperature, routed,
+                lambda: jnp.arange(toks.shape[1]) < lens[:, None])
             return tok, bad, c, key
 
         return jax.jit(prefill_step, donate_argnums=(1,))
@@ -528,7 +538,9 @@ class ServingEngine:
             # inside the jit-cache keys) — the engine just forwards it
             cfg = dataclasses.replace(cfg, kv_dtype=serve_cfg.kv_dtype)
         self.cfg = cfg
-        self._moe_layers = L.moe_layers(cfg)
+        # a model with MoE layers appends its expert picks to each
+        # program's first output (_with_count)
+        self._routes = lm.expert_rows(cfg, 1) > 0
         self.params = params
         self.scfg = serve_cfg
         b = serve_cfg.slots
@@ -967,59 +979,21 @@ class ServingEngine:
             jax.block_until_ready(out)
         with telemetry.span("readback", rec):
             host = [np.asarray(x) for x in out[:n_out]]
-        if self._moe_layers:
+        if self._routes:
             rec.expert_rows_routed = int(host[0][-1].reshape(-1)[0])
             host[0] = host[0][:-1]
-            rec.expert_rows = self._moe_layers * L.moe_rows(self.cfg, rows or host[0].size)
+            rec.expert_rows = lm.expert_rows(self.cfg, rows or host[0].size)
         telemetry.LOG.append(rec)
         return host, out[n_out:], rec
 
     def _page_walk(self, rec: telemetry.Dispatch, pos) -> None:
-        """Count on ``rec`` the KV pages the decode program's attention
-        walks, for positions ``pos`` (ticks x slots; a slot attends its
-        ``pos + 1`` tokens), against the pages its block tables hold —
-        summed over ticks, slots and layers (every layer of a paged model
-        attends; ``telemetry.Dispatch``).  What is walked is the kernel
-        layer's answer (``ops.paged_walk``), asked as the layer calls it."""
-        if self.tables is None:
-            return
-        cfg, pos = self.cfg, np.asarray(pos)
-        layers = collections.Counter(lm.static_windows(cfg))  # window -> layers
-        rec.pages_table = pos.size * self.max_pages * sum(layers.values())
-        rec.pages_walked = int(sum(
-            n * paged_walk(
-                pos + 1, self.pool.page_size, self.max_pages, w,
-                **self._walk_kw(),
-            ).sum()
-            for w, n in layers.items()
-        ))
-
-    def _prefill_walk(self, rec: telemetry.Dispatch, starts, lens) -> None:
-        """Count on ``rec`` the prior KV pages the prefill program's
-        attention walks for slots of ``starts`` prior tokens and ``lens``
-        live chunk tokens, against the pages its grid cells' tables hold —
-        summed over cells, slots and layers, as ``ops.prefill_walk``
-        answers for the call the layer makes."""
-        if self.tables is None:
-            return
-        layers = collections.Counter(lm.static_windows(self.cfg))
-        for w, n in layers.items():
-            walked, table = prefill_walk(
-                starts, lens, self.prefill_chunk, self.pool.page_size,
-                self.max_pages, w, **self._walk_kw(),
-            )
-            rec.prior_pages_walked += n * int(walked.sum())
-            rec.prior_pages_table += n * int(table.sum())
-
-    def _walk_kw(self) -> dict:
-        """The kernel-selecting facts of the attention calls, as the
-        layers pass them to ``ops``."""
-        cfg = self.cfg
-        return dict(
-            head_dim=cfg.kv_head_dim, kv_dtype=cfg.kv_dtype,
-            attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
-            backend=None if cfg.kernel_backend == "auto" else cfg.kernel_backend,
-        )
+        """Count on ``rec`` the KV pages a paged decode program walks for
+        positions ``pos`` (ticks x slots; a slot attends its ``pos + 1``
+        tokens), as the model answers for its calls (``lm.pages_walked``)."""
+        if self.tables is not None:
+            rec.pages_walked, rec.pages_table = lm.pages_walked(
+                self.cfg, np.asarray(pos) + 1, self.pool.page_size,
+                self.max_pages)
 
     def _gen_ready(self, s: int) -> bool:
         """Slot ``s`` is in steady-state generation: its next feed is its
@@ -1147,9 +1121,7 @@ class ServingEngine:
             # fresh pages), give back the grow-ahead, and fall back — the
             # per-tick path's COW failure preempts
             self._apply_cow(pairs)
-            for s in active:
-                if self.tables.trim(s, int(self.pos[s]) + 1):
-                    self._tables_dirty = True
+            self._trim_to_pos(active)
             return False
         self._apply_cow(pairs)
         work = [(s, spans[s]) for s in active]
@@ -1157,12 +1129,33 @@ class ServingEngine:
             # a guard violation FAILed the blamed slot(s): give back the
             # survivors' grow-ahead and fall back, where the next path's
             # own guard re-checks the trimmed dispatch
-            for s in active:
-                if self.slot_req[s] is not None:
-                    if self.tables.trim(s, int(self.pos[s]) + 1):
-                        self._tables_dirty = True
+            self._trim_to_pos(active)
             return False
         return True
+
+    def _trim_to_pos(self, slots: List[int]) -> None:
+        """Give back the pages of each occupied slot in ``slots`` past its
+        next write: unused grow-ahead, and rejected draft tails."""
+        for s in slots:
+            if (self.slot_req[s] is not None
+                    and self.tables.trim(s, int(self.pos[s]) + 1)):
+                self._tables_dirty = True
+
+    def _feeds(self, slots: List[int]):
+        """``(feed, live, remaining)`` of a decode program over ``slots``:
+        each slot's token at its cursor in prompt + output, the mask of
+        ``slots``, and each slot's token allowance."""
+        b = self.scfg.slots
+        feed = np.zeros((b,), np.int32)
+        live = np.zeros((b,), bool)
+        rem = np.zeros((b,), np.int32)
+        for s in slots:
+            req = self.slot_req[s]
+            cur, n = req._cursor, len(req.prompt)  # type: ignore[attr-defined]
+            feed[s] = req.prompt[cur] if cur < n else req.output[cur - n]
+            live[s] = True
+            rem[s] = (req.max_new_tokens or self.scfg.max_new_tokens) - len(req.output)
+        return feed, live, rem
 
     def _step_window(self, active: List[int]) -> Optional[int]:
         """Up to ``sync_every`` decode ticks in one ``lax.scan`` dispatch.
@@ -1172,16 +1165,7 @@ class ServingEngine:
         the paged pool cannot cover the worst-case window (caller falls
         back to a per-tick step)."""
         with telemetry.span("schedule"):
-            b = self.scfg.slots
-            feed = np.zeros((b,), np.int32)
-            live = np.zeros((b,), bool)
-            rem = np.zeros((b,), np.int32)
-            for s in active:
-                req = self.slot_req[s]
-                feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
-                live[s] = True
-                limit = req.max_new_tokens or self.scfg.max_new_tokens
-                rem[s] = limit - len(req.output)
+            feed, live, rem = self._feeds(active)
             # clamp the window to the slots' host-known tick spans — token
             # allowance AND max_len headroom — by halving (not to the exact
             # span: every distinct length is its own scan trace, so lengths
@@ -1230,10 +1214,7 @@ class ServingEngine:
             if self.tables is not None:
                 # return unused grow-ahead pages so boundary-time admission /
                 # preemption sees the same pool a per-tick engine would
-                for s in active:
-                    if self.slot_req[s] is not None:
-                        if self.tables.trim(s, int(self.pos[s]) + 1):
-                            self._tables_dirty = True
+                self._trim_to_pos(active)
         return len(active)
 
     # -- speculative draft-verify window --------------------------------
@@ -1254,16 +1235,7 @@ class ServingEngine:
             scfg = self.scfg
             k = scfg.draft_len
             c = k + 1
-            b = scfg.slots
-            feed = np.zeros((b,), np.int32)
-            live = np.zeros((b,), bool)
-            rem = np.zeros((b,), np.int32)
-            for s in active:
-                req = self.slot_req[s]
-                feed[s] = (req.prompt + req.output)[req._cursor]  # type: ignore[attr-defined]
-                live[s] = True
-                limit = req.max_new_tokens or scfg.max_new_tokens
-                rem[s] = limit - len(req.output)
+            feed, live, rem = self._feeds(active)
 
             # a slot's worst-case write span over n rounds: every verify chunk
             # lands c positions from the current pos, and a live round commits
@@ -1294,7 +1266,7 @@ class ServingEngine:
             if not self._prepare_window(active, spans):
                 return None
 
-            hist = np.zeros((b, scfg.max_len), np.int32)
+            hist = np.zeros((scfg.slots, scfg.max_len), np.int32)
             for s in active:
                 req = self.slot_req[s]
                 toks = req.prompt + req.output
@@ -1328,10 +1300,7 @@ class ServingEngine:
                     if req is None:
                         continue
                     if rbad[s]:
-                        self.poisoned_rows += 1
-                        self._terminate(
-                            req, FAILED, slot=s,
-                            error="poisoned verify logits (no finite value)")
+                        self._fail_poisoned(s, req, "verify logits")
                         continue
                     if not row[s].any():
                         continue
@@ -1353,10 +1322,7 @@ class ServingEngine:
             if self.tables is not None:
                 # rejected draft tails sit in pages past pos under the
                 # grow-ahead grant; trim reclaims them with the unused grant
-                for s in active:
-                    if self.slot_req[s] is not None:
-                        if self.tables.trim(s, int(self.pos[s]) + 1):
-                            self._tables_dirty = True
+                self._trim_to_pos(active)
         return len(active)
 
     # -- prefix-cache bookkeeping ---------------------------------------
@@ -1421,29 +1387,6 @@ class ServingEngine:
             if pair:
                 pairs.append(pair)
         return pairs
-
-    def _cow_or_preempt(self, work: List[Tuple[int, int]],
-                        ) -> Tuple[List[int], List[Tuple[int, int]]]:
-        """Run the COW gate for each ``(slot, last_pos)`` about to be
-        dispatched.  A slot whose copy cannot be satisfied even after
-        eviction and victim preemption is preempted itself and dropped
-        from the dispatch — its partially-repointed pages roll back with
-        its table, so the surviving slots' pairs stay valid.  Returns
-        (surviving slots, device copy pairs)."""
-        dispatch = frozenset(s for s, _ in work)
-        survivors: List[int] = []
-        pairs: List[Tuple[int, int]] = []
-        for s, last in work:
-            if self.slot_req[s] is None:
-                continue  # became a victim earlier in this loop
-            try:
-                local = self._cow_range(s, last, protect=dispatch)
-            except PoolExhausted:
-                self._preempt(s)  # recompute resume replays it cleanly
-                continue
-            survivors.append(s)
-            pairs += local
-        return survivors, pairs
 
     def _poison_mask(self, rows: List[int],
                      site: str = "poison") -> np.ndarray:
@@ -1560,50 +1503,78 @@ class ServingEngine:
         telemetry.LOG.append(rec)
 
     # -- per-tick paths -------------------------------------------------
-    def _step_replay(self, active: List[int]) -> int:
+    def _cow_and_guard(self, work: List[Tuple[int, int]],
+                       ) -> List[Tuple[int, int]]:
+        """The per-tick preamble for the ``(slot, n_tokens)`` about to be
+        written from each slot's position: copy-on-write over the pages
+        they land in, the device copies, then the dispatch guard.  A slot
+        whose copy cannot be met even after eviction and victim preemption
+        is preempted itself and dropped — its partly repointed pages roll
+        back with its table, so the survivors' pairs stay valid.  Returns
+        the pairs that may dispatch."""
+        if self.tables is None or not work:
+            return work
+        dispatch = frozenset(s for s, _ in work)
+        survivors: List[Tuple[int, int]] = []
+        pairs: List[Tuple[int, int]] = []
+        for s, n in work:
+            if self.slot_req[s] is None:
+                continue  # became a victim earlier in this loop
+            try:
+                pairs += self._cow_range(s, int(self.pos[s]) + n - 1,
+                                         protect=dispatch)
+            except PoolExhausted:
+                self._preempt(s)  # recompute resume replays it cleanly
+                continue
+            survivors.append((s, n))
+        self._apply_cow(pairs)
+        return self._guard_work(survivors)
+
+    def _fail_poisoned(self, s: int, req: Request,
+                       what: str = "logits row") -> None:
+        """FAIL the request in slot ``s``: its program's ``what`` held no
+        finite value."""
+        self.poisoned_rows += 1
+        self._terminate(req, FAILED, slot=s,
+                        error=f"poisoned {what} (no finite value)")
+
+    def _decode_tick(self, slots: List[int]) -> List[int]:
+        """One ``decode_step`` over ``slots``.  Each slot feeds the token at
+        its cursor (a prompt token while it replays its prompt, its last
+        output once it generates) and emits the sampled token once the
+        cursor reaches the end of its replay stream.  Returns the slots
+        dispatched: those that passed the COW/guard preamble."""
         with telemetry.span("schedule"):
-            if self.tables is not None:
-                active, pairs = self._cow_or_preempt(
-                    [(s, int(self.pos[s])) for s in active]
-                )
-                self._apply_cow(pairs)
-                active = [s for s, _ in self._guard_work([(s, 1) for s in active])]
-                if not active:
-                    self.dispatches -= 1  # nothing actually dispatched
-                    return 0
-            feed = np.zeros((self.scfg.slots,), np.int32)
-            live = np.zeros((self.scfg.slots,), bool)
-            full_len: Dict[int, int] = {}
-            for s in active:
-                req = self.slot_req[s]
-                cur = req._cursor  # type: ignore[attr-defined]
-                np_ = len(req.prompt)
-                full_len[s] = np_ + len(req.output)
-                feed[s] = (
-                    req.prompt[cur] if cur < np_ else req.output[cur - np_]
-                )
-                live[s] = True
-            poison = self._poison_mask(active)
+            slots = [s for s, _ in self._cow_and_guard([(s, 1) for s in slots])]
+            if not slots:
+                return slots
+            feed, live, _ = self._feeds(slots)
+            poison = self._poison_mask(slots)
         (next_tok, bad), (self.cache, self._key), rec = self._run_program(
-            self._step, (feed, self.pos, self._key, live, poison), 2, active,
+            self._step, (feed, self.pos, self._key, live, poison), 2, slots,
         )
         with telemetry.span("drain", rec):
             self._page_walk(rec, self.pos[None])
-            for s in active:
+            for s in slots:
                 req = self.slot_req[s]
-                cur = req._cursor  # type: ignore[attr-defined]
                 self.pos[s] += 1
-                req._cursor = cur + 1  # type: ignore[attr-defined]
+                req._cursor += 1  # type: ignore[attr-defined]
                 if bad[s]:
-                    self.poisoned_rows += 1
-                    self._terminate(req, FAILED, slot=s,
-                                    error="poisoned logits row (no finite value)")
-                    continue
-                if cur + 1 >= full_len[s]:  # this step produced a real token
+                    self._fail_poisoned(s, req)
+                elif req._cursor >= len(req.prompt) + len(req.output):  # type: ignore[attr-defined]
+                    # this step produced a real token
                     self._register_prefix(s, req)
                     self._emit_token(s, req, int(next_tok[s]))
-            self.tick_tokens.append(len(active))
-            self.steps_run += 1
+        return slots
+
+    def _step_replay(self, active: List[int]) -> int:
+        """One replay tick: every active slot through one decode step."""
+        active = self._decode_tick(active)
+        if not active:
+            self.dispatches -= 1  # nothing actually dispatched
+            return 0
+        self.tick_tokens.append(len(active))
+        self.steps_run += 1
         return len(active)
 
     def _step_chunked(self, active: List[int]) -> int:
@@ -1623,49 +1594,15 @@ class ServingEngine:
             chunk_lens = plan_prefill_chunks(
                 self.token_budget, len(gen), pending, self.prefill_chunk
             )
-            if gen and self.tables is not None:
-                gen, pairs = self._cow_or_preempt(
-                    [(s, int(self.pos[s])) for s in gen]
-                )
-                self._apply_cow(pairs)
-                gen = [s for s, _ in self._guard_work([(s, 1) for s in gen])]
-            if gen:
-                feed = np.zeros((self.scfg.slots,), np.int32)
-                live = np.zeros((self.scfg.slots,), bool)
-                for s in gen:
-                    req = self.slot_req[s]
-                    feed[s] = req.output[-1]
-                    live[s] = True
-                poison = self._poison_mask(gen)
         if gen:
-            (next_tok, bad), (self.cache, self._key), rec = self._run_program(
-                self._step, (feed, self.pos, self._key, live, poison), 2, gen,
-            )
-            with telemetry.span("drain", rec):
-                self._page_walk(rec, self.pos[None])
-                for s in gen:
-                    req = self.slot_req[s]
-                    self.pos[s] += 1
-                    req._cursor += 1  # type: ignore[attr-defined]
-                    if bad[s]:
-                        self.poisoned_rows += 1
-                        self._terminate(
-                            req, FAILED, slot=s,
-                            error="poisoned logits row (no finite value)")
-                        continue
-                    self._emit_token(s, req, int(next_tok[s]))
+            gen = self._decode_tick(gen)
 
         with telemetry.span("schedule"):
             # COW during the gen dispatch may have preempted a prefilling slot
-            chunk_lens = {s: n for s, n in chunk_lens.items()
-                          if self.slot_req[s] is not None}
-            if chunk_lens and self.tables is not None:
-                ok, pairs = self._cow_or_preempt(
-                    [(s, int(self.pos[s]) + n - 1) for s, n in chunk_lens.items()]
-                )
-                chunk_lens = {s: chunk_lens[s] for s in ok}
-                self._apply_cow(pairs)
-                chunk_lens = dict(self._guard_work(list(chunk_lens.items())))
+            chunk_lens = dict(self._cow_and_guard(
+                [(s, n) for s, n in chunk_lens.items()
+                 if self.slot_req[s] is not None]
+            ))
             if chunk_lens:
                 width = self.prefill_chunk
                 toks = np.zeros((self.scfg.slots, width), np.int32)
@@ -1684,16 +1621,17 @@ class ServingEngine:
                 live_rows=sum(chunk_lens.values()),
             )
             with telemetry.span("drain", rec):
-                self._prefill_walk(rec, self.pos, lens)
+                if self.tables is not None:
+                    rec.prior_pages_walked, rec.prior_pages_table = (
+                        lm.prior_pages_walked(
+                            self.cfg, self.pos, lens, self.prefill_chunk,
+                            self.pool.page_size, self.max_pages))
                 for s, n in chunk_lens.items():
                     req = self.slot_req[s]
                     self.pos[s] += n
                     req._cursor += n  # type: ignore[attr-defined]
                     if pbad[s]:
-                        self.poisoned_rows += 1
-                        self._terminate(
-                            req, FAILED, slot=s,
-                            error="poisoned logits row (no finite value)")
+                        self._fail_poisoned(s, req)
                         continue
                     if req._cursor >= len(req.prompt) + len(req.output):  # type: ignore[attr-defined]
                         # the chunk reached the end of the replay stream:

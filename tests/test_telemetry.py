@@ -2,6 +2,7 @@
 (``repro.serving.telemetry``): what each step ran, for whom, and when each
 host phase began and ended."""
 import collections
+import dataclasses
 import functools
 import glob
 import os
@@ -383,3 +384,79 @@ def test_decode_records_count_their_page_walk(sync_every, bounded, monkeypatch, 
         assert rec.pages_walked == walked
     others = [rec for rec, _, _ in calls if rec not in [d[0] for d in decode]]
     assert all(rec.pages_walked == rec.pages_table == 0 for rec in others)
+
+
+def _walk_configs():
+    qwen = _qwen()
+    return {
+        "gqa": qwen,
+        "windowed": dataclasses.replace(qwen, sliding_window=24, global_attn_every=2),
+        "mla_moe": get_config("deepseek_v2_lite_16b").reduced(),
+    }
+
+
+@pytest.mark.parametrize("bounded", [True, False])
+@pytest.mark.parametrize("name", ["gqa", "windowed", "mla_moe"])
+def test_lm_counts_what_its_layers_walk(name, bounded, monkeypatch):
+    """``lm.pages_walked`` / ``lm.prior_pages_walked``: the per-layer sum of
+    what ``ops.paged_walk`` / ``ops.prefill_walk`` answer for each layer's
+    window and the kernel-selecting facts of its paged attention call."""
+    from repro.kernels import ops
+
+    cfg = _walk_configs()[name]
+    if bounded:  # as ops answers for a kernel that walks in the kernel
+        monkeypatch.setattr(ops, "_walks_live_pages", lambda *a: True)
+    facts = dict(head_dim=cfg.kv_head_dim, kv_dtype=cfg.kv_dtype,
+                 attention=cfg.attention, logit_soft_cap=cfg.logit_soft_cap,
+                 backend=None if cfg.kernel_backend == "auto" else cfg.kernel_backend)
+    windows = lm.static_windows(cfg)
+    if name == "windowed":
+        assert len(set(windows)) == 2
+    ps, width, chunk = 8, 12, 32
+    lens = np.array([[0, 1, 8, 9, 40, 96], [3, 17, 33, 50, 64, 90]])
+    want = sum(int(ops.paged_walk(lens, ps, width, w, **facts).sum()) for w in windows)
+    assert lm.pages_walked(cfg, lens, ps, width) == (want, lens.size * width * len(windows))
+    if bounded:
+        assert want < lens.size * width * len(windows)
+    starts, live = np.array([0, 32, 64, 40, 8]), np.array([20, 32, 0, 9, 32])
+    per_layer = [ops.prefill_walk(starts, live, chunk, ps, width, w, **facts) for w in windows]
+    assert lm.prior_pages_walked(cfg, starts, live, chunk, ps, width) == (
+        sum(int(wk.sum()) for wk, _ in per_layer), sum(int(tb.sum()) for _, tb in per_layer))
+
+
+@pytest.mark.parametrize("name", ["gqa", "windowed", "mla_moe"])
+def test_lm_counts_the_expert_rows(name):
+    """``lm.expert_rows``: the held experts' rows of every MoE layer, each
+    ``layers.moe_rows`` of the token rows; none for a dense model."""
+    from repro.models import layers as L
+
+    cfg = _walk_configs()[name]
+    for rows in (1, 7, 64):
+        want = L.moe_layers(cfg) * L.moe_rows(cfg, rows) if cfg.moe else 0
+        assert lm.expert_rows(cfg, rows) == want
+    assert (lm.expert_rows(cfg, 1) > 0) == (name == "mla_moe")
+
+
+def test_engine_imports_the_model_not_its_layers_or_kernels():
+    """The serving engine's one seam to the model is ``repro.models.lm``:
+    it imports nothing of ``repro.models.layers`` and, of ``repro.kernels``,
+    only the pool's runtime guard."""
+    import ast
+    import inspect
+
+    tree = ast.parse(inspect.getsource(E))
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                assert not alias.name.startswith(("repro.models.layers", "repro.kernels"))
+        elif isinstance(node, ast.ImportFrom):
+            mod, names = node.module or "", {a.name for a in node.names}
+            if node.level:  # relative to the engine's package, repro.serving
+                base = ["repro", "serving"][: 3 - node.level]
+                mod = ".".join(base + ([mod] if mod else []))
+            assert not mod.startswith("repro.models.layers")
+            assert not (mod == "repro.models" and "layers" in names)
+            if mod.startswith("repro.kernels"):
+                assert mod == "repro.kernels.ops" and names == {"guard_dispatch"}
+            if mod == "repro":
+                assert "kernels" not in names
